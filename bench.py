@@ -82,6 +82,13 @@ serving-subprocess gates (compile census budgets, the ISSUE 11 telemetry <=2% ov
 bar, SLO/goodput counter arithmetic) fail the bench run (exit 3) on
 breach, after the record prints.
 
+One process per chip at a time: this file's parent process never imports
+jax.  The chip phases (throughput, time-to-accuracy, the LM rows) run in ONE
+child (``chip_phases``), then the two compile-condition children, then the
+CPU-pinned blocks — strictly one after another.  A chip phase or
+compile-condition child that fails also fails the run (exit 3), after the
+record prints.
+
 Prints ONE JSON line:
   {"metric": ..., "value": ..., "unit": ..., "vs_baseline": ..., ...extras}
 
@@ -95,7 +102,12 @@ host->device feed + PS variable RPCs bound it; SURVEY.md §3.1).
 from __future__ import annotations
 
 import json
+import os
+import subprocess
+import sys
 import time
+
+_ROOT = os.path.dirname(os.path.abspath(__file__))
 
 BASELINE_IMAGES_PER_SEC_PER_CHIP = 10_000.0  # nominal reference estimate, see docstring
 TARGET_ACC = 0.99
@@ -113,19 +125,30 @@ def _cache_dir_nonempty(cache_dir: str | None) -> bool:
     """Whether the persistent compile cache holds ANY entries.
 
     Deliberately named for what it checks: entries may belong to a
-    different program, so this is provenance for phase 1's
-    first-epoch figure, NOT proof phase 1 compiled warm — the warm/cold
+    different program, so this is provenance for the chip phases'
+    first-epoch figure, NOT proof they compiled warm — the warm/cold
     compile figures are therefore each measured in their own subprocess
     (r3 advisor: a nonempty dir without THIS program's entries would
     otherwise report a cold compile as compile_s_warm)."""
-    import os
-
     if not cache_dir or not os.path.isdir(cache_dir):
         return False
     try:
         return any(os.scandir(cache_dir))
     except OSError:
         return False
+
+
+def _last_record(stdout: str, key: str, value: str) -> dict | None:
+    """The last JSON line of ``stdout`` whose ``key`` equals ``value``."""
+    found = None
+    for line in stdout.splitlines():
+        try:
+            rec = json.loads(line)
+        except json.JSONDecodeError:
+            continue
+        if isinstance(rec, dict) and rec.get(key) == value:
+            found = rec
+    return found
 
 
 def _compile_s_in_subprocess(use_cache: bool) -> float | None:
@@ -136,54 +159,57 @@ def _compile_s_in_subprocess(use_cache: bool) -> float | None:
     layer, so "cache disabled" after a warm compile is not cold, and a
     repeat compile in the same process is warmer than any fresh run.  A
     subprocess (`launch/cli.py --throughput 1`) has no in-memory caches —
-    cold really recompiles, warm really deserializes from disk.  None if
-    the subprocess fails (the main figures don't depend on it).
+    cold really recompiles (the cache is switched off by jax's own
+    ``JAX_ENABLE_COMPILATION_CACHE``, wherever it was placed), warm really
+    deserializes from disk.  The child needs the chip, so it only ever runs
+    while no other process of this bench holds it.  None if the subprocess
+    fails — which fails the run (exit 3) after the record prints.
     """
-    import json
-    import subprocess
-    import sys
-
     args = [
         sys.executable, "-m", "distributed_tensorflow_ibm_mnist_tpu.launch.cli",
         "--preset", "mnist_lenet_1chip", "--throughput", "1",
     ]
     for key, val in BENCH_OVERRIDES.items():
         args += ["--set", f"{key}={val!r}"]
+    env = dict(os.environ)
     if not use_cache:
-        args += ["--set", "compile_cache_dir=None"]
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     try:
-        out = subprocess.run(args, capture_output=True, text=True, timeout=420)
-        for line in out.stdout.splitlines():
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if rec.get("kind") == "throughput":
-                return rec["compile_and_first_epoch_s"]
-        # fell through: no throughput record — say why on stderr (e.g. a
-        # single-client TPU runtime refusing a second process) instead of
-        # silently nulling the compile fields
-        print(
-            f"bench: compile-measurement subprocess (use_cache={use_cache}) "
-            f"produced no throughput record (rc={out.returncode}); stderr "
-            f"tail: {out.stderr[-500:]!r}",
-            file=sys.stderr,
-        )
+        out = subprocess.run(args, capture_output=True, text=True,
+                             timeout=420, env=env, cwd=_ROOT)
     except (subprocess.SubprocessError, OSError) as e:
-        print(
-            f"bench: compile-measurement subprocess (use_cache={use_cache}) "
-            f"failed: {e!r}",
-            file=sys.stderr,
-        )
-    return None
+        print(f"bench: compile-measurement subprocess (use_cache={use_cache}) "
+              f"failed: {e!r}", file=sys.stderr)
+        return None
+    rec = _last_record(out.stdout, "kind", "throughput")
+    if rec is None:
+        print(f"bench: compile-measurement subprocess (use_cache={use_cache}) "
+              f"produced no throughput record (rc={out.returncode}); stderr "
+              f"tail: {out.stderr[-500:]!r}", file=sys.stderr)
+        return None
+    return rec["compile_and_first_epoch_s"]
 
 
-def main() -> None:
-    from distributed_tensorflow_ibm_mnist_tpu.core.trainer import (
-        Trainer,
-        resolve_compile_cache_dir,
+def chip_phases() -> int:
+    """Everything that needs the chip, in ONE process of its own.
+
+    A chip belongs to one process at a time, so the bench's parent stays
+    off jax entirely and runs this in a child (``main`` spawns it), then
+    the two compile-condition children, strictly one after another.
+    Prints one JSON line ``{"kind": "chip_phases", ...}``; returns nonzero
+    — after printing — if an LM phase raised, so a Mosaic compile failure
+    of the flash kernel cannot disappear from the record.
+    """
+    import traceback
+
+    from distributed_tensorflow_ibm_mnist_tpu.core.trainer import Trainer
+    from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import (
+        enable_compile_cache,
     )
-    from distributed_tensorflow_ibm_mnist_tpu.utils.config import get_preset
+    from distributed_tensorflow_ibm_mnist_tpu.utils.config import (
+        RunConfig,
+        get_preset,
+    )
     from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import CompileTracker
 
     # ISSUE 6 compile accounting: install before ANY jit runs so every XLA
@@ -192,12 +218,10 @@ def main() -> None:
     compile_tracker = CompileTracker.install()
     compile0 = compile_tracker.snapshot()
 
-    # batch 1024 saturates the chip (measured on v5e: ~590k img/s steady-state;
-    # larger batches gain nothing — the model is overhead/bandwidth-bound, not
-    # MXU-bound) while a cosine-annealed 4e-3 Adam still reaches 99% test acc
-    # in 2 epochs.
-    import os
-
+    # batch 1024 saturates the chip (larger batches gain nothing — the
+    # model is overhead/bandwidth-bound, not MXU-bound) while a
+    # cosine-annealed 4e-3 Adam still reaches 99% test acc in 2 epochs.
+    #
     # DTM_BENCH_QUICK: CI smoke of the HARNESS, not a measurement — the
     # same contract the subprocess blocks already honor (bench_serving
     # et al. read the env var themselves).  The headline shrinks to a
@@ -211,26 +235,12 @@ def main() -> None:
             model="mlp", model_kwargs={"hidden": (32,)}, synthetic=True,
             n_train=512, n_test=128, batch_size=128, epochs=2,
             target_accuracy=0.2)
-    cache_dir = resolve_compile_cache_dir(cfg.compile_cache_dir)
-    prewarmed = _cache_dir_nonempty(cache_dir)
+    cache_dir = enable_compile_cache()
     trainer = Trainer(cfg)
 
     # Phase 1 — steady-state throughput + MFU (public API; also warms the
     # epoch-runner compile cache and restores the fresh state afterwards).
     tput = trainer.measure_throughput(epochs=2 if quick else 10)
-
-    # Phase 1b — BOTH compile conditions, each in its own fresh subprocess
-    # (see _compile_s_in_subprocess for why in-process is dishonest in both
-    # directions).  Phase 1's own first-epoch figure is not used for
-    # either: a nonempty cache dir doesn't prove it holds THIS program's
-    # entries (r3 advisor), but after phase 1 the cache certainly does, so
-    # the use_cache=True subprocess really deserializes and the
-    # use_cache=False one really recompiles.
-    compile_s_cold = None if quick else _compile_s_in_subprocess(use_cache=False)
-    compile_s_warm = (
-        _compile_s_in_subprocess(use_cache=True)
-        if cache_dir and not quick else None
-    )
 
     # Warm the eval compile outside phase 2's timed region (same shapes).
     trainer.evaluate()
@@ -244,46 +254,97 @@ def main() -> None:
     # Phase 3 — the round-3 long-context headline as secondary metrics:
     # S=8192 causal flash LM (RoPE), steady-state tokens/sec + real MFU
     # (analytic attention supplement).  Skippable for tight time budgets.
-    lm = None
-    import os
-
-    if not os.environ.get("DTM_BENCH_SKIP_LM"):
-        try:
-            from distributed_tensorflow_ibm_mnist_tpu.utils.config import RunConfig
-
-            lm_cfg = RunConfig(
-                name="bench_lm8k", model="causal_lm",
-                model_kwargs={"dim": 512, "depth": 4, "heads": 8,
-                              "attn": "flash"},
-                dataset="retrieval",
-                dataset_kwargs={"vocab": 256, "seq_len": 8192},
-                n_train=64, n_test=16, batch_size=8, epochs=1, quiet=True,
-                eval_batch_size=8,
-            )
-            lm = Trainer(lm_cfg).measure_throughput(epochs=3)
-        except Exception as e:  # secondary metric: never sink the headline
-            import sys
-
-            print(f"bench: LM phase failed: {e!r}", file=sys.stderr)
-
     # Phase 3b — the same LM at head_dim 128 (heads 4): flash attention's
     # per-score-element cost is ~6 VPU f32 ops against 4*D MXU FLOPs, so
-    # doubling D halves the VPU:MXU ratio — measured round 5 at 1.35x the
-    # D=64 form (docs/PERFORMANCE.md).  Reported separately so the D=64
-    # row stays comparable across rounds.
-    lm_d128 = None
-    if lm is not None:  # only beside a working D=64 comparison baseline
+    # doubling D halves the VPU:MXU ratio.  Reported separately so the
+    # D=64 row stays comparable across rounds.
+    lm = lm_d128 = None
+    failed: list[str] = []
+    lm_cfg = RunConfig(
+        name="bench_lm8k", model="causal_lm",
+        model_kwargs={"dim": 512, "depth": 4, "heads": 8, "attn": "flash"},
+        dataset="retrieval",
+        dataset_kwargs={"vocab": 256, "seq_len": 8192},
+        n_train=64, n_test=16, batch_size=8, epochs=1, quiet=True,
+        eval_batch_size=8,
+    )
+    if not os.environ.get("DTM_BENCH_SKIP_LM"):
         try:
-            d128_cfg = lm_cfg.replace(
+            lm = Trainer(lm_cfg).measure_throughput(epochs=3)
+            lm_d128 = Trainer(lm_cfg.replace(
                 name="bench_lm8k_d128",
                 model_kwargs={"dim": 512, "depth": 4, "heads": 4,
                               "attn": "flash"},
-            )
-            lm_d128 = Trainer(d128_cfg).measure_throughput(epochs=3)
-        except Exception as e:
-            import sys
+            )).measure_throughput(epochs=3)
+        except Exception:  # phase boundary: keep the headline, fail the run
+            traceback.print_exc()
+            failed.append("lm_d128" if lm is not None else "lm")
 
-            print(f"bench: LM d128 phase failed: {e!r}", file=sys.stderr)
+    cdelta = CompileTracker.delta(compile_tracker.snapshot(), compile0)
+    mk = lm_cfg.model_kwargs
+    print(json.dumps({
+        "kind": "chip_phases", "quick": quick, "failed": failed,
+        "compile_cache_dir": cache_dir,
+        "tput": tput, "summary": summary,
+        "wall_excl_compile": wall_excl_compile,
+        "batch_size": cfg.batch_size, "lr": cfg.lr,
+        "lm": lm, "lm_d128": lm_d128,
+        "lm_config": (
+            f"{lm_cfg.model} dim{mk['dim']} depth{mk['depth']} "
+            f"heads{mk['heads']} S={lm_cfg.dataset_kwargs['seq_len']} "
+            f"causal {mk['attn']} rope b{lm_cfg.batch_size}"),
+        # compile accounting for the chip process (the subprocess blocks
+        # carry their own counts): a warm persistent compile cache shows
+        # up here as LOWER compile seconds at the same program count
+        "n_compiled_programs": cdelta["n_compiled_programs"],
+        "compile_time_s": cdelta["compile_time_s"],
+        "compile_by_site": cdelta["by_site"],
+    }), flush=True)
+    return 1 if failed else 0
+
+
+def main() -> None:
+    from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import (
+        compile_cache_dir,
+    )
+
+    # This parent never imports jax: every phase that needs the chip runs
+    # in a child, one at a time (a parent that has touched jax holds the
+    # chip, and a child that needs it then fails or hangs).
+    quick = bool(os.environ.get("DTM_BENCH_QUICK"))
+    prewarmed = _cache_dir_nonempty(compile_cache_dir())
+
+    # Phases 1-3 — the chip phases, in their own process (chip_phases).
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, bench; sys.exit(bench.chip_phases())"],
+        stdout=subprocess.PIPE, text=True, cwd=_ROOT)
+    chip = _last_record(out.stdout, "kind", "chip_phases")
+    if chip is None:
+        print(f"bench: the chip phases produced no record "
+              f"(rc={out.returncode}) — nothing to report", file=sys.stderr)
+        sys.exit(out.returncode or 1)
+    chip_gate_rc = out.returncode
+    tput, summary = chip["tput"], chip["summary"]
+    wall_excl_compile = chip["wall_excl_compile"]
+    lm, lm_d128 = chip["lm"], chip["lm_d128"]
+
+    # Phase 1b — BOTH compile conditions, each in its own fresh subprocess
+    # (see _compile_s_in_subprocess for why in-process is dishonest in both
+    # directions), now that the chip child has exited and released the
+    # chip.  The chip phases' own first-epoch figure is not used for
+    # either: a nonempty cache dir doesn't prove it holds THIS program's
+    # entries (r3 advisor), but after the chip phases the cache certainly
+    # does, so the use_cache=True subprocess really deserializes and the
+    # use_cache=False one really recompiles.
+    compile_s_cold = compile_s_warm = None
+    if not quick:
+        compile_s_cold = _compile_s_in_subprocess(use_cache=False)
+        if chip["compile_cache_dir"]:
+            compile_s_warm = _compile_s_in_subprocess(use_cache=True)
+        if compile_s_cold is None or (
+                chip["compile_cache_dir"] and compile_s_warm is None):
+            chip_gate_rc = chip_gate_rc or 1
 
     # Phase 4 — the MULTICHIP comparison: ZeRO-1 sharded vs replicated
     # weight update on a dp=8 mesh (ISSUE 1).  Runs scripts/
@@ -295,9 +356,6 @@ def main() -> None:
     sharded = None
     if not os.environ.get("DTM_BENCH_SKIP_SHARDED"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             env.pop("XLA_FLAGS", None)  # the script arms its own device count
@@ -321,8 +379,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             print(f"bench: dp_sharded_update phase failed: {e!r}", file=sys.stderr)
 
     # Phase 5 — the serving comparison: continuous batching (serving/
@@ -342,9 +398,6 @@ def main() -> None:
     serving_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_SERVING"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -369,8 +422,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             serving_gate_rc = 1
             print(f"bench: serving phase failed: {e!r}", file=sys.stderr)
 
@@ -383,9 +434,6 @@ def main() -> None:
     kv_paging = None
     if not os.environ.get("DTM_BENCH_SKIP_SERVING"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -408,8 +456,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             print(f"bench: kv_paging phase failed: {e!r}", file=sys.stderr)
 
     # Phase 5c — tensor-parallel serving (ISSUE 10): a model exceeding one
@@ -426,9 +472,6 @@ def main() -> None:
     tp_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_TP"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             env.pop("XLA_FLAGS", None)  # the script arms its own devices
@@ -454,8 +497,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             tp_gate_rc = 1
             print(f"bench: tp_serving phase failed: {e!r}", file=sys.stderr)
 
@@ -473,9 +514,6 @@ def main() -> None:
     cp_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_CP"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             env.pop("XLA_FLAGS", None)  # the script arms its own devices
@@ -501,8 +539,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             cp_gate_rc = 1
             print(f"bench: cp_serving phase failed: {e!r}", file=sys.stderr)
 
@@ -520,9 +556,6 @@ def main() -> None:
     quant_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_QUANT"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -548,8 +581,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             quant_gate_rc = 1
             print(f"bench: quant phase failed: {e!r}", file=sys.stderr)
 
@@ -569,9 +600,6 @@ def main() -> None:
     sampling_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_SAMPLING"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -597,8 +625,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             sampling_gate_rc = 1
             print(f"bench: sampling phase failed: {e!r}", file=sys.stderr)
 
@@ -616,9 +642,6 @@ def main() -> None:
     chunked_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_CHUNKED"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -644,8 +667,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             chunked_gate_rc = 1
             print(f"bench: chunked_prefill phase failed: {e!r}", file=sys.stderr)
 
@@ -660,9 +681,6 @@ def main() -> None:
     chaos = None
     if not os.environ.get("DTM_BENCH_SKIP_CHAOS"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -685,8 +703,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             print(f"bench: chaos phase failed: {e!r}", file=sys.stderr)
 
     # Phase 7 — the router soak (ISSUE 8): 3 engine replicas behind the
@@ -701,9 +717,6 @@ def main() -> None:
     router = None
     if not os.environ.get("DTM_BENCH_SKIP_ROUTER"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -727,8 +740,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             print(f"bench: router phase failed: {e!r}", file=sys.stderr)
 
     # Phase 8 — speculative decoding (ISSUE 9): n-gram prompt-lookup
@@ -742,9 +753,6 @@ def main() -> None:
     speculative = None
     if not os.environ.get("DTM_BENCH_SKIP_SPEC"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -768,8 +776,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             print(f"bench: speculative phase failed: {e!r}", file=sys.stderr)
 
     # Phase 9 — the training-side compile census (ROADMAP 5a remainder):
@@ -785,9 +791,6 @@ def main() -> None:
     census_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_TRAIN_CENSUS"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             env.pop("XLA_FLAGS", None)  # the script arms its own devices
@@ -813,8 +816,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             census_gate_rc = 1
             print(f"bench: train_census phase failed: {e!r}", file=sys.stderr)
 
@@ -832,9 +833,6 @@ def main() -> None:
     slo_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_SLO_DAEMON"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -859,8 +857,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             slo_gate_rc = 1
             print(f"bench: slo_daemon phase failed: {e!r}", file=sys.stderr)
 
@@ -876,9 +872,6 @@ def main() -> None:
     disagg_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_DISAGG"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -903,8 +896,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             disagg_gate_rc = 1
             print(f"bench: disagg phase failed: {e!r}", file=sys.stderr)
 
@@ -920,9 +911,6 @@ def main() -> None:
     frontdoor_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_FRONTDOOR"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -947,8 +935,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             frontdoor_gate_rc = 1
             print(f"bench: frontdoor phase failed: {e!r}", file=sys.stderr)
 
@@ -965,9 +951,6 @@ def main() -> None:
     crash_gate_rc = 0
     if not os.environ.get("DTM_BENCH_SKIP_CRASH"):
         try:
-            import subprocess
-            import sys
-
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
@@ -992,8 +975,6 @@ def main() -> None:
                     file=sys.stderr,
                 )
         except Exception as e:
-            import sys
-
             crash_gate_rc = 1
             print(f"bench: crash phase failed: {e!r}", file=sys.stderr)
 
@@ -1038,21 +1019,16 @@ def main() -> None:
         # measurement condition (deviates from the BASELINE.json:8 preset's
         # batch=128 on purpose — the metric of record is images/sec/chip and
         # time-to-99%, and batch is a free knob of the rebuild, not the task):
-        "batch_size": cfg.batch_size,
-        "lr": cfg.lr,
+        "batch_size": chip["batch_size"],
+        "lr": chip["lr"],
         "device": tput["device"],
         "param_count": summary["param_count"],
         "quick": quick,
     }
     if lm is not None:
-        mk = lm_cfg.model_kwargs
         result["lm_tokens_per_sec_per_chip"] = lm.get("tokens_per_sec_per_chip")
         result["lm_mfu"] = lm.get("mfu")
-        result["lm_config"] = (
-            f"{lm_cfg.model} dim{mk['dim']} depth{mk['depth']} "
-            f"heads{mk['heads']} S={lm_cfg.dataset_kwargs['seq_len']} "
-            f"causal {mk['attn']} rope b{lm_cfg.batch_size}"
-        )
+        result["lm_config"] = chip["lm_config"]
     if lm_d128 is not None:
         result["lm_d128_tokens_per_sec_per_chip"] = lm_d128.get(
             "tokens_per_sec_per_chip")
@@ -1124,24 +1100,23 @@ def main() -> None:
         result["crash"] = {
             k: v for k, v in crash.items() if k != "metric"
         }
-    # compile accounting for THIS process (phases 1/2/3 — the subprocess
-    # blocks carry their own counts): cache hits don't count, so a warm
-    # persistent compile cache shows up here as a LOWER program count
-    cdelta = CompileTracker.delta(compile_tracker.snapshot(), compile0)
-    result["n_compiled_programs"] = cdelta["n_compiled_programs"]
-    result["compile_time_s"] = cdelta["compile_time_s"]
-    result["compile_by_site"] = cdelta["by_site"]
+    # compile accounting of the chip process (phases 1/2/3 — the
+    # subprocess blocks carry their own counts)
+    result["n_compiled_programs"] = chip["n_compiled_programs"]
+    result["compile_time_s"] = chip["compile_time_s"]
+    result["compile_by_site"] = chip["compile_by_site"]
+    if chip["failed"]:
+        result["failed_chip_phases"] = chip["failed"]
     print(json.dumps(result), flush=True)
-    # the hard gates (tp memory/parity/failover, train compile census,
-    # serving: compile budgets + telemetry overhead + SLO/goodput
-    # arithmetic) fail the RUN, not just their block — after the record
-    # prints so the numbers are never lost with the verdict
-    if (tp_gate_rc or cp_gate_rc or census_gate_rc or serving_gate_rc
-            or quant_gate_rc or sampling_gate_rc or chunked_gate_rc
-            or slo_gate_rc or disagg_gate_rc or frontdoor_gate_rc
-            or crash_gate_rc):
-        import sys
-
+    # the hard gates (a chip phase or compile-condition child that failed;
+    # tp memory/parity/failover, train compile census, serving: compile
+    # budgets + telemetry overhead + SLO/goodput arithmetic) fail the RUN,
+    # not just their block — after the record prints so the numbers are
+    # never lost with the verdict
+    if (chip_gate_rc or tp_gate_rc or cp_gate_rc or census_gate_rc
+            or serving_gate_rc or quant_gate_rc or sampling_gate_rc
+            or chunked_gate_rc or slo_gate_rc or disagg_gate_rc
+            or frontdoor_gate_rc or crash_gate_rc):
         sys.exit(3)
 
 

@@ -24,10 +24,17 @@ CLI_ARGS=("$@")
 REPO_ROOT="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 PKG="distributed_tensorflow_ibm_mnist_tpu"
 
-# 1. Ship the framework to every host of the slice (rsync over gcloud ssh).
+# 1. Ship the framework to every host of the slice (scp over gcloud ssh).
+#    The native data library goes as SOURCE (native/dtm.cpp), never as this
+#    machine's native/build/ binary: each host builds its own on first use.
+gcloud compute tpus tpu-vm ssh "${TPU_NAME}" --zone="${ZONE}" --worker=all \
+  --command="mkdir -p ~/app/native"
 gcloud compute tpus tpu-vm scp --recurse \
-  "${REPO_ROOT}/${PKG}" "${REPO_ROOT}/native" "${REPO_ROOT}/pyproject.toml" \
+  "${REPO_ROOT}/${PKG}" "${REPO_ROOT}/pyproject.toml" \
   "${TPU_NAME}:~/app/" --zone="${ZONE}" --worker=all
+gcloud compute tpus tpu-vm scp \
+  "${REPO_ROOT}/native/dtm.cpp" \
+  "${TPU_NAME}:~/app/native/" --zone="${ZONE}" --worker=all
 
 # 2. Start the identical SPMD process on every host.  No role flags, no
 #    ClusterSpec: TPU metadata gives each process its slice coordinates.

@@ -3,9 +3,13 @@
 The reference's native data path lived in the TF wheel's C++ runtime
 (SURVEY.md §2.2); ours is authored in ``native/dtm.cpp`` and consumed here
 via ctypes (no pybind11 in this environment).  The library is compiled
-lazily with g++ on first use and cached next to the source; every entry
-point has a numpy fallback, so the framework never *requires* a working
-toolchain — ``available()`` reports which path you're on.
+lazily with g++ on first use into ``native/build/`` under a name keyed on
+the SOURCE'S CONTENT, so a stale or foreign binary is never loaded: a
+checkout builds what its own ``dtm.cpp`` says.  Every entry point has a
+numpy fallback, so the framework never *requires* a working toolchain —
+but a build that was attempted and failed says so (a ``RuntimeWarning``
+with the compiler's stderr), and ``status()`` reports which path you're on
+and why.
 
 Surface:
 * :func:`gather` — parallel batch-assembly gather (out[i] = src[idx[i]]);
@@ -18,9 +22,11 @@ Surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 from pathlib import Path
 
 import numpy as np
@@ -30,42 +36,54 @@ _BUILD_DIR = _SRC.parent / "build"
 _LOCK = threading.Lock()
 _LIB: ctypes.CDLL | None = None
 _TRIED = False
+_WHY_NOT: str | None = None  # why the numpy path is live, once _TRIED
 
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _f32p = ctypes.POINTER(ctypes.c_float)
 
 
-def _compile() -> Path | None:
-    so = _BUILD_DIR / "libdtm.so"
-    if so.exists() and so.stat().st_mtime >= _SRC.stat().st_mtime:
+def _so_path() -> Path:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libdtm-{digest}.so"
+
+
+def _compile() -> Path:
+    """Build (or reuse) the library for THIS source; raises on failure."""
+    so = _so_path()
+    if so.exists():
         return so
     _BUILD_DIR.mkdir(exist_ok=True)
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")  # concurrent builders: atomic publish
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        str(_SRC), "-o", str(so),
+        str(_SRC), "-o", str(tmp),
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
+        os.replace(tmp, so)
+    finally:
+        tmp.unlink(missing_ok=True)
     return so
 
 
 def _load() -> ctypes.CDLL | None:
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _WHY_NOT
     with _LOCK:
         if _TRIED:
             return _LIB
         _TRIED = True
         if os.environ.get("DTM_DISABLE_NATIVE"):
-            return None
-        so = _compile()
-        if so is None:
+            _WHY_NOT = "DTM_DISABLE_NATIVE is set"
             return None
         try:
-            lib = ctypes.CDLL(str(so))
-        except OSError:
+            lib = ctypes.CDLL(str(_compile()))
+        except (OSError, subprocess.SubprocessError) as e:
+            stderr = getattr(e, "stderr", b"") or b""
+            _WHY_NOT = f"{e!r} {stderr.decode(errors='replace')[-800:]}".strip()
+            warnings.warn(
+                f"native data library unavailable, numpy path in use: "
+                f"{_WHY_NOT}", RuntimeWarning, stacklevel=3)
             return None
         lib.dtm_gather.argtypes = [_u8p, _i32p, _u8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
         lib.dtm_render_affine.argtypes = [
@@ -89,6 +107,15 @@ def _load() -> ctypes.CDLL | None:
 def available() -> bool:
     """Whether the C++ library compiled and loaded on this machine."""
     return _load() is not None
+
+
+def status() -> dict:
+    """Which data path is live and why: ``{"path": "native", "library":
+    <file>}`` or ``{"path": "numpy", "reason": <why the build or load did
+    not happen>}`` (attempts the build on first call)."""
+    if _load() is not None:
+        return {"path": "native", "library": str(_so_path())}
+    return {"path": "numpy", "reason": _WHY_NOT}
 
 
 def _ptr(a: np.ndarray, ty):

@@ -65,9 +65,10 @@ def _build(argv: list[str] | None = None) -> tuple[RunConfig, argparse.Namespace
     )
     parser.add_argument(
         "--virtual-devices", type=int, default=None, metavar="N",
-        help="dev machines: rebuild jax onto an N-device virtual CPU mesh "
-        "before training (utils/hostmesh) — lets dp/tp/sp/pp configs run "
-        "where only one (or no) accelerator is attached",
+        help="dev machines: run on an N-device virtual CPU mesh instead of "
+        "any attached accelerator (utils/hostmesh) — lets dp/tp/sp/pp "
+        "configs run where only one (or no) chip is attached.  The "
+        "accelerator is never opened; the printed `device` says cpu",
     )
     parser.add_argument(
         "--coordinator", default=None,
@@ -100,14 +101,13 @@ def main(argv: list[str] | None = None) -> int:
 
     config, args = _build(argv)
     if args.virtual_devices:
-        import jax
+        # the flag IS the choice of platform: go straight to the CPU without
+        # opening an accelerator client just to count its devices
+        from distributed_tensorflow_ibm_mnist_tpu.utils.hostmesh import (
+            ensure_virtual_cpu_devices,
+        )
 
-        if len(jax.devices()) < args.virtual_devices:
-            from distributed_tensorflow_ibm_mnist_tpu.utils.hostmesh import (
-                ensure_virtual_cpu_devices,
-            )
-
-            ensure_virtual_cpu_devices(args.virtual_devices)
+        ensure_virtual_cpu_devices(args.virtual_devices)
     trainer = Trainer(config)
     if args.throughput:
         if config.profile_dir:

@@ -32,13 +32,8 @@ def bootstrap(
         # CPU clusters: the default (no-op) CPU collectives layer cannot run
         # cross-process computations ("Multiprocess computations aren't
         # implemented on the CPU backend") — arm the gloo TCP collectives
-        # BEFORE the backend client exists.  TPU/GPU ignore this flag, and
-        # jax versions without it (or builds without gloo) skip it silently
-        # rather than fail the bootstrap.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+        # BEFORE the backend client exists.  TPU/GPU ignore this flag.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,

@@ -3,8 +3,9 @@
 The reference consumed its kernels (cuDNN conv, Eigen softmax/xent) through
 the tensorflow-gpu wheel (SURVEY.md §2.2); XLA:TPU emits ours, and the ops in
 this package are the hand-written Pallas exceptions for cases where fusion
-control matters.  Every op runs in interpret mode on CPU so the test suite
-exercises identical code paths (SURVEY.md §4).
+control matters.  The CPU test suite runs every op through the Pallas
+interpreter — an explicit choice (ops/interpret.py), never a fallback — so
+it exercises identical code paths (SURVEY.md §4).
 """
 
 from distributed_tensorflow_ibm_mnist_tpu.ops.xent import (  # noqa: F401

@@ -38,9 +38,9 @@ SEPARATE heads (any lane- or sublane-packing of two heads' Q/K either sums
 their score matrices or multiplies against structural zeros — same MXU
 occupancy, more memory traffic), so the fix is to stop paying for the pad
 in memory and bandwidth rather than to fake a fuller contraction.
-Sequence padding is masked inside the kernels, so any S works.  On
-non-TPU backends the kernels run in Pallas interpret mode, which is how
-the CPU test suite exercises the same code path (SURVEY.md §4).
+Sequence padding is masked inside the kernels, so any S works.  The CPU
+test suite exercises the same code path through the Pallas interpreter,
+chosen explicitly (ops/interpret.py) — never guessed from the backend.
 
 Composes with sequence parallelism: ring attention
 (parallel/ring_attention.py) rotates K/V shards BETWEEN devices while this
@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distributed_tensorflow_ibm_mnist_tpu.ops.interpret import resolve_interpret
 
 _NEG = -1e30
 _LOG2E = 1.4426950408889634  # exp(x) == exp2(x * log2(e)): the kernels run
@@ -120,10 +122,6 @@ _GROUPED_DQ_VMEM_BUDGET = int(2.5 * 1024 * 1024)
 # groups the partial-buffer cost outweighs the one-recompute win, so the
 # kernel falls back to the two-kernel scheme instead.
 _GROUPED_MAX_GROUPS = 8
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pick_block(n: int, target: int) -> int:
@@ -634,8 +632,7 @@ def _flash(q, k, v, causal, interpret, window):
 
 
 def _flash_fwd(q, k, v, causal, interpret, window=0):
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     qp, kp, vp, (b, s, h, d, hkv) = _prepare(q, k, v)
     bh, sp, _ = qp.shape
     block_q = _pick_block(sp, _FWD_BLOCK_Q or _BLOCK_Q)
@@ -690,8 +687,7 @@ def _bwd_calls(q, k, v, g, lse, delta, causal, interpret, window=0):
     Factored out of :func:`_flash_bwd` so ring attention can drive the same
     kernels per K/V block with the statistics of the full ring
     (parallel/ring_attention.py)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     qp, kp, vp, (b, s, h, d, hkv) = _prepare(q, k, v)
     gp = _prepare(g, g, g)[0]
     bh, sp, _ = qp.shape
@@ -925,8 +921,9 @@ def flash_attention(
     causal: bool = False, interpret: bool | None = None, window: int = 0,
 ) -> jax.Array:
     """Blockwise (flash) attention on (B, S, H, D); drop-in ``attn_fn`` for
-    models/transformer.py.  ``interpret=None`` auto-selects interpret mode
-    off-TPU.
+    models/transformer.py.  ``interpret=None`` compiles under Mosaic (an
+    error off-TPU) unless the process opted into the interpreter —
+    ops/interpret.py.
 
     ``window`` > 0 is causal sliding-window attention: each position
     attends to the last ``window`` positions (itself included).  Off-window

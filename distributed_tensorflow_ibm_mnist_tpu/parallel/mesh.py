@@ -1,4 +1,4 @@
-"""Device-mesh construction and shard_map compatibility shim.
+"""Device-mesh construction and the package's one shard_map call site.
 
 The reference's cluster topology was a ClusterSpec built from role flags
 (SURVEY.md §1 L2).  The TPU-native analog is a named ``jax.sharding.Mesh``
@@ -14,24 +14,15 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-# jax.shard_map moved out of jax.experimental around 0.6; keep one import site.
-try:  # pragma: no cover - version dependent
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off (works across jax versions).
+    """``jax.shard_map`` with the varying-manual-axes check off.
 
-    The check flag was renamed ``check_rep`` -> ``check_vma``; replicated
-    outputs produced via psum are correct but the checker can't always prove
-    it, so we disable it at this single call site.
+    Replicated outputs produced via psum are correct but the checker can't
+    always prove it, so it is disabled at this single call site.
     """
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def hybrid_mesh_shapes(
@@ -142,18 +133,17 @@ def _device_grid(shape: tuple[int, ...], devices: list) -> np.ndarray:
     On real TPU slices ``mesh_utils.create_device_mesh`` maps logical axes
     onto the physical torus so each axis's collectives ride contiguous ICI
     rings — list-order reshape (what round 1 did; VERDICT.md item 7) gives
-    inner axes non-neighbor links.  Virtual/CPU devices carry no coords, and
-    create_device_mesh also rejects using a strict subset of the visible
-    chips, so those fall back to the list-order reshape (identical behavior
-    to before, and topology is meaningless there anyway).
+    inner axes non-neighbor links.  Virtual/CPU devices carry no coords
+    (topology is meaningless there), and create_device_mesh rejects a
+    strict subset of the visible chips, so those two cases — and only
+    those — take the list-order reshape.  A create_device_mesh failure on
+    the full set of real chips is raised: a silent fall to list order
+    would change which links each axis rides.
     """
     first = devices[0]
     on_tpu = getattr(first, "platform", "") == "tpu" and hasattr(first, "coords")
     if on_tpu and len(devices) == len(jax.devices()) and len(devices) > 1:
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            return mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            pass  # unknown topology (e.g. tunnelled single-host oddities)
+        return mesh_utils.create_device_mesh(shape, devices=devices)
     return np.array(devices).reshape(shape)

@@ -133,9 +133,9 @@ prefix-sharing mechanism.
 Launch-path prewarm (ROADMAP item 5a, :meth:`InferenceEngine.prewarm`):
 every program above compiles lazily at first use, so the first requests
 eat the whole compile bill as TTFT.  ``prewarm()`` runs the engine's full
-program family once with dummy inputs before traffic — paired with
-``compile_cache_dir=`` the compiles also persist across processes, and
-``Router.prewarm()`` fans the warmup across replicas.
+program family once with dummy inputs before traffic — the persistent
+compile cache (utils/compile_cache.py) keeps those compiles across
+processes, and ``Router.prewarm()`` fans the warmup across replicas.
 
 Greedy decode through this loop is token-for-token identical to
 ``make_generator`` for every ``decode_ahead`` (both run the same
@@ -175,6 +175,7 @@ external liveness probe for a wedged pump.
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from typing import Callable
@@ -192,6 +193,8 @@ from distributed_tensorflow_ibm_mnist_tpu.core.generate import (
 )
 from distributed_tensorflow_ibm_mnist_tpu.models.quant import quantize_params_int8
 from distributed_tensorflow_ibm_mnist_tpu.models.transformer import reset_cache_slots
+from distributed_tensorflow_ibm_mnist_tpu.ops.flash_attention import flash_attention
+from distributed_tensorflow_ibm_mnist_tpu.parallel.mesh import shard_map_compat
 from distributed_tensorflow_ibm_mnist_tpu.parallel.ring_attention import (
     make_ring_attention,
 )
@@ -227,6 +230,7 @@ from distributed_tensorflow_ibm_mnist_tpu.serving.sampling import (
 )
 from distributed_tensorflow_ibm_mnist_tpu.serving.scheduler import FIFOScheduler, Request
 from distributed_tensorflow_ibm_mnist_tpu.serving.stats import ServingStats
+from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import enable_compile_cache
 from distributed_tensorflow_ibm_mnist_tpu.utils.metrics import MetricWriter
 from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import CompileTracker
 
@@ -253,6 +257,15 @@ class EngineStalled(RuntimeError):
     ``stall_timeout_s``.  In-flight requests were already moved to FAILED
     and their slots reset before this raised — the engine object remains
     usable (or closeable) by the caller that catches it."""
+
+
+def _home_device(params):
+    """The chip a single-chip engine lives on: where its params already
+    are, else jax's current default device."""
+    for leaf in jax.tree.leaves(params):
+        if isinstance(leaf, jax.Array) and len(leaf.devices()) == 1:
+            return next(iter(leaf.devices()))
+    return next(iter(jnp.zeros(()).devices()))
 
 
 class InferenceEngine:
@@ -354,7 +367,6 @@ class InferenceEngine:
                  rng=None, writer: MetricWriter | None = None,
                  clock: Callable[[], float] = time.monotonic,
                  stall_timeout_s: float | None = None,
-                 compile_cache_dir: str | None = None,
                  chaos=None, tracer=None, trace_tid: int = 0,
                  telemetry=None):
         if stall_timeout_s is not None and stall_timeout_s <= 0:
@@ -489,17 +501,10 @@ class InferenceEngine:
                     "attn_fn for prefill — a model that already carries a "
                     "custom attn_fn would be silently clobbered; pass the "
                     "base model and let the engine compose the ring")
-        # persistent XLA compilation cache (opt-in): warm processes skip
-        # recompiling the engine's program family — the r04→r05 cold-start
-        # regression lever.  Semantics per core/trainer.resolve_compile_
-        # cache_dir ("default" = env/repo-local dir on accelerator
-        # backends, an explicit path always opts in, None = off).
-        if compile_cache_dir is not None:
-            from distributed_tensorflow_ibm_mnist_tpu.core.trainer import (
-                _enable_compile_cache,
-            )
-
-            _enable_compile_cache(compile_cache_dir)
+        # persistent XLA compilation cache: warm processes (and respawned
+        # replicas) skip recompiling the engine's program family.  Placed
+        # from outside — utils/compile_cache.py
+        enable_compile_cache()
         # --- weight-only int8 quantization (ISSUE 12) --- the model
         # clones to its Int8Dense form and the HOST param tree quantizes
         # ONCE here (per-output-channel symmetric scales, models/quant.py)
@@ -526,8 +531,8 @@ class InferenceEngine:
                     f"(the causal-LM family); {type(model).__name__} has "
                     "none") from None
             params = quantize_params_int8(params)
-        # --- tensor/context-parallel mesh (tp=cp=1: every attribute None,
-        # the whole path byte-identical to the single-chip engine) --- the
+        # --- tensor/context-parallel mesh (tp=cp=1: no mesh, the same
+        # programs on one chip — see the else branch) --- the
         # serving half of ROADMAP item 5b: weights column/row-sharded by
         # the SAME Megatron rule the training mesh uses, KV cache sharded
         # over the head axis, one psum per attention block and one per MLP
@@ -554,10 +559,18 @@ class InferenceEngine:
             self._rep = jax.sharding.NamedSharding(
                 self._mesh, jax.sharding.PartitionSpec())
         else:
+            # one chip.  The engine COMMITS its params, cache and uploads
+            # to that chip, as the mesh path commits to its mesh: jit keys
+            # its executables on which inputs are committed, so params that
+            # arrive committed (from_trainer's device_put, a restored
+            # checkpoint) beside uncommitted uploads would compile a second
+            # program per site the first time a device-resident input
+            # replaces an uploaded one — after prewarm(), on a request.
             self._mesh = None
             self._kv_rule = None
-            self._param_shardings = None
-            self._rep = None
+            self._rep = jax.sharding.SingleDeviceSharding(_home_device(params))
+            self._param_shardings = self._rep
+            params = jax.device_put(params, self._rep)
         self.model = model
         self.params = params
         self.slots = slots
@@ -735,6 +748,21 @@ class InferenceEngine:
                     f"cp={cp} needs a model with an attn_fn= field (the "
                     f"causal-LM family); {type(model).__name__} has none"
                 ) from None
+        elif (tp > 1 and getattr(model, "attn", None) == "flash"
+              and getattr(model, "attn_fn", None) is None):
+            # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+            # shard_map" — first seen on the four-chip v5e host, PR 21; the
+            # Pallas interpreter lowers to plain HLO, so the CPU mesh never
+            # showed it).  Attention is head-parallel: the flash prefill
+            # kernel runs as a shard_map island over tp, each chip on its
+            # own H/tp query heads and H_kv/tp K/V heads (whole GQA groups
+            # — tp divides both, checked above).
+            heads = jax.sharding.PartitionSpec(None, None, "tp", None)
+            prefill_model = model.clone(attn_fn=shard_map_compat(
+                functools.partial(
+                    flash_attention, causal=bool(getattr(model, "causal", True)),
+                    window=int(getattr(model, "window", 0))),
+                self._mesh, in_specs=(heads, heads, heads), out_specs=heads))
         else:
             prefill_model = model
         self._prefill = make_prefill(prefill_model, max_len)  # per-bucket shapes
@@ -863,7 +891,7 @@ class InferenceEngine:
                                kv_pages) if kv_page_size
             else cache_shapes(model, params, slots, max_len))
         self._cache_shardings = (
-            None if self._mesh is None else mesh_shardings(
+            self._rep if self._mesh is None else mesh_shardings(
                 self._mesh, make_param_specs(_shapes, self._kv_rule)))
         if kv_page_size:
             self.cache = _zeros_like_shapes(_shapes, self._cache_shardings)
@@ -988,13 +1016,14 @@ class InferenceEngine:
         return f"{name}[cp{self.cp}]"
 
     def _dev(self, x):
-        """Host upload for per-window device inputs.  Single-chip: a plain
-        uncommitted transfer (byte-identical to the pre-tp engine).  Under
-        tp: COMMITTED replicated-on-mesh, so the first dispatch (prewarm)
-        and every serving dispatch present jit the SAME input shardings —
-        one program per site, never a layout-keyed recompile."""
-        x = jnp.asarray(x)
-        return x if self._rep is None else jax.device_put(x, self._rep)
+        """Host upload for per-window device inputs: COMMITTED, to the
+        engine's chip or replicated on its mesh, so the first dispatch
+        (prewarm) and every serving dispatch present jit the SAME input
+        shardings — one program per site, never a layout-keyed recompile.
+        One hop from host memory (no uncommitted intermediate)."""
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        return jax.device_put(x, self._rep)
 
     @property
     def _chip0(self):
@@ -1046,10 +1075,6 @@ class InferenceEngine:
         model = get_model(trainer.config.model,
                           num_classes=trainer.num_classes, **clean_kwargs)
         kw.setdefault("writer", trainer.writer)
-        # inherit the run's persistent-compile-cache choice: the serving
-        # program family is exactly what a warm cache saves (satellite of
-        # ISSUE 7 — the r04→r05 cold-compile regression)
-        kw.setdefault("compile_cache_dir", trainer.config.compile_cache_dir)
         return cls(model, trainer._decode_params(), slots=slots,
                    max_len=max_len, **kw)
 
@@ -2454,13 +2479,11 @@ class InferenceEngine:
             # that already carries int8 kernels passes through unchanged
             # (quantize_params_int8 is idempotent).
             params = quantize_params_int8(params)
-        if self._mesh is not None:
-            # accepts a full host/single-chip tree and re-shards it
-            # wholesale onto THIS engine's mesh (the router's hot-swap
-            # hands every replica the same unsharded checkpoint tree);
-            # an already-correctly-sharded tree is a no-op placement
-            params = jax.device_put(params, self._param_shardings)
-        self.params = params
+        # accepts a full host/single-chip tree and places it wholesale in
+        # THIS engine's layout — its mesh, or its one chip (the router's
+        # hot-swap hands every replica the same unsharded checkpoint
+        # tree); an already-correctly-placed tree is a no-op
+        self.params = jax.device_put(params, self._param_shardings)
         if self._prefix is not None:
             self._prefix.clear()
         if self._radix is not None:
@@ -2474,8 +2497,8 @@ class InferenceEngine:
     def prewarm(self) -> dict:
         """Compile the engine's ENTIRE program family before the first
         request — the launch-path half of the cold-start fix (ROADMAP item
-        5a; the persistent compile cache from ISSUE 7 is the cross-process
-        half, and ``compile_cache_dir=`` makes these compiles land there).
+        5a; the persistent compile cache — utils/compile_cache.py — is the
+        cross-process half: these compiles land there).
 
         Runs each resident program once with zero/dummy inputs on the IDLE
         engine: every bucket's prefill, the shared first-token pick, the
@@ -2595,11 +2618,12 @@ class InferenceEngine:
                 self.params)
             row_cache = jax.tree.map(
                 lambda s: jnp.zeros(s.shape, s.dtype), row_shapes)
-            if self._mesh is not None:
-                # match the layout a REAL prefill's pinned output arrives
-                # in, so prewarm compiles the same insert program serving
-                # reuses
-                row_cache = jax.device_put(row_cache, mesh_shardings(
+            # match the layout (and commitment) a REAL prefill's pinned
+            # output arrives in, so prewarm compiles the same insert
+            # program serving reuses
+            row_cache = jax.device_put(
+                row_cache,
+                self._rep if self._mesh is None else mesh_shardings(
                     self._mesh, make_param_specs(row_shapes, self._kv_rule)))
             if self._pool is not None:
                 bt_row = self._dev(

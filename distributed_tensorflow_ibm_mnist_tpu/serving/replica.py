@@ -21,11 +21,11 @@ Health is a three-state machine, transitions owned by the router:
   :meth:`spawn` a replacement in place.
 
 The factory (``make_engine(trace_tid)``) is the configuration seam: it
-chooses slots/paging/decode-ahead AND ``compile_cache_dir=`` — a factory
-wired to a persistent compile cache makes every respawn warm (the restarted
-replica reuses the program family the first spawn compiled, so bring-up
-drops from whole-family compile time to cache reads; ``spawn_history``
-records the difference).  The ``trace_tid`` argument is the replica's own
+chooses slots/paging/decode-ahead.  Every engine turns on the persistent
+compile cache (utils/compile_cache.py), which makes a respawn warm: the
+restarted replica reuses the program family the first spawn compiled, so
+bring-up drops from whole-family compile time to cache reads
+(``spawn_history`` records the difference).  The ``trace_tid`` argument is the replica's own
 timeline track: all N engines share ONE tracer, and per-replica tracks keep
 their host loops from interleaving on a single lane.
 """

@@ -214,8 +214,9 @@ class Router:
     """Front N engine replicas: see the module docstring.
 
     ``make_engine(trace_tid)`` is the replica factory (serving/replica.py
-    — wire ``compile_cache_dir=`` there for warm respawns, share this
-    router's ``clock`` for deadline coherence, leave ``writer=`` unset).
+    — share this router's ``clock`` for deadline coherence, leave
+    ``writer=`` unset; respawns are warm through the persistent compile
+    cache every engine enables, utils/compile_cache.py).
     A two-parameter factory ``make_engine(trace_tid, replica_index)``
     composes replicas x tensor parallelism: give replica ``i`` the
     ``i``-th disjoint device group from ``parallel.tensor_parallel.
@@ -764,9 +765,9 @@ class Router:
         replica — the launch-path half of ROADMAP item 5a: compile each
         replica's full program family BEFORE the first request, so no
         request anywhere in the tier pays first-use compile as TTFT.
-        When the factory wires ``compile_cache_dir=``, the first replica
-        compiles and the rest (and every later respawn) hit the
-        persistent cache.  Call after construction, before traffic.
+        Where the persistent compile cache is on (utils/compile_cache.py)
+        the first replica compiles and the rest (and every later respawn)
+        hit it.  Call after construction, before traffic.
 
         Returns per-replica prewarm reports keyed by replica index
         (see :meth:`InferenceEngine.prewarm`), plus ``"total_s"``.

@@ -121,12 +121,6 @@ class RunConfig:
     #   steady-state epochs of fit() into this dir (TensorBoard profile
     #   plugin format; utils/profiling).  The first epoch — XLA compile —
     #   is fenced out of the trace when epochs > 1.  CLI: --profile DIR.
-    # Persistent XLA compilation cache: repeat runs skip the one-time compile
-    # (the analog of the reference having no compile stage at all). None
-    # disables; "default" resolves to $DTM_COMPILE_CACHE if set, else
-    # <repo-root>/.cache/xla (falling back to ~/.cache/... when that tree is
-    # not writable, e.g. a system-wide pip install).
-    compile_cache_dir: str | None = "default"
 
     def replace(self, **kw) -> "RunConfig":
         return dataclasses.replace(self, **kw)

@@ -22,9 +22,10 @@ through ``attn_fn`` remain lower bounds.
 
 MFU denominator: the chip's peak matmul throughput at the dtype the model
 computes in (bf16 for the zoo's default).  Peaks are keyed on
-``device_kind`` from public TPU specs; ``$DTM_PEAK_TFLOPS`` overrides for
-kinds not in the table (and is the only option on CPU, where "peak" is
-ill-defined and MFU is reported as None).
+``device_kind`` from public TPU specs.  A TPU whose kind is not in the
+table is an error, not a default; ``$DTM_PEAK_TFLOPS`` overrides the table
+(and is the only option on CPU, where "peak" is ill-defined and MFU is
+reported as None) and must parse as a number.
 """
 
 from __future__ import annotations
@@ -41,7 +42,6 @@ _PEAK_TFLOPS_BF16: dict[str, float] = {
     "TPU v4": 275.0,  # 2-die training chip; device_kind names the chip
     "TPU v5 lite": 197.0,
     "TPU v5e": 197.0,
-    "TPU v5": 229.5,
     "TPU v5p": 459.0,
     "TPU v6 lite": 918.0,
     "TPU v6e": 918.0,
@@ -56,22 +56,29 @@ def device_peak_tflops(device=None) -> float | None:
     "TPU v5 lite podslice" resolve consistently with their base kind
     ("TPU v4 ..." suffixed variants land on the same 275 as the exact
     kind; "TPU v4i" is its own, longer, entry and wins its own prefix);
-    ``$DTM_PEAK_TFLOPS`` wins outright.  Returns None when unknown (CPU,
-    exotic kinds) — callers report MFU as None rather than against a
-    made-up peak.
+    ``$DTM_PEAK_TFLOPS`` wins outright and must be a number.  Off the TPU
+    platform an unknown kind returns None — callers report MFU as None
+    rather than against a made-up peak; ON it, an unknown kind raises: an
+    MFU of None on the machine the number is for hides a missing table row.
     """
     env = os.environ.get("DTM_PEAK_TFLOPS")
     if env:
         try:
             return float(env)
         except ValueError:
-            pass
+            raise ValueError(
+                f"DTM_PEAK_TFLOPS={env!r} is not a number") from None
     device = device or jax.devices()[0]
     kind = str(getattr(device, "device_kind", "")).strip()
     best = None
     for prefix, peak in _PEAK_TFLOPS_BF16.items():
         if kind.startswith(prefix) and (best is None or len(prefix) > best[0]):
             best = (len(prefix), peak)
+    if best is None and getattr(device, "platform", "") == "tpu":
+        raise ValueError(
+            f"no bf16 peak for TPU device_kind {kind!r}: add its published "
+            f"peak to utils/flops._PEAK_TFLOPS_BF16 "
+            f"(known: {sorted(_PEAK_TFLOPS_BF16)})")
     return best[1] if best else None
 
 

@@ -2,82 +2,61 @@
 
 The SURVEY.md §4 test strategy — distributed behavior validated on an
 N-device CPU platform instead of "run it on the cluster to find out" —
-needs N CPU devices *reliably*.  Env vars alone
-(``XLA_FLAGS=--xla_force_host_platform_device_count=N JAX_PLATFORMS=cpu``)
-are not reliable everywhere: site hooks that import jax at interpreter
-start can pin ``jax_platforms`` before user code runs.  This helper arms
-the platform from inside the process, which works in both worlds.
+needs N CPU devices.  From the shell that is
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N``;
+this helper does the same from inside a process (the ``--virtual-devices``
+CLI flag, the multi-device examples, the driver's multichip dry run).
+
+It is never reached from ``Trainer``, ``InferenceEngine``, ``bench.py``'s
+chip phases or ``chip_smoke.py``: a program that wants the chip must not
+quietly leave it for virtual CPUs.  Where a caller does leave an
+initialized accelerator backend, this says so on stderr.
 """
 
 from __future__ import annotations
 
+import sys
+
 
 def backends_initialized() -> bool:
-    """True once jax has built its backend clients (version-compat probe).
+    """True once jax has built its backend clients.
 
     Unlike ``jax.devices()`` this never triggers initialization itself —
-    which matters because XLA parses its flag env exactly once, at first
-    client creation.
+    so a caller can go straight to the CPU platform without first opening
+    (and then discarding) the TPU client.
     """
     from jax._src import xla_bridge as xb
 
-    if hasattr(xb, "backends_are_initialized"):
-        return xb.backends_are_initialized()
-    return bool(getattr(xb, "_backends", None))
+    return xb.backends_are_initialized()
 
 
 def ensure_virtual_cpu_devices(n: int) -> int:
     """Force jax onto an ``n``-device (or more) CPU platform.
 
-    Safe to call before or after ``import jax``; if backends were already
-    initialized with too few devices they are cleared and rebuilt, which
-    invalidates any live jax arrays created before the call.  Returns the
-    resulting device count.
+    Safe to call before or after ``import jax``.  Before the backends
+    exist it only sets the platform options — no accelerator client is
+    ever opened.  If backends were already initialized with too few CPU
+    devices, or on an accelerator, they are cleared and rebuilt, which
+    invalidates any live jax arrays created before the call; leaving an
+    accelerator is announced on stderr.  Returns the resulting device
+    count.
     """
     import jax
 
-    initialized = backends_initialized()
-    if initialized and jax.default_backend() == "cpu" and len(jax.devices()) >= n:
-        return len(jax.devices())
-    if initialized:
+    if backends_initialized():
+        devices = jax.devices()
+        platform = devices[0].platform
+        if platform == "cpu" and len(devices) >= n:
+            return len(devices)
+        if platform != "cpu":
+            print(
+                f"hostmesh: leaving the initialized {platform!r} backend "
+                f"({len(devices)} device(s)) for {n} virtual CPU devices — "
+                "nothing after this point runs on the accelerator",
+                file=sys.stderr, flush=True)
         import jax.extend as jex
 
         jex.backend.clear_backends()
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-        jax.config.update("jax_platforms", "cpu")
-        return len(jax.devices())
-    except AttributeError:
-        pass
-    # pre-0.5 jax has no jax_num_cpu_devices, and the C++ layer parses
-    # XLA_FLAGS exactly once per process — once a too-small backend was
-    # built, no in-process rebuild can widen it.  Arm the env and re-exec
-    # the script (marker env guards against a loop); if re-exec is not
-    # possible (interactive session, argv gone) fall through and report
-    # the count we actually have so callers can degrade explicitly.
-    import os
-    import sys
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    if (
-        os.environ.get("_DTM_HOSTMESH_REEXEC") != "1"
-        and getattr(sys, "argv", None)
-        and sys.argv[0]
-        and os.path.exists(sys.argv[0])
-    ):
-        os.environ["_DTM_HOSTMESH_REEXEC"] = "1"
-        # under `python -m pkg.mod`, argv[0] is the module FILE and the
-        # re-exec runs it in script mode, which would drop the package
-        # root off sys.path — carry the live path so imports resolve
-        # identically in the re-exec'd process
-        os.environ["PYTHONPATH"] = os.pathsep.join(
-            dict.fromkeys(p or os.getcwd() for p in sys.path)
-        )
-        os.execv(sys.executable, [sys.executable] + sys.argv)
+    jax.config.update("jax_num_cpu_devices", n)
     jax.config.update("jax_platforms", "cpu")
     return len(jax.devices())

@@ -21,10 +21,10 @@ The pieces:
   ``summary()`` folds the buffer into one strict-JSON dict.
 * :class:`CompileTracker` — process-global accounting of XLA compilations
   via ``jax.monitoring``'s ``/jax/core/compile/backend_compile_duration``
-  event (one firing per compiled program; cache hits don't fire), each
-  attributed to the SITE active at compile time (``with tracker.site(
-  "prefill[b32]")``).  Falls back to a count-only ``jax_log_compiles``
-  logging tap when the monitoring API is unavailable.  This is what makes
+  event (one firing per program the process had to obtain; in-process jit
+  cache hits don't fire), each attributed to the SITE active at compile
+  time (``with tracker.site("prefill[b32]")``), plus a count of how many
+  of them the persistent compilation cache served.  This is what makes
   "number of distinct compiled programs" a tracked bench metric — the
   r04→r05 cold-compile regression (ROADMAP item 5) becomes reproducible
   and regression-gated per-PR.
@@ -882,13 +882,19 @@ def trace_forest(doc: dict) -> dict:
 class CompileTracker:
     """Process-global XLA compile accounting with per-site attribution.
 
-    ``install()`` registers ONE ``jax.monitoring`` duration listener per
-    process (listeners cannot be unregistered individually, so the tracker
-    is a singleton — everything downstream reads snapshot DELTAS, never
-    absolute counts).  Each ``/jax/core/compile/backend_compile_duration``
-    firing is one compiled XLA program: cache hits (in-process jit cache
-    or the persistent compilation cache) do not fire, which is exactly the
-    "distinct compiled programs" figure ROADMAP item 5 wants gated.
+    ``install()`` registers ONE pair of ``jax.monitoring`` listeners per
+    process (the tracker is a singleton — everything downstream reads
+    snapshot DELTAS, never absolute counts).  Each
+    ``/jax/core/compile/backend_compile_duration`` firing is one XLA
+    program this process had to obtain: an in-process jit cache hit does
+    not fire, which is exactly the "distinct compiled programs" figure
+    ROADMAP item 5 wants gated.  On the installed jax the event wraps the
+    persistent-cache lookup, so a program a WARM persistent cache serves
+    fires too (its duration is the retrieval time): the program count is
+    the same cold or warm (58 and 58 for the LeNet run on the v5e, PR 21)
+    and only ``compile_time_s`` shrinks.  Each
+    ``/jax/compilation_cache/cache_hits`` firing marks one of those
+    programs as served by the persistent cache (``persistent_cache_hits``).
 
     Attribution: the innermost active ``with tracker.site("label")``
     (thread-local stack) owns compilations fired inside it; outside any
@@ -896,11 +902,6 @@ class CompileTracker:
     family (``prefill[b<bucket>]``, ``decode_window[k<k>]``, ...), the
     trainer its step variants — so a program-family explosion names the
     site that grew.
-
-    Fallback: where ``jax.monitoring`` is missing the tracker taps jax's
-    ``jax_log_compiles`` logger instead — counts only (``compile_time_s``
-    stays 0.0); ``self.mode`` records which path is live ("monitoring",
-    "log_compiles", or "unavailable").
     """
 
     _instance: "CompileTracker | None" = None
@@ -909,8 +910,8 @@ class CompileTracker:
     def __init__(self):
         self.n = 0
         self.time_s = 0.0
+        self.cache_hits = 0
         self.by_site: dict[str, dict[str, float]] = {}
-        self.mode = "unavailable"
         self._tl = threading.local()
         self._mu = threading.Lock()
         self._tracer: Tracer | None = None
@@ -926,45 +927,20 @@ class CompileTracker:
             return cls._instance
 
     def _register(self) -> None:
-        try:
-            import jax.monitoring
+        import jax.monitoring
 
-            jax.monitoring.register_event_duration_secs_listener(
-                self._on_duration)
-            self.mode = "monitoring"
-            return
-        except Exception:
-            pass
-        try:  # count-only fallback: tap the jax_log_compiles logger
-            import logging
-
-            import jax
-
-            jax.config.update("jax_log_compiles", True)
-
-            tracker = self
-
-            class _Tap(logging.Handler):
-                def emit(self, record):
-                    try:
-                        if "Compiling" in record.getMessage():
-                            tracker._record(0.0)
-                    except Exception:
-                        pass
-
-            logging.getLogger("jax._src.dispatch").addHandler(_Tap())
-            logging.getLogger("jax._src.interpreters.pjit").addHandler(_Tap())
-            self.mode = "log_compiles"
-        except Exception:
-            self.mode = "unavailable"
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
 
     def _on_duration(self, name: str, secs: float, **kw) -> None:
         # one firing per compiled XLA program; everything else ignored
-        try:
-            if name == "/jax/core/compile/backend_compile_duration":
-                self._record(float(secs))
-        except Exception:
-            pass  # a broken listener must never break a compile
+        if name == "/jax/core/compile/backend_compile_duration":
+            self._record(float(secs))
+
+    def _on_event(self, name: str, **kw) -> None:
+        if name == "/jax/compilation_cache/cache_hits":
+            with self._mu:
+                self.cache_hits += 1
 
     def _record(self, secs: float) -> None:
         stack = getattr(self._tl, "stack", None)
@@ -1001,11 +977,13 @@ class CompileTracker:
 
     def snapshot(self) -> dict:
         """Monotonic totals since install: ``{"n_compiled_programs",
-        "compile_time_s", "by_site"}`` (strict JSON; copy, not a view)."""
+        "compile_time_s", "persistent_cache_hits", "by_site"}`` (strict
+        JSON; copy, not a view)."""
         with self._mu:
             return {
                 "n_compiled_programs": self.n,
                 "compile_time_s": round(self.time_s, 6),
+                "persistent_cache_hits": self.cache_hits,
                 "by_site": {
                     k: {"n": v["n"], "time_s": round(v["time_s"], 6)}
                     for k, v in self.by_site.items()
@@ -1029,6 +1007,9 @@ class CompileTracker:
                 after["n_compiled_programs"] - before["n_compiled_programs"]),
             "compile_time_s": round(
                 after["compile_time_s"] - before["compile_time_s"], 6),
+            "persistent_cache_hits": (
+                after.get("persistent_cache_hits", 0)
+                - before.get("persistent_cache_hits", 0)),
             "by_site": by_site,
         }
 

@@ -65,5 +65,5 @@ if __name__ == "__main__":
     print(f"experts: w1 {w1.shape} sharded {w1.sharding.spec} "
           f"({w1.shape[0] // 8} experts owned per device)")
     s = t.fit()
-    print(f"moe fit: acc {s['best_test_accuracy']:.3f} "
+    print(f"moe fit on {jax.default_backend()}: acc {s['best_test_accuracy']:.3f} "
           f"({s['images_per_sec']:.0f} img/s across {t.n_chips} devices)")

@@ -51,7 +51,7 @@ if __name__ == "__main__":
     local = sum(next(iter(leaf.addressable_shards)).data.size for leaf in bucket_leaves)
     total = sum(leaf.size for leaf in bucket_leaves)
     print(
-        f"\n{n}-way DP with sharded update: "
+        f"\n{n}-way DP with sharded update on {jax.default_backend()}: "
         f"{summary['images_per_sec']:.0f} images/sec, "
         f"best acc {summary['best_test_accuracy']:.4f}\n"
         f"buckets: {layout.bucket_sizes} ({len(layout.slots)} param leaves "

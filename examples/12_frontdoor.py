@@ -29,7 +29,6 @@ parts that transfer to a real checkpoint.
 """
 
 import sys
-import tempfile
 import threading
 from pathlib import Path
 
@@ -49,6 +48,7 @@ from distributed_tensorflow_ibm_mnist_tpu.serving import (
     Router,
     ServingDaemon,
 )
+from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import enable_compile_cache
 
 VOCAB = 16
 MAX_LEN = 16
@@ -62,14 +62,14 @@ def main():
     # the persistent compile cache is what makes the autoscaler's
     # respawns warm: replica 0's prewarm populates it, every later
     # spawn reads it back instead of recompiling
-    cache_dir = tempfile.mkdtemp(prefix="dtm_frontdoor_xc_")
+    enable_compile_cache(cpu=True)
 
     def make_engine(tid):
         return InferenceEngine(
             model, params, slots=2, max_len=MAX_LEN, kv_page_size=4,
             scheduler=FIFOScheduler(max_len=MAX_LEN, buckets=(8,),
                                     max_queue=64),
-            trace_tid=tid, compile_cache_dir=cache_dir)
+            trace_tid=tid)
 
     router = Router(make_engine, 1)
     router.prewarm()

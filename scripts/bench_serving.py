@@ -78,9 +78,9 @@ Two more legs (ISSUE 7, paged KV):
   ``CENSUS_BUDGET`` and the bench exits nonzero (status 3) when any leg
   exceeds its budget — a new program sneaking into the serving path fails
   CI instead of silently inflating compile time.
-* **compile_cache** — the opt-in persistent compilation cache
-  (``compile_cache_dir=`` / ``train.py --compile-cache-dir``) measured
-  honestly: SUBPROCESSES share a temp cache dir (an in-process rerun
+* **compile_cache** — the persistent compilation cache
+  (utils/compile_cache.py) measured honestly: SUBPROCESSES share one
+  emptied-first cache dir (an in-process rerun
   would hit jax's in-memory jit cache and prove nothing); the cold run
   populates the dir, the warm run must add no files, and cold-vs-warm
   compile seconds come from each process's own CompileTracker.  A third
@@ -137,6 +137,10 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+
+from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import (  # noqa: E402
+    compile_cache_dir,
+)
 
 QUICK = os.environ.get("DTM_BENCH_QUICK", "") not in ("", "0")
 
@@ -686,7 +690,7 @@ def run_chunked(slots: int, requests: int) -> dict:
         "ttft_p99_x": round(ttft_x, 3),
         "ttft_target_x": TTFT_HELD_X,
         "output_mismatches": mismatches,  # MUST be 0 (chunked parity)
-        "census": {"legs": census, "mode": tracker.mode,
+        "census": {"legs": census,
                    "budget": {k: CENSUS_BUDGET[k] for k in census},
                    "over_budget": census_over},
         "gates": gates,
@@ -916,7 +920,6 @@ def run_compile_census(slots: int) -> dict:
             over[f"spec_cold:{site}"] = n - budget
     return {
         "legs": legs,
-        "mode": tracker.mode,
         "budget": CENSUS_BUDGET,
         "spec_site_budget": SPEC_SITE_BUDGET,
         # the regression gate: any leg over its pinned budget fails the
@@ -939,11 +942,12 @@ def run_compile_census(slots: int) -> dict:
     }
 
 
-def _compile_cache_probe(cache_dir: str, prewarm: bool = False) -> None:
-    """Subprocess mode (``--compile-cache-probe DIR``): build ONE engine
-    with the persistent XLA compile cache at DIR, serve two requests, and
-    print the engine's compile accounting as JSON.  Run three times
-    against the same DIR by :func:`run_compile_cache`: the first call
+def _compile_cache_probe(prewarm: bool = False) -> None:
+    """Subprocess mode (``--compile-cache-probe``): build ONE engine with
+    the persistent XLA compile cache on (placed by the parent through
+    ``JAX_COMPILATION_CACHE_DIR``), serve two requests, and print the
+    engine's compile accounting as JSON.  Run three times against the
+    same directory by :func:`run_compile_cache`: the first call
     populates the cache, the second measures what a warm process actually
     saves — cross-PROCESS, which is the regression the cache exists to
     fix (an in-process rerun would hit jax's in-memory jit cache and
@@ -953,7 +957,7 @@ def _compile_cache_probe(cache_dir: str, prewarm: bool = False) -> None:
     compile moved before traffic.  Uses the bench's PRIMARY model: the
     persistent cache only stores programs above
     ``jax_persistent_cache_min_compile_time_secs`` (0.1 s —
-    core/trainer._enable_compile_cache), and the toy models' programs all
+    utils/compile_cache.py), and the toy models' programs all
     compile under that floor, honestly measuring nothing."""
     from distributed_tensorflow_ibm_mnist_tpu.models import get_model
     from distributed_tensorflow_ibm_mnist_tpu.serving import (
@@ -969,7 +973,6 @@ def _compile_cache_probe(cache_dir: str, prewarm: bool = False) -> None:
     t0 = time.perf_counter()
     eng = InferenceEngine(
         model, params, slots=2, max_len=max_len,
-        compile_cache_dir=cache_dir,
         scheduler=FIFOScheduler(max_len=max_len, buckets=(16,), max_queue=4))
     # the production threshold (0.1 s) is tuned for accelerator-scale
     # programs; this host's XLA:CPU backend-compiles each engine program
@@ -992,7 +995,7 @@ def _compile_cache_probe(cache_dir: str, prewarm: bool = False) -> None:
         "wall_s": round(time.perf_counter() - t0, 4),
         "compile_s": s["compile_time_s"],
         "n_programs": s["n_compiled_programs"],
-        "n_cache_files": len(os.listdir(cache_dir)),
+        "n_cache_files": len(os.listdir(compile_cache_dir())),
         # first request's TTFT: with --prewarm every program was compiled
         # before the submit, so this is pure serving latency; without, it
         # eats the first-use compiles — the cold-vs-prewarmed delta the
@@ -1004,25 +1007,29 @@ def _compile_cache_probe(cache_dir: str, prewarm: bool = False) -> None:
 
 def run_compile_cache(timeout_s: float = 600.0) -> dict:
     """ISSUE 7 satellite: cold-vs-warm compile seconds through the opt-in
-    persistent compilation cache (``compile_cache_dir=`` on the engine /
-    ``compile_cache_dir`` in RunConfig).  Two subprocess probes share one
-    ephemeral cache dir; the report is honest about the delta it actually
-    measured — ``cache_effective`` is a measurement, not an assertion
-    (CPU-backend cacheability varies across jax versions)."""
+    persistent compilation cache (utils/compile_cache.py).  Three
+    subprocess probes share one directory — a fixed ``bench_serving_probe``
+    under the process's cache directory, emptied first so the first probe
+    is cold; the report is honest about the delta it actually measured —
+    ``cache_effective`` is a measurement, not an assertion (CPU-backend
+    cacheability varies across jax versions)."""
+    import shutil
     import subprocess
-    import tempfile
 
-    with tempfile.TemporaryDirectory(prefix="dtm-compile-cache-") as d:
-        runs = []
-        for extra in ((), (), ("--prewarm",)):
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "--compile-cache-probe", d, *extra],
-                capture_output=True, text=True, timeout=timeout_s,
-                env={**os.environ, "JAX_PLATFORMS": "cpu"})
-            if proc.returncode != 0:
-                return {"error": (proc.stderr or proc.stdout).strip()[-400:]}
-            runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+    d = os.path.join(compile_cache_dir(), "bench_serving_probe")
+    shutil.rmtree(d, ignore_errors=True)
+    os.makedirs(d)
+    runs = []
+    for extra in ((), (), ("--prewarm",)):
+        proc = subprocess.run(
+            [sys.executable, os.path.abspath(__file__),
+             "--compile-cache-probe", *extra],
+            capture_output=True, text=True, timeout=timeout_s,
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": d})
+        if proc.returncode != 0:
+            return {"error": (proc.stderr or proc.stdout).strip()[-400:]}
+        runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
     cold, warm, prewarmed = runs
     return {
         "cold_wall_s": cold["wall_s"],
@@ -1326,10 +1333,11 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--compile-cache-probe", metavar="DIR", default=None,
+    ap.add_argument("--compile-cache-probe", action="store_true",
                     help="internal: run one engine against the persistent "
-                         "compile cache at DIR and print its compile "
-                         "accounting (spawned by the compile_cache leg)")
+                         "compile cache at $JAX_COMPILATION_CACHE_DIR and "
+                         "print its compile accounting (spawned by the "
+                         "compile_cache leg)")
     ap.add_argument("--prewarm", action="store_true",
                     help="internal: with --compile-cache-probe, call "
                          "engine.prewarm() before the first submit")
@@ -1345,8 +1353,8 @@ def main() -> None:
                          "print its own JSON record — bench.py's "
                          "`chunked_prefill` block")
     args = ap.parse_args()
-    if args.compile_cache_probe is not None:
-        _compile_cache_probe(args.compile_cache_probe, prewarm=args.prewarm)
+    if args.compile_cache_probe:
+        _compile_cache_probe(prewarm=args.prewarm)
         return
     if QUICK:
         args.requests = min(args.requests, 10)

@@ -92,7 +92,7 @@ def _mk_prompts(seed: int, n: int):
             for i in range(n)]
 
 
-def _build(chaos=None, tracer=None, cache_dir=None):
+def _build(chaos=None, tracer=None):
     from distributed_tensorflow_ibm_mnist_tpu.models import get_model
     from distributed_tensorflow_ibm_mnist_tpu.serving import (
         FIFOScheduler,
@@ -109,8 +109,7 @@ def _build(chaos=None, tracer=None, cache_dir=None):
             model, params,
             scheduler=FIFOScheduler(max_len=ENGINE_KW["max_len"],
                                     buckets=BUCKETS, max_queue=64),
-            tracer=tracer, trace_tid=tid, chaos=chaos,
-            compile_cache_dir=cache_dir, **ENGINE_KW)
+            tracer=tracer, trace_tid=tid, chaos=chaos, **ENGINE_KW)
 
     router = Router(make_engine, N_REPLICAS, chaos=chaos, tracer=tracer)
     router.prewarm()   # no request pays first-use compile as TTFT
@@ -301,7 +300,6 @@ def _autoscaler_leg(rate: float, p50: float) -> dict:
     elastic scale-up is genuinely WARM: the restarted replica's programs
     come from cache, and its bring-up cost is the measured ``spawn_s``
     the TTFT-penalty gate is bounded by."""
-    import tempfile
     import time as _time
 
     from distributed_tensorflow_ibm_mnist_tpu.serving import (
@@ -322,10 +320,14 @@ def _autoscaler_leg(rate: float, p50: float) -> dict:
                      burst_len_s=AUTO_BURST_LEN_S,
                      prompt_len=(2, 6), max_new=(2, 4)),
         interactive_ttft_slo_s=20.0 * p50, batch_ttft_slo_s=40.0 * p50)
-    cache_dir = tempfile.mkdtemp(prefix="dtm_autoscale_xc_")
+    from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache(cpu=True)
 
     def _drive(elastic: bool) -> dict:
-        router = _build(cache_dir=cache_dir)
+        router = _build()
         daemon = ServingDaemon(router, max_queue=256,
                                liveness_timeout_s=30.0).start()
         asc = None

@@ -160,7 +160,6 @@ def main() -> None:
     from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import Tracer
 
     root = tempfile.mkdtemp(prefix="router_soak_")
-    xc_dir = os.path.join(root, "xc")          # persistent compile cache
 
     # --- phase 1: W1 + references + calibration (no chaos anywhere yet)
     cfg, t1 = train_w1(root)
@@ -188,13 +187,19 @@ def main() -> None:
     writer = MetricWriter(path=os.path.join(root, "metrics.jsonl"),
                           stdout=False)
 
+    from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache(cpu=True)
+
     def make_engine(tid):
         eng = _engine(model, w1, stall_timeout_s=None,  # raw raise => failover
-                      compile_cache_dir=xc_dir, chaos=inj,
-                      tracer=tracer, trace_tid=tid)
+                      chaos=inj, tracer=tracer, trace_tid=tid)
         # warm INSIDE the factory so spawn_s includes the compile family:
-        # the first spawn pays cold compiles (and writes the persistent
-        # cache), every later spawn reads it back — the cold-vs-warm figure
+        # on an empty cache the first spawn pays cold compiles (and writes
+        # the persistent cache), every later spawn reads it back — the
+        # cold-vs-warm figure
         eng.submit(WARM_PROMPT, max_new=WARM_NEW)
         while eng.has_work:
             eng.step()
@@ -303,8 +308,9 @@ def main() -> None:
         },
         "wave2": {"n": len(wave2), "identical": wave2_identical},
         "bringup": {
-            # replica 0's first spawn compiled cold and wrote the cache;
-            # every other spawn (replicas 1-2, the restart) read it back
+            # replica 0's first spawn compiled (cold when the cache dir
+            # started empty) and wrote the cache; every other spawn
+            # (replicas 1-2, the restart) read it back
             "cold_spawn_s": round(spawn_hist[0][0], 3),
             "warm_spawn_s": [round(s, 3)
                              for i, hist in enumerate(spawn_hist)

@@ -165,6 +165,20 @@ def test_engine_parity_across_decode_ahead():
     assert windows[8] < windows[4] < windows[2] < windows[1]
 
 
+def _mid_window_stop(stream, windows, avoid=()):
+    """Index of the first token of ``stream`` that has not occurred earlier,
+    is not in ``avoid``, and does NOT end a decode window for any k in
+    ``windows`` (token 0 comes from prefill; a k-window then covers indices
+    1..k, k+1..2k, ...) — so arming it as EOS provably retires the row
+    mid-window whatever the installation's random numbers made the stream.
+    None if the stream offers no such token."""
+    for i, t in enumerate(stream):
+        if (i >= 1 and t not in stream[:i] and t not in avoid
+                and all(i % k for k in windows)):
+            return i
+    return None
+
+
 def test_eos_budget_retire_mid_window_and_waste_accounting():
     """A row stopping mid-window (EOS or budget) keeps tokens up to and
     including the stop, discards the ≤k−1 overrun, and the discard shows
@@ -176,9 +190,12 @@ def test_eos_budget_retire_mid_window_and_waste_accounting():
     rb = base.submit(prompt, max_new=9)
     base.run()
 
-    # eos_id chosen as the greedy 4th token -> retirement mid-window
-    eos = int(rb.generated[3])
-    stop_at = next(i for i, t in enumerate(rb.generated) if t == eos)
+    # eos_id chosen so the stop lands mid-window for both k below
+    stream = [int(t) for t in rb.generated]
+    stop_at = _mid_window_stop(stream, windows=(4, 8))
+    if stop_at is None:
+        pytest.skip(f"greedy stream {stream} has no fresh token mid-window")
+    eos = stream[stop_at]
 
     # with eos_id set, ANY k must emit the base stream truncated at the
     # first EOS (inclusive) — no separate k=1-with-eos engine needed
@@ -476,18 +493,17 @@ def test_bench_serving_quick_smoke():
     assert pc["prefills_skipped"] > 0
     assert rec["engine_over_static"] is not None
     # ISSUE 6 legs: the compile census must show repeats compiling zero
-    # new programs and the new bucket compiling some (when the compile
-    # hook is available at all), and the tracer-overhead leg must report
+    # new programs and the new bucket compiling some, and the
+    # tracer-overhead leg must report
     # a finite comparison (the <=2% budget itself is a bench figure — a
     # loaded CI host can't pin a 2% wall-clock delta reliably)
     census = rec["compile_census"]
-    if census["mode"] != "unavailable":
-        assert census["repeat_compiles_zero"] is True
-        assert census["new_bucket_compiles"] is True
-        assert census["legs"]["bucket16_first"]["n_new_programs"] > 0
-        # ISSUE 7: pinned-budget regression gate (a breach exits the
-        # bench nonzero, so returncode==0 above already implies this)
-        assert census["census_ok"] is True, census["over_budget"]
+    assert census["repeat_compiles_zero"] is True
+    assert census["new_bucket_compiles"] is True
+    assert census["legs"]["bucket16_first"]["n_new_programs"] > 0
+    # ISSUE 7: pinned-budget regression gate (a breach exits the
+    # bench nonzero, so returncode==0 above already implies this)
+    assert census["census_ok"] is True, census["over_budget"]
     # ISSUE 7 satellite: the persistent-compile-cache leg ran its two
     # subprocess probes; cache_effective stays a reported measurement,
     # not an assertion (CPU cacheability varies across jax versions)

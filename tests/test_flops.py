@@ -37,6 +37,22 @@ def test_peak_tflops_unknown_cpu(monkeypatch):
     assert mfu(1e12) is None
 
 
+def test_peak_tflops_refuses_malformed_override_and_unknown_tpu(monkeypatch):
+    monkeypatch.setenv("DTM_PEAK_TFLOPS", "fast")
+    with pytest.raises(ValueError, match="DTM_PEAK_TFLOPS"):
+        device_peak_tflops()
+    monkeypatch.delenv("DTM_PEAK_TFLOPS")
+
+    class Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    assert device_peak_tflops(Dev()) == 197.0  # the v5e's own report: exact hit
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peak_tflops(Dev())
+
+
 def test_mfu_fraction(monkeypatch):
     monkeypatch.setenv("DTM_PEAK_TFLOPS", "100")
     assert abs(mfu(50e12) - 0.5) < 1e-9
@@ -159,6 +175,10 @@ def test_measure_throughput_public_api(monkeypatch):
     assert np.isfinite(out["last_loss"])
     assert out["model_tflops_per_sec_per_chip"] > 0
     assert 0 < out["mfu"] < 1
+    # "untouched" includes the state's commitment: the fit() that follows
+    # (bench.py's order) must reuse the epoch program, not recompile it
+    summary = t.fit()
+    assert not [s for s in summary["compile_by_site"] if s.startswith("train_epoch")]
 
 
 def test_measure_throughput_no_full_state_host_gather(eight_devices):

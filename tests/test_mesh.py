@@ -187,3 +187,27 @@ def test_make_mesh_multislice_positive_branch(eight_devices):
         # model-axis neighbors NEVER cross slices
         for i in range(4):
             assert grid[i, 0, 0, 0].slice_index == grid[i, 1, 0, 0].slice_index
+
+
+def test_tpu_create_device_mesh_failure_is_raised(monkeypatch):
+    """A create_device_mesh failure on the full set of real chips must
+    surface: a silent fall to list order would change which ICI links each
+    axis rides."""
+
+    class FakeTpu:
+        platform = "tpu"
+
+        def __init__(self, i):
+            self.id = i
+            self.coords = (i, 0, 0)
+
+    fakes = [FakeTpu(i) for i in range(4)]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: fakes)
+    from jax.experimental import mesh_utils
+
+    def boom(shape, devices=None):
+        raise NotImplementedError("unknown topology")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", boom)
+    with pytest.raises(NotImplementedError, match="unknown topology"):
+        mesh_mod._device_grid((4, 1, 1, 1), fakes)

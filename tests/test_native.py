@@ -1,8 +1,9 @@
 """Native C++ data pipeline: gather/renderer/prefetcher vs numpy truth.
 
-These tests compile the library on first run (cached after).  If no C++
-toolchain exists, the bindings must fall back silently — exercised by the
-DTM_DISABLE_NATIVE path test.
+These tests compile the library on first run (cached after, under a name
+keyed on the source's content).  Without a working C++ toolchain the
+bindings fall back to numpy with a warning; the fallback itself is
+exercised by the disabled-library test.
 """
 
 import numpy as np
@@ -118,3 +119,30 @@ def test_fallback_without_native(monkeypatch):
         got = list(pf)
     assert len(got) == 3
     np.testing.assert_array_equal(got[1][0], src[16:32])
+
+
+def test_build_is_keyed_on_source_content_and_failure_is_loud(monkeypatch, tmp_path):
+    """The loaded library's name carries the hash of native/dtm.cpp (a stale
+    or foreign binary under another name is never picked up), and a build
+    that fails says so instead of silently taking the numpy path."""
+    import hashlib
+
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native._so_path().name == f"libdtm-{digest}.so"
+    if native.available():
+        assert native.status() == {
+            "path": "native", "library": str(native._so_path())}
+
+    bad = tmp_path / "dtm.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", bad)
+    monkeypatch.setattr(native, "_BUILD_DIR", tmp_path / "build")
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_TRIED", False)
+    monkeypatch.setattr(native, "_WHY_NOT", None)
+    monkeypatch.delenv("DTM_DISABLE_NATIVE", raising=False)
+    with pytest.warns(RuntimeWarning, match="numpy path in use"):
+        assert not native.available()
+    st = native.status()
+    assert st["path"] == "numpy" and st["reason"]
+    assert not list((tmp_path / "build").glob("*.so"))

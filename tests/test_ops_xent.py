@@ -95,3 +95,37 @@ def test_train_step_with_fused_xent_matches_reference_loss():
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5),
         s_fused.params, s_ref.params,
     )
+
+
+def test_row_tile_is_sized_to_vmem_and_oversize_rows_are_refused():
+    """The tile choice that Mosaic refused on the chip (256 rows x an LM
+    vocabulary = a 32 MiB block) is sized down; a class row too wide for
+    even 8 rows is refused with its size, not sent to another path."""
+    from distributed_tensorflow_ibm_mnist_tpu.ops import xent
+
+    assert xent._row_tile(1024, 128) == 256            # small classes: unchanged
+    assert xent._row_tile(2048, 32768) == 8            # 8 x 32768 x 4 B = 1 MiB
+    assert xent._call_params(8, 32768, interpret=False) == {"interpret": False}
+    wide = xent._call_params(8, 131072, interpret=False)["compiler_params"]
+    assert wide.vmem_limit_bytes == 10 * 8 * 131072 * 4
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        xent._call_params(8, 1 << 20, interpret=False)
+    assert xent._call_params(8, 1 << 20, interpret=True) == {"interpret": True}
+
+
+def test_kernel_off_tpu_without_explicit_interpret_is_an_error():
+    """Interpret mode is the caller's choice (ops/interpret.py), never a
+    guess from the backend: with the process-wide switch off, a kernel on
+    the CPU backend raises naming the backend; interpret=True still runs."""
+    from distributed_tensorflow_ibm_mnist_tpu.ops import interpret
+
+    logits, labels = _rand(8, 10)
+    interpret.set_interpret(False)
+    try:
+        with pytest.raises(RuntimeError, match="'cpu' backend"):
+            softmax_xent(logits, labels)
+        got = softmax_xent(logits, labels, interpret=True)
+    finally:
+        interpret.set_interpret(True)
+    want = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)

@@ -352,9 +352,13 @@ def test_engine_emits_serving_record_through_metric_writer(tmp_path):
 def test_engine_from_trainer_end_to_end():
     """InferenceEngine.from_trainer serves a trained run through the same
     clean decode model + cast params Trainer.generate uses — outputs match
-    trainer.generate token for token."""
+    trainer.generate token for token.  The trainer's decode params arrive
+    COMMITTED to their device: prewarm() must still cover every program a
+    stream dispatches (the engine commits its own state beside them — with
+    uncommitted uploads the second window and the first insert recompiled)."""
     from distributed_tensorflow_ibm_mnist_tpu.core.trainer import Trainer
     from distributed_tensorflow_ibm_mnist_tpu.utils.config import RunConfig
+    from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import CompileTracker
 
     cfg = RunConfig(
         name="serve", model="causal_lm",
@@ -368,9 +372,16 @@ def test_engine_from_trainer_end_to_end():
         eng = InferenceEngine.from_trainer(
             t, slots=2, max_len=24,
             scheduler=FIFOScheduler(max_len=24, buckets=(8,)))
+        assert all(leaf.committed for leaf in jax.tree.leaves(eng.params))
+        eng.prewarm()
+        warm = eng._compile.snapshot()
         prompt = np.asarray([2, 9, 4, 7], np.int32)
         req = eng.submit(prompt, max_new=8)
+        more = [eng.submit(prompt[:n], max_new=3) for n in (1, 2, 3)]
         eng.run()
+        assert all(r.status == "done" for r in more)
+        post = CompileTracker.delta(eng._compile.snapshot(), warm)
+        assert post["n_compiled_programs"] == 0, post["by_site"]
         want = np.asarray(t.generate(jnp.asarray(prompt)[None, :], max_new=8,
                                      max_len=24))[0, 4:]
         np.testing.assert_array_equal(np.asarray(req.generated), want)

@@ -199,6 +199,23 @@ def test_prewarm_under_tp_then_zero_serving_compiles(native):
     eng.close()
 
 
+def test_flash_prefill_under_tp_is_a_head_sharded_island(eight_devices):
+    """A model whose prefill attention is the Pallas flash kernel serves
+    under tp through a shard_map island over the head axis (GSPMD cannot
+    partition a Mosaic call — the four-chip v5e refused it; interpreted
+    here, the island must still give the tp=1 tokens, GQA included)."""
+    model, params = _model_and_params(attn="flash", heads_kv=2)
+    want = _serve(model, params)
+    assert _serve(model, params, tp=2) == want
+    eng = _engine(model, params, tp=2)
+    assert eng._prefill is not None and eng.model.attn == "flash"
+    hlo = jax.jit(lambda p, t, n: eng._prefill(p, t, n)[1]).lower(
+        eng.params, jnp.zeros((1, 16), jnp.int32),
+        jnp.asarray([4], jnp.int32)).as_text()
+    assert "shard_map" in hlo or "manual" in hlo.lower()
+    eng.close()
+
+
 def test_swap_params_reshards_host_tree_under_tp(native, refs):
     """swap_params at tp=2 with a full HOST (numpy) tree from a different
     seed: the engine re-shards it wholesale and serves the new weights'
