@@ -1,0 +1,10 @@
+"""Chip 0's idle time while the engine admitted requests
+(``engine.admit``: pops, inline prefill dispatches, landings, first picks),
+as a percentage of the traced window.  With the three other shares it sums to
+``device_idle_share`` less the lead-in and lead-out of the traced window."""
+
+from benchmark import host_spans
+
+
+def read(r):
+    return host_spans.idle_share(r, "admit")

@@ -232,7 +232,7 @@ from distributed_tensorflow_ibm_mnist_tpu.serving.scheduler import FIFOScheduler
 from distributed_tensorflow_ibm_mnist_tpu.serving.stats import ServingStats
 from distributed_tensorflow_ibm_mnist_tpu.utils.compile_cache import enable_compile_cache
 from distributed_tensorflow_ibm_mnist_tpu.utils.metrics import MetricWriter
-from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import CompileTracker
+from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import CompileTracker, host_span
 
 # sentinel "row cache" _prefill_request returns for a radix partial-prefix
 # hit: nothing was dispatched — the real work (the suffix-extend program)
@@ -772,12 +772,17 @@ class InferenceEngine:
         else:
             _insert_fn = self._insert_impl
             _reset_fn = reset_cache_slots
-        self._insert = jax.jit(
-            lambda cache, *a: _pin(_insert_fn(cache, *a)),
-            donate_argnums=(0,))
-        self._reset = jax.jit(
-            lambda cache, mask: _pin(_reset_fn(cache, mask)),
-            donate_argnums=(0,))
+
+        # named functions, not lambdas: a program is found in a device
+        # trace by its function's name (jit__insert_row, jit__reset_rows)
+        def _insert_row(cache, *a):
+            return _pin(_insert_fn(cache, *a))
+
+        def _reset_rows(cache, mask):
+            return _pin(_reset_fn(cache, mask))
+
+        self._insert = jax.jit(_insert_row, donate_argnums=(0,))
+        self._reset = jax.jit(_reset_rows, donate_argnums=(0,))
 
         pad_id_ = self.pad_id
         top_k_ = int(top_k)
@@ -844,14 +849,15 @@ class InferenceEngine:
             # per-page scatter + no-forward block-table install, both with
             # the cache donated like every other cache-threading program
             self._page_gather = jax.jit(gather_page)
-            self._page_write = jax.jit(
-                lambda cache, payload, pid: _pin(
-                    page_write(cache, payload, pid)),
-                donate_argnums=(0,))
-            self._bt_install = jax.jit(
-                lambda cache, bt_row, slot, cur: _pin(
-                    bt_install(cache, bt_row, slot, cur)),
-                donate_argnums=(0,))
+
+            def _page_write(cache, payload, pid):
+                return _pin(page_write(cache, payload, pid))
+
+            def _bt_install(cache, bt_row, slot, cur):
+                return _pin(bt_install(cache, bt_row, slot, cur))
+
+            self._page_write = jax.jit(_page_write, donate_argnums=(0,))
+            self._bt_install = jax.jit(_bt_install, donate_argnums=(0,))
 
         def _prefill_row(params, prompt, lens):
             # the B=1 row cache is pinned head-sharded too: the insert
@@ -1158,15 +1164,18 @@ class InferenceEngine:
         radix-extend landing, so hit/miss first tokens are bit-identical.
         Returns ``(token, logprob)`` as host scalars."""
         temp, topp, topk, minp, key = self._req_sampling(req)
-        with self._compile.site(self._site("first_pick")):
-            tok, logp = first_pick(
-                logits, self._dev(np.array([temp], np.float32)),
-                self._dev(np.array([topp], np.float32)),
-                self._dev(np.array([topk], np.int32)),
-                self._dev(np.array([minp], np.float32)),
-                self._dev(key[None, :].astype(np.uint32)),
-                self._dev(np.zeros((1,), np.int32)))
-        return int(tok[0]), float(logp[0])
+        # the span covers the host read below too — the blocking wait for
+        # the prefill's logits, which the first_pick SITE leaves outside
+        with host_span("engine.first_pick", req=req.id):
+            with self._compile.site(self._site("first_pick")):
+                tok, logp = first_pick(
+                    logits, self._dev(np.array([temp], np.float32)),
+                    self._dev(np.array([topp], np.float32)),
+                    self._dev(np.array([topk], np.int32)),
+                    self._dev(np.array([minp], np.float32)),
+                    self._dev(key[None, :].astype(np.uint32)),
+                    self._dev(np.zeros((1,), np.int32)))
+            return int(tok[0]), float(logp[0])
 
     # ------------------------------------------------------------------
     # tracing bookkeeping (every helper is a no-op without a tracer —
@@ -1306,7 +1315,6 @@ class InferenceEngine:
                                    parent=req.trace["phase"] or req.trace["id"],
                                    tid=req.trace["tid"], bucket=req.bucket)
                 if self._tracer is not None and req.trace is not None else None)
-        t0 = self.clock()
         try:
             with self._compile.site(self._site(f"prefill[b{req.bucket}]")):
                 row_cache, logits = self._prefill_row(
@@ -1315,40 +1323,7 @@ class InferenceEngine:
         finally:
             if span is not None:
                 self._tracer.end(span)  # a poisoned prefill still closes it
-                if self.cp > 1 and req.bucket % self.cp == 0:
-                    self._emit_ring_hops(req, span, t0, self.clock())
         return row_cache, logits
-
-    def _emit_ring_hops(self, req: Request, parent_span, t0: float,
-                        t1: float) -> None:
-        """Per-hop ``ring_hop`` child spans under a cp>1 prefill span
-        (ISSUE 20 satellite).  The XLA dispatch is one fused program — the
-        cp-1 ppermute hops have no host-visible boundaries — so each hop
-        is rendered as a uniform slice of the measured dispatch window,
-        annotated with the ANALYTIC per-hop comm bytes (utils/flops.
-        ring_hop_bytes at the grouped H_kv width): honest structure +
-        honest byte accounting, no fake per-hop timings claimed beyond
-        the uniform-slice convention the span args spell out."""
-        if self._tracer is None or req.trace is None:
-            return
-        from distributed_tensorflow_ibm_mnist_tpu.utils.flops import (
-            ring_hop_bytes,
-        )
-
-        m = self.model
-        heads_kv = getattr(m, "heads_kv", None) or getattr(m, "heads", 1)
-        head_dim = getattr(m, "dim", 0) // max(getattr(m, "heads", 1), 1)
-        hop_bytes = ring_hop_bytes(
-            req.bucket // self.cp, heads_kv, head_dim,
-            dtype_bytes=jnp.dtype(getattr(m, "dtype", jnp.float32)).itemsize,
-            depth=getattr(m, "depth", 1))
-        n_hops = self.cp - 1
-        dt = max(t1 - t0, 0.0) / max(n_hops, 1)
-        for h in range(n_hops):
-            self._tracer.complete(
-                "ring_hop", t0 + h * dt, t0 + (h + 1) * dt, cat="serving",
-                parent=parent_span, tid=req.trace["tid"], hop=h,
-                comm_bytes=hop_bytes, timing="uniform-slice")
 
     def _usable_radix_tokens(self, req: Request, matched: int | None = None
                              ) -> int:
@@ -1528,7 +1503,12 @@ class InferenceEngine:
             if prefilled is None:
                 prefilled = self._prefill_request(req)
             if self._pool is not None:
-                landed = self._paged_land(req, slot, prefilled)
+                with host_span("engine.land", req=req.id) as land:
+                    landed = self._paged_land(req, slot, prefilled)
+                    if landed is not None:
+                        land.set_metadata(
+                            pages=req.pages,
+                            radix_blocks=req.radix_tokens // self._page_size)
                 if landed is None:
                     # pool momentarily full — NOT a failure: the caller
                     # re-parks the (already chaos'd, maybe prefilled)
@@ -1745,7 +1725,7 @@ class InferenceEngine:
                 self._tracer.complete(
                     "prefill_chunk", t_c0, t_c1, cat="serving",
                     parent=req.trace.get("phase") or req.trace["id"],
-                    tid=req.trace["tid"], start=done,
+                    tid=req.trace["tid"], offset=done,
                     tokens=int(suffix.size))
             return True
         except Exception as e:
@@ -1991,9 +1971,19 @@ class InferenceEngine:
         """One host-loop iteration: cancel → admit → decode window →
         retire.  Returns the number of REAL tokens produced this
         iteration (window tokens past a row's EOS/budget are discarded,
-        never counted)."""
+        never counted).
+
+        Each phase is a :func:`host_span` on the profiler's clock
+        (``engine.step`` around ``engine.admit`` / ``dispatch`` /
+        ``overlap`` / ``readback`` / ``emit`` / ``reset``; table in
+        docs/OBSERVABILITY.md): free with no profiler session, and under
+        one they say what the host did in each idle gap of the device."""
         if self._closed:
             raise RuntimeError("engine is closed")
+        with host_span("engine.step", occupied=self.occupied):
+            return self._step()
+
+    def _step(self) -> int:
         t0 = self.clock()
         reset_mask = np.zeros((self.slots,), bool)
 
@@ -2006,7 +1996,8 @@ class InferenceEngine:
 
         # 2) admit into free slots — freed capacity refills immediately,
         #    which is the whole point of continuous batching
-        admitted = self._admit_free_slots(reset_mask)
+        with host_span("engine.admit"):
+            admitted = self._admit_free_slots(reset_mask)
 
         # 3) ONE windowed decode dispatch across ALL slots (fixed shape;
         #    idle rows decode garbage into their own rows).  The active
@@ -2052,77 +2043,78 @@ class InferenceEngine:
                     # step): the event index is the dispatch count, which
                     # keeps seeded plans stable across decode_ahead
                     self._chaos.raise_if_fired("serving-step", ChaosFault)
-                if spec:
-                    # ---- host drafting: build the (slots, k) chunk ----
-                    # column 0 = each slot's pending last token (the same
-                    # contract the decode window's tok carry uses), then
-                    # up to draft_len prompt-lookup proposals per slot
-                    t_d0 = self.clock()
-                    chunk = np.full((self.slots, k), self.pad_id, np.int32)
-                    chunk[:, 0] = self._slot_tok
-                    dls = np.zeros((self.slots,), np.int32)
-                    for slot, req in enumerate(self._slot_req):
-                        if req is None or self._slot_prefill[slot] is not None:
-                            continue
-                        d = self._drafter.draft(np.concatenate(
-                            [req.tokens,
-                             np.asarray(req.generated, np.int32)]))
-                        if d.size:
-                            chunk[slot, 1:1 + d.size] = d
-                            dls[slot] = d.size
-                    with self._compile.site(self._site("slot_draft")):
-                        chunk_dev = self._dev(chunk)
-                        dls_dev = self._dev(dls)
-                        # acceptance makes the PRNG position advance
-                        # data-dependent: spec windows re-upload the plane
-                        # fresh from the host generated counts each window
-                        pos_dev = self._dev(np.array(
-                            [0 if r is None else len(r.generated)
-                             for r in self._slot_req], np.int32))
-                    t_d1 = self.clock()
-                else:
-                    if self._tok_dev is None:
-                        self._tok_dev = self._dev(self._slot_tok)
-                    if self._pos_dev is None:
-                        # PRNG positions = tokens generated so far; the
-                        # window returns the advanced plane (carried like
-                        # _tok_dev, rebuilt here after any admission)
-                        self._pos_dev = self._dev(np.array(
-                            [0 if r is None else len(r.generated)
-                             for r in self._slot_req], np.int32))
-                if self._active_dev is None:
-                    # PREFILLING slots stay INACTIVE: their pages hold a
-                    # partial prompt — garbage decode writes above the
-                    # chunk cursor are overwritten by the next chunk
-                    self._active_dev = self._dev(np.array(
-                        [r is not None and p is None
-                         for r, p in zip(self._slot_req,
-                                         self._slot_prefill)]))
-                if self._planes_dev is None:
-                    self._planes_dev = (self._dev(self._slot_temp),
-                                        self._dev(self._slot_topp),
-                                        self._dev(self._slot_topk),
-                                        self._dev(self._slot_minp),
-                                        self._dev(self._slot_key))
-                (temps_dev, topps_dev, topks_dev, minps_dev,
-                 keys_dev) = self._planes_dev
-                t_disp = self.clock()
-                if spec:
-                    with self._compile.site(self._site(f"verify_window[k{k}]")):
-                        self.cache, blk_dev, logp_dev, acc_dev, _ = \
-                            self._verify(
-                                self.params, self.cache, chunk_dev, dls_dev,
-                                self._active_dev, temps_dev, topps_dev,
-                                topks_dev, minps_dev, keys_dev, pos_dev)
-                else:
-                    with self._compile.site(self._site(f"decode_window[k{k}]")):
-                        self.cache, blk_dev, logp_dev, last_dev, pos_out = \
-                            self._window(
-                                self.params, self.cache, self._tok_dev,
-                                self._active_dev, temps_dev, topps_dev,
-                                topks_dev, minps_dev, keys_dev,
-                                self._pos_dev)
-                dispatch_s = self.clock() - t_disp
+                with host_span("engine.dispatch", k=k):
+                    if spec:
+                        # ---- host drafting: build the (slots, k) chunk ----
+                        # column 0 = each slot's pending last token (the same
+                        # contract the decode window's tok carry uses), then
+                        # up to draft_len prompt-lookup proposals per slot
+                        t_d0 = self.clock()
+                        chunk = np.full((self.slots, k), self.pad_id, np.int32)
+                        chunk[:, 0] = self._slot_tok
+                        dls = np.zeros((self.slots,), np.int32)
+                        for slot, req in enumerate(self._slot_req):
+                            if req is None or self._slot_prefill[slot] is not None:
+                                continue
+                            d = self._drafter.draft(np.concatenate(
+                                [req.tokens,
+                                 np.asarray(req.generated, np.int32)]))
+                            if d.size:
+                                chunk[slot, 1:1 + d.size] = d
+                                dls[slot] = d.size
+                        with self._compile.site(self._site("slot_draft")):
+                            chunk_dev = self._dev(chunk)
+                            dls_dev = self._dev(dls)
+                            # acceptance makes the PRNG position advance
+                            # data-dependent: spec windows re-upload the plane
+                            # fresh from the host generated counts each window
+                            pos_dev = self._dev(np.array(
+                                [0 if r is None else len(r.generated)
+                                 for r in self._slot_req], np.int32))
+                        t_d1 = self.clock()
+                    else:
+                        if self._tok_dev is None:
+                            self._tok_dev = self._dev(self._slot_tok)
+                        if self._pos_dev is None:
+                            # PRNG positions = tokens generated so far; the
+                            # window returns the advanced plane (carried like
+                            # _tok_dev, rebuilt here after any admission)
+                            self._pos_dev = self._dev(np.array(
+                                [0 if r is None else len(r.generated)
+                                 for r in self._slot_req], np.int32))
+                    if self._active_dev is None:
+                        # PREFILLING slots stay INACTIVE: their pages hold a
+                        # partial prompt — garbage decode writes above the
+                        # chunk cursor are overwritten by the next chunk
+                        self._active_dev = self._dev(np.array(
+                            [r is not None and p is None
+                             for r, p in zip(self._slot_req,
+                                             self._slot_prefill)]))
+                    if self._planes_dev is None:
+                        self._planes_dev = (self._dev(self._slot_temp),
+                                            self._dev(self._slot_topp),
+                                            self._dev(self._slot_topk),
+                                            self._dev(self._slot_minp),
+                                            self._dev(self._slot_key))
+                    (temps_dev, topps_dev, topks_dev, minps_dev,
+                     keys_dev) = self._planes_dev
+                    t_disp = self.clock()
+                    if spec:
+                        with self._compile.site(self._site(f"verify_window[k{k}]")):
+                            self.cache, blk_dev, logp_dev, acc_dev, _ = \
+                                self._verify(
+                                    self.params, self.cache, chunk_dev, dls_dev,
+                                    self._active_dev, temps_dev, topps_dev,
+                                    topks_dev, minps_dev, keys_dev, pos_dev)
+                    else:
+                        with self._compile.site(self._site(f"decode_window[k{k}]")):
+                            self.cache, blk_dev, logp_dev, last_dev, pos_out = \
+                                self._window(
+                                    self.params, self.cache, self._tok_dev,
+                                    self._active_dev, temps_dev, topps_dev,
+                                    topks_dev, minps_dev, keys_dev,
+                                    self._pos_dev)
+                    dispatch_s = self.clock() - t_disp
             except Exception as e:
                 now = self.clock()
                 if self._tracer is not None:
@@ -2160,18 +2152,20 @@ class InferenceEngine:
                 # prefilling instead of blocking — one chunk of the oldest
                 # PREFILLING slot in chunked mode, else the next queued
                 # request's bucketed prefill
-                if self._prefill_chunk:
-                    chunked = self._chunk_tick(reset_mask)
-                else:
-                    self._overlap_prefill()
+                with host_span("engine.overlap"):
+                    if self._prefill_chunk:
+                        chunked = self._chunk_tick(reset_mask)
+                    else:
+                        self._overlap_prefill()
                 # ONE blocking host sync per window: the (slots, k) block
                 # serves the host inspection below, and `last` (the final
                 # carry token) feeds the next window without a host slice
-                t_rb = self.clock()
-                blk = np.asarray(blk_dev)
-                logps = np.asarray(logp_dev)
-                acc = np.asarray(acc_dev) if spec else None
-                readback_s = self.clock() - t_rb
+                with host_span("engine.readback"):
+                    t_rb = self.clock()
+                    blk = np.asarray(blk_dev)
+                    logps = np.asarray(logp_dev)
+                    acc = np.asarray(acc_dev) if spec else None
+                    readback_s = self.clock() - t_rb
                 if spec:
                     # each slot's pending token is acceptance-dependent —
                     # set per slot below; the device token mirror is never
@@ -2184,65 +2178,66 @@ class InferenceEngine:
                 now = self.clock()
                 t_acc0 = t_rb + readback_s
                 waste = 0
-                for slot, req in enumerate(self._slot_req):
-                    if req is None or self._slot_prefill[slot] is not None:
-                        continue  # PREFILLING rows were inactive: no tokens
-                    n_emit = k
-                    if spec:
-                        # accepted drafts + the model's one free correction
-                        # token: emitted tokens are exactly blk[:, :acc+1]
-                        n_emit = int(acc[slot]) + 1
-                        self._slot_tok[slot] = blk[slot, n_emit - 1]
-                        self.stats.spec(int(dls[slot]), int(acc[slot]))
-                        if self._tracer is not None and req.trace is not None:
-                            # draft/verify/accept land on the REQUEST's
-                            # track BEFORE the token loop, so a mid-
-                            # acceptance retirement (which closes the
-                            # request's trace tree) cannot lose them
-                            par = req.trace.get("phase") or req.trace["id"]
-                            rtid = req.trace["tid"]
-                            self._tracer.complete(
-                                "draft", t_d0, t_d1, cat="speculative",
-                                parent=par, tid=rtid, drafted=int(dls[slot]))
-                            self._tracer.complete(
-                                "verify", t_disp, t_acc0, cat="speculative",
-                                parent=par, tid=rtid)
-                            self._tracer.complete(
-                                "accept", t_acc0, now, cat="speculative",
-                                parent=par, tid=rtid,
-                                accepted=int(acc[slot]),
-                                drafted=int(dls[slot]))
-                    appended = 0
-                    for j in range(n_emit):
-                        tok = int(blk[slot, j])
-                        req.generated.append(tok)
-                        req.logprobs.append(float(logps[slot, j]))
-                        produced += 1
-                        appended += 1
-                        try:
-                            self._notify(req, tok)
-                        except Exception as e:
-                            # the callback's failure is THIS request's
-                            # failure; its remaining window tokens die with it
-                            self._slot_req[slot] = None
-                            self._release_slot_alloc(slot)
-                            self._active_dev = None
-                            self._fail(req, e, now)
-                            reset_mask[slot] = True
-                            break
-                        reason = self._done_reason(req)
-                        if reason is not None:
-                            # EOS/budget mid-window: keep tokens up to and
-                            # including the stop, discard the ≤k-1 overrun
-                            self._retire(slot, reason, now,
-                                         waste=k - appended)
-                            reset_mask[slot] = True
-                            break
-                    # this slot dispatched k device steps (scan steps in
-                    # plain mode, verify lanes in spec mode) and delivered
-                    # `appended` tokens — the remainder (post-stop overrun
-                    # / rejected lanes) is the window's waste
-                    waste += k - appended
+                with host_span("engine.emit"):
+                    for slot, req in enumerate(self._slot_req):
+                        if req is None or self._slot_prefill[slot] is not None:
+                            continue  # PREFILLING rows were inactive: no tokens
+                        n_emit = k
+                        if spec:
+                            # accepted drafts + the model's one free correction
+                            # token: emitted tokens are exactly blk[:, :acc+1]
+                            n_emit = int(acc[slot]) + 1
+                            self._slot_tok[slot] = blk[slot, n_emit - 1]
+                            self.stats.spec(int(dls[slot]), int(acc[slot]))
+                            if self._tracer is not None and req.trace is not None:
+                                # draft/verify/accept land on the REQUEST's
+                                # track BEFORE the token loop, so a mid-
+                                # acceptance retirement (which closes the
+                                # request's trace tree) cannot lose them
+                                par = req.trace.get("phase") or req.trace["id"]
+                                rtid = req.trace["tid"]
+                                self._tracer.complete(
+                                    "draft", t_d0, t_d1, cat="speculative",
+                                    parent=par, tid=rtid, drafted=int(dls[slot]))
+                                self._tracer.complete(
+                                    "verify", t_disp, t_acc0, cat="speculative",
+                                    parent=par, tid=rtid)
+                                self._tracer.complete(
+                                    "accept", t_acc0, now, cat="speculative",
+                                    parent=par, tid=rtid,
+                                    accepted=int(acc[slot]),
+                                    drafted=int(dls[slot]))
+                        appended = 0
+                        for j in range(n_emit):
+                            tok = int(blk[slot, j])
+                            req.generated.append(tok)
+                            req.logprobs.append(float(logps[slot, j]))
+                            produced += 1
+                            appended += 1
+                            try:
+                                self._notify(req, tok)
+                            except Exception as e:
+                                # the callback's failure is THIS request's
+                                # failure; its remaining window tokens die with it
+                                self._slot_req[slot] = None
+                                self._release_slot_alloc(slot)
+                                self._active_dev = None
+                                self._fail(req, e, now)
+                                reset_mask[slot] = True
+                                break
+                            reason = self._done_reason(req)
+                            if reason is not None:
+                                # EOS/budget mid-window: keep tokens up to and
+                                # including the stop, discard the ≤k-1 overrun
+                                self._retire(slot, reason, now,
+                                             waste=k - appended)
+                                reset_mask[slot] = True
+                                break
+                        # this slot dispatched k device steps (scan steps in
+                        # plain mode, verify lanes in spec mode) and delivered
+                        # `appended` tokens — the remainder (post-stop overrun
+                        # / rejected lanes) is the window's waste
+                        waste += k - appended
                 self.stats.window(dispatch_s, readback_s,
                                   steps=decoding_at_dispatch * k, waste=waste)
                 if self._tracer is not None:
@@ -2268,14 +2263,15 @@ class InferenceEngine:
 
         # 4) zero retired rows so idle cursors restart from 0 (bounded) and
         #    the next admission starts from a clean row
-        if reset_mask.any():
-            with self._compile.site(self._site("slot_reset")):
-                self.cache = self._reset(self.cache, self._dev(reset_mask))
-        # deferred page frees apply only now, AFTER the reset dispatch is
-        # enqueued: single-stream device execution guarantees every program
-        # still reading a retired slot's block table runs before any later
-        # tenant of the reallocated pages writes them
-        self._flush_freed_pages()
+        with host_span("engine.reset"):
+            if reset_mask.any():
+                with self._compile.site(self._site("slot_reset")):
+                    self.cache = self._reset(self.cache, self._dev(reset_mask))
+            # deferred page frees apply only now, AFTER the reset dispatch is
+            # enqueued: single-stream device execution guarantees every program
+            # still reading a retired slot's block table runs before any later
+            # tenant of the reallocated pages writes them
+            self._flush_freed_pages()
 
         if produced > 0 or admitted or chunked or self.occupied == 0:
             self._last_progress_t = self.clock()

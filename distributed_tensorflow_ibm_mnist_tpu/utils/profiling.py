@@ -7,19 +7,19 @@ the loop.  TPU-native replacements here:
   plugin) around any code region;
 * :func:`start_server` — on-demand profiling of a live job from another
   process (``jax.profiler``'s sampling path);
-* :class:`StepTimer` — honest step timing with ``block_until_ready``
-  fencing (async dispatch makes naive ``time.time()`` around a jitted call
-  measure only enqueue time) and warmup-aware summary stats.
+* :class:`TraceSession` — the same capture staged imperatively, for the
+  trainer's ``profile_dir``.
+
+Under any of them the program's own spans (``utils/tracing.host_span``:
+every compile site, every phase of ``InferenceEngine.step()``) land in the
+capture's host plane beside the device lines.
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
-from typing import Any, Callable
 
 import jax
-import numpy as np
 
 
 @contextlib.contextmanager
@@ -62,86 +62,3 @@ class TraceSession:
         if self.active:
             jax.profiler.stop_trace()
             self.active = False
-
-
-class StepTimer:
-    """Wall-time per step with device fencing and warmup exclusion.
-
-    >>> timer = StepTimer(warmup=2)
-    >>> for batch in batches:
-    ...     with timer.step():
-    ...         state, m = train_step(state, batch)  # fenced on exit
-    >>> timer.summary(items_per_step=batch_size)
-    """
-
-    def __init__(self, warmup: int = 1):
-        self._warmup = warmup
-        self._times: list[float] = []
-        self._fence_obj: Any = None
-
-    @contextlib.contextmanager
-    def step(self, fence: Any = None):
-        """Time one step; ``fence`` (a jax array/pytree) is block-waited on
-        exit — pass the step's output; defaults to blocking all live arrays
-        via ``jax.block_until_ready`` on what the body registers with
-        :meth:`set_fence`."""
-        t0 = time.perf_counter()
-        self._fence_obj = fence
-        yield self
-        if self._fence_obj is not None:
-            jax.block_until_ready(self._fence_obj)
-        self._times.append(time.perf_counter() - t0)
-
-    def set_fence(self, obj: Any):
-        self._fence_obj = obj
-
-    @property
-    def times(self) -> list[float]:
-        """Post-warmup samples only; empty until a non-warmup step lands
-        (never silently reports compile time as steady state)."""
-        return self._times[self._warmup:]
-
-    def summary(self, items_per_step: int | None = None) -> dict[str, Any]:
-        """Post-warmup timing stats, always strict-JSON-safe.
-
-        Zero post-warmup samples (every step was warmup, or no steps ran)
-        yields ``None``-valued fields — NOT NaN: feeding ``[nan]`` through
-        np.percentile/mean sprays RuntimeWarnings and produces bare ``NaN``
-        tokens that break every strict JSON consumer downstream.  The same
-        sanitizer MetricWriter applies to records (metrics._sanitize)
-        guards the computed path too, so a pathological sample can never
-        leak a non-finite value either.
-        """
-        from distributed_tensorflow_ibm_mnist_tpu.utils.metrics import _sanitize
-
-        samples = self.times
-        if not samples:
-            out: dict[str, Any] = {
-                "steps": int(len(self._times)),
-                "mean_s": None, "p50_s": None, "p90_s": None, "max_s": None,
-            }
-            if items_per_step:
-                out["items_per_sec"] = None
-            return out
-        ts = np.asarray(samples)
-        out = {
-            "steps": int(len(self._times)),
-            "mean_s": float(ts.mean()),
-            "p50_s": float(np.percentile(ts, 50)),
-            "p90_s": float(np.percentile(ts, 90)),
-            "max_s": float(ts.max()),
-        }
-        if items_per_step:
-            out["items_per_sec"] = float(items_per_step / ts.mean())
-        return _sanitize(out)
-
-
-def profile_fn(fn: Callable, *args, iters: int = 10, warmup: int = 2) -> dict[str, float]:
-    """Time a jitted callable honestly: warmup (compile) excluded, fenced."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    timer = StepTimer(warmup=0)
-    for _ in range(iters):
-        with timer.step() as t:
-            t.set_fence(fn(*args))
-    return timer.summary()

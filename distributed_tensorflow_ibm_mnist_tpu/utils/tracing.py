@@ -28,6 +28,13 @@ The pieces:
   "number of distinct compiled programs" a tracked bench metric — the
   r04→r05 cold-compile regression (ROADMAP item 5) becomes reproducible
   and regression-gated per-PR.
+* :func:`host_span` — the program's spans on the PROFILER's clock: a
+  ``jax.profiler.TraceAnnotation`` that lands in the ``.xplane.pb`` host
+  plane beside the device lines whenever a profiler session is open, and
+  records nothing otherwise.  Every :meth:`CompileTracker.site` enters one
+  (``site:<label>``), and the engine wraps the phases of ``step()`` in
+  them (``engine.step``, ``engine.admit``, ...), so a device idle gap
+  carries the program's own name for what the host was doing.
 * :func:`validate_trace` — the schema gate for exported traces: strict
   JSON (no NaN/Infinity tokens), every span closed, every parent id
   resolving.  ``scripts/trace_report.py`` renders the same files into a
@@ -879,6 +886,25 @@ def trace_forest(doc: dict) -> dict:
 # compile accounting
 
 
+def host_span(name: str, **args):
+    """A host span on the profiler's clock: ``with host_span("engine.admit"):``.
+
+    Returns a ``jax.profiler.TraceAnnotation``.  With no profiler session
+    open it records nothing (the C++ ``TraceMe`` checks one flag); with one
+    (``jax.profiler.start_trace``, the benchmark's ``--trace 1``) the span
+    lands in the trace's host plane on the same clock as the device lines.
+    ``name`` is a constant, or carries only what a compile-site label
+    carries (``site:prefill[b2048]``) — request ids, slots and counts go in
+    ``**args`` (or ``span.set_metadata(...)`` once they are known), which
+    the trace keeps as the event's stats and not in its name
+    (scripts/lint_tracing.py holds call sites to this).  Every name is
+    listed in docs/OBSERVABILITY.md."""
+    # imported here: this module loads in processes that never touch jax
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **args)
+
+
 class CompileTracker:
     """Process-global XLA compile accounting with per-site attribution.
 
@@ -901,7 +927,9 @@ class CompileTracker:
     site they land in ``"unattributed"``.  The engine labels its program
     family (``prefill[b<bucket>]``, ``decode_window[k<k>]``, ...), the
     trainer its step variants — so a program-family explosion names the
-    site that grew.
+    site that grew.  Every site is also a :func:`host_span` named
+    ``site:<label>``: one edit gave each dispatch site of the engine, the
+    trainer, the generator and the handoff its span on the profiler's clock.
     """
 
     _instance: "CompileTracker | None" = None
@@ -965,7 +993,8 @@ class CompileTracker:
             stack = self._tl.stack = []
         stack.append(str(label))
         try:
-            yield
+            with host_span("site:" + stack[-1]):
+                yield
         finally:
             stack.pop()
 

@@ -1,8 +1,9 @@
 #!/usr/bin/env python
-"""Static tracing-contract lint for the serving tier (ISSUE 19 satellite).
+"""Static tracing-contract lint (ISSUE 19 satellite; host spans ISSUE 25).
 
-Two invariants keep tracing zero-cost-off and clock-sane, and both are
-mechanical enough to enforce with ``ast`` instead of code review:
+Two invariants keep the serving tier's tracing zero-cost-off and
+clock-sane, a third keeps the spans on the profiler's clock readable, and
+all are mechanical enough to enforce with ``ast`` instead of code review:
 
 1. **Nil-guard contract.**  Every call through a ``_tracer`` attribute
    (``self._tracer.begin(...)``, ``engine._tracer.complete(...)``) must
@@ -21,6 +22,15 @@ mechanical enough to enforce with ``ast`` instead of code review:
    allowlisted file — its two wall-clock reads are the *intentional*
    restart-surviving timestamps the journal format documents.
 
+3. **Host-span name contract** (the whole package).  The name passed to
+   ``host_span(...)`` is a literal listed in docs/OBSERVABILITY.md's
+   event table, or a listed literal prefix plus a compile-site label
+   (``"site:" + label``): a metric reads a span BY NAME, so a name built
+   from a request id, a slot or a token count would be one name per
+   request and match nothing — those go in the keyword arguments.  And
+   ``TraceAnnotation`` is named in ``utils/tracing.py`` alone: every span
+   the package records on the profiler's clock goes through ``host_span``.
+
 Run as a script (``python scripts/lint_tracing.py``) for CI — exits
 nonzero listing every violation — or import :func:`check_source` /
 :func:`check_file` from tests (tests/test_lint_tracing.py wires this
@@ -31,6 +41,7 @@ from __future__ import annotations
 
 import ast
 import os
+import re
 import sys
 
 # files whose time.time() reads are intentionally wall-clock (the
@@ -167,6 +178,79 @@ class _Walker(ast.NodeVisitor):
                 self.path.pop()
 
 
+# identifiers that may not feed a host_span NAME: per-request or
+# per-iteration values, one distinct name each
+_HIGH_CARDINALITY = ("req", "rid", "slot", "tok", "count", "len")
+
+
+def _high_cardinality(word: str) -> bool:
+    return (word == "id" or word.endswith("_id")
+            or any(h in word for h in _HIGH_CARDINALITY))
+
+
+def _name_parts(node: ast.AST) -> tuple[str | None, list[ast.AST]]:
+    """``(literal head, dynamic parts)`` of a host_span name expression:
+    a constant, ``"head" + expr``, or an f-string with a literal head."""
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return node.value, []
+    if (isinstance(node, ast.BinOp) and isinstance(node.op, ast.Add)
+            and isinstance(node.left, ast.Constant)
+            and isinstance(node.left.value, str)):
+        return node.left.value, [node.right]
+    if (isinstance(node, ast.JoinedStr) and node.values
+            and isinstance(node.values[0], ast.Constant)):
+        return node.values[0].value, list(node.values[1:])
+    return None, [node]
+
+
+def documented_span_names(doc_path: str | None = None) -> set[str]:
+    """Every back-quoted token of docs/OBSERVABILITY.md's table rows."""
+    doc_path = doc_path or os.path.join(
+        os.path.dirname(package_dir()), "docs", "OBSERVABILITY.md")
+    with open(doc_path, encoding="utf-8") as f:
+        rows = [ln for ln in f if ln.startswith("|")]
+    return set(re.findall(r"`([^`]+)`", "".join(rows)))
+
+
+def check_host_spans(src: str, filename: str,
+                     documented: set[str]) -> list[str]:
+    """The host-span name contract over one source string."""
+    out: list[str] = []
+    is_home = filename.replace(os.sep, "/").endswith("utils/tracing.py")
+    for node in ast.walk(ast.parse(src, filename=filename)):
+        if (not is_home and isinstance(node, (ast.Name, ast.Attribute, ast.alias))
+                and "TraceAnnotation" in (
+                    getattr(node, "id", None), getattr(node, "attr", None),
+                    getattr(node, "name", None))):
+            out.append(
+                f"{filename}:{node.lineno}: TraceAnnotation outside "
+                f"utils/tracing.py — record the span through host_span()")
+        if not (isinstance(node, ast.Call) and node.args and (
+                getattr(node.func, "id", None) == "host_span"
+                or getattr(node.func, "attr", None) == "host_span")):
+            continue
+        head, dynamic = _name_parts(node.args[0])
+        listed = head is not None and (
+            head in documented if not dynamic
+            else any(d.startswith(head) and d != head for d in documented))
+        if not listed:
+            out.append(
+                f"{filename}:{node.lineno}: host_span name "
+                f"`{_unparse(node.args[0])}` is not in "
+                f"docs/OBSERVABILITY.md's event table")
+        for part in dynamic:
+            words = [n.id for n in ast.walk(part) if isinstance(n, ast.Name)]
+            words += [n.attr for n in ast.walk(part)
+                      if isinstance(n, ast.Attribute)]
+            bad = sorted({w for w in words if _high_cardinality(w.lower())})
+            if bad:
+                out.append(
+                    f"{filename}:{node.lineno}: host_span name built from "
+                    f"{', '.join(bad)} — a request id, slot or count goes "
+                    f"in the keyword arguments, never in the name")
+    return out
+
+
 def check_source(src: str, filename: str = "<string>") -> list[str]:
     """Lint one source string; returns violation messages (empty = clean)."""
     w = _Walker(filename)
@@ -179,10 +263,14 @@ def check_file(path: str) -> list[str]:
         return check_source(f.read(), path)
 
 
-def serving_dir() -> str:
+def package_dir() -> str:
     return os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "distributed_tensorflow_ibm_mnist_tpu", "serving")
+        "distributed_tensorflow_ibm_mnist_tpu")
+
+
+def serving_dir() -> str:
+    return os.path.join(package_dir(), "serving")
 
 
 def check_serving() -> list[str]:
@@ -194,8 +282,21 @@ def check_serving() -> list[str]:
     return out
 
 
+def check_package_host_spans() -> list[str]:
+    """The host-span name contract over every module of the package."""
+    documented = documented_span_names()
+    out: list[str] = []
+    for root, _dirs, files in sorted(os.walk(package_dir())):
+        for name in sorted(files):
+            if name.endswith(".py"):
+                path = os.path.join(root, name)
+                with open(path, encoding="utf-8") as f:
+                    out.extend(check_host_spans(f.read(), path, documented))
+    return out
+
+
 def main() -> int:
-    violations = check_serving()
+    violations = check_serving() + check_package_host_spans()
     for v in violations:
         print(v)
     n = len([f for f in os.listdir(serving_dir()) if f.endswith(".py")])

@@ -12,7 +12,6 @@ from distributed_tensorflow_ibm_mnist_tpu.utils.elastic import (
     PreemptionHandler,
     run_with_recovery,
 )
-from distributed_tensorflow_ibm_mnist_tpu.utils.profiling import StepTimer, profile_fn
 
 
 def _cfg(**kw):
@@ -25,66 +24,6 @@ def _cfg(**kw):
 
 
 # ---- profiling ----
-
-def test_step_timer_and_profile_fn():
-    f = jax.jit(lambda x: jnp.sum(x * x))
-    x = jnp.arange(1024.0)
-    stats = profile_fn(f, x, iters=5, warmup=1)
-    assert stats["steps"] == 5
-    assert 0 < stats["mean_s"] < 5.0
-    assert stats["p90_s"] >= stats["p50_s"] >= 0
-
-    timer = StepTimer(warmup=1)
-    for _ in range(4):
-        with timer.step() as t:
-            t.set_fence(f(x))
-    s = timer.summary(items_per_step=128)
-    assert s["items_per_sec"] > 0 and len(timer.times) == 3
-
-
-def test_step_timer_summary_with_zero_post_warmup_samples():
-    """ISSUE 6 satellite: warmup >= recorded steps used to push an empty
-    array through np.percentile (NaN + RuntimeWarning) and emit NaN into
-    strict-JSON metric records.  Now every statistic is None (null), the
-    same convention MetricWriter._sanitize enforces."""
-    import json
-
-    f = jax.jit(lambda x: x + 1)
-    x = jnp.arange(8.0)
-    for warmup, n_steps in ((2, 1), (1, 1), (5, 0)):
-        timer = StepTimer(warmup=warmup)
-        for _ in range(n_steps):
-            with timer.step() as t:
-                t.set_fence(f(x))
-        s = timer.summary(items_per_step=8)
-        assert s["steps"] == n_steps  # total recorded, warmup included
-        assert s["mean_s"] is None and s["p50_s"] is None
-        assert s["p90_s"] is None and s["max_s"] is None
-        assert s["items_per_sec"] is None
-        json.dumps(s, allow_nan=False)  # strict-JSON clean, no NaN tokens
-    # without items_per_step the key must stay absent, as before
-    assert "items_per_sec" not in StepTimer(warmup=3).summary()
-
-
-def test_step_timer_warmup_exclusion_and_fencing():
-    """StepTimer drops exactly `warmup` leading samples, and set_fence
-    blocks on the device value so the recorded time covers the compute."""
-    timer = StepTimer(warmup=2)
-    f = jax.jit(lambda x: jnp.sum(x * x))
-    x = jnp.arange(512.0)
-    for _ in range(6):
-        with timer.step() as t:
-            t.set_fence(f(x))
-    assert len(timer.times) == 4  # 6 recorded - 2 warmup
-    s = timer.summary()
-    assert s["steps"] == 6  # total recorded, warmup included
-    assert s["max_s"] >= s["p90_s"] >= s["p50_s"] > 0
-    # a fence-less step still records (wall time only)
-    bare = StepTimer(warmup=0)
-    with bare.step():
-        pass
-    assert len(bare.times) == 1 and bare.times[0] >= 0
-
 
 def test_trace_session_stop_is_idempotent(tmp_path):
     """TraceSession: stop() without start() is a no-op, double stop() is

@@ -16,8 +16,7 @@ The decisive properties:
   JSON).
 * LAUNCH/OPS — ``prewarm()`` under a cp mesh compiles the whole
   cp-qualified family (``prefill[b16,cp2]``) so serving compiles ZERO
-  programs; chaos event counts are cp-invariant; ``ring_hop`` child
-  spans carry the analytic grouped-width comm bytes.
+  programs; chaos event counts are cp-invariant.
 * REFUSALS — dense layout, indivisible max_len/kv_pages, and
   attn_fn-bearing models refuse cp>1 with actionable errors.
 
@@ -243,30 +242,6 @@ def test_chaos_event_counts_cp_invariant(native):
                       inj.events("serving-step"))
     assert counts[1] == counts[2] == counts[4], counts
     assert counts[1][0] >= len(PROMPTS) and counts[1][1] > 0
-
-
-def test_ring_hop_spans_carry_grouped_comm_bytes(native):
-    from distributed_tensorflow_ibm_mnist_tpu.utils.flops import (
-        ring_hop_bytes,
-    )
-    from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import Tracer
-
-    model, params = native
-    tr = Tracer()
-    eng = _engine(model, params, cp=2, tracer=tr)
-    reqs = [eng.submit(p, max_new=3) for p in PROMPTS[:2]]
-    eng.run()
-    eng.close()
-    assert all(r.status == "done" for r in reqs)
-    hops = [e for e in tr.events() if e["name"] == "ring_hop"]
-    # cp-1 = 1 hop per dense prefill, one prefill per request
-    assert len(hops) == 2
-    want = ring_hop_bytes(16 // 2, KW["heads"], KW["dim"] // KW["heads"],
-                          dtype_bytes=4, depth=KW["depth"])
-    for h in hops:
-        assert h["args"]["comm_bytes"] == want
-        assert h["args"]["timing"] == "uniform-slice"
-        assert h["cat"] == "serving"
 
 
 # ----------------------------------------------------------------------

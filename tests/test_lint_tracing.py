@@ -3,7 +3,9 @@
 ``scripts/lint_tracing.py`` enforces two mechanical invariants over the
 serving package — every ``_tracer`` call is nil-guarded (zero-cost-off)
 and no serving code reads ``time.time()`` (monotonic clock domain,
-journal.py excepted).  Running it from pytest makes a regression a RED
+journal.py excepted) — and one over the whole package: a ``host_span``
+name is a literal of docs/OBSERVABILITY.md's table, never built from a
+request id, slot or count.  Running it from pytest makes a regression a RED
 test, not a forgotten CI step; the unit cases below pin that the checker
 itself still catches what it claims to catch.
 """
@@ -141,6 +143,45 @@ def test_monotonic_is_fine():
            "def f():\n"
            "    return time.monotonic()\n")
     assert lint.check_source(src, "engine.py") == []
+
+
+# ----------------------------------------------------------------------
+# the host-span name contract (ISSUE 25): names are documented literals
+
+
+def test_package_host_spans_are_documented_literals():
+    violations = lint.check_package_host_spans()
+    assert violations == [], "\n".join(violations)
+    names = lint.documented_span_names()
+    assert {"engine.step", "engine.emit", "site:<label>"} <= names
+
+
+@pytest.mark.parametrize("call,finds", [
+    ("host_span('engine.step', occupied=n)", None),
+    ("host_span('site:' + label)", None),
+    ("host_span(f'site:{label}')", None),
+    ("tracing.host_span('engine.emit')", None),
+    ("host_span('engine.undocumented')", "not in docs"),
+    ("host_span(f'engine.step[{req.id}]')", "built from"),
+    ("host_span('site:' + str(slot))", "built from slot"),
+    ("host_span(f'engine.emit[{n_tokens}]')", "built from n_tokens"),
+    ("host_span(name)", "not in docs"),
+    ("jax.profiler.TraceAnnotation('engine.step')", "TraceAnnotation outside"),
+])
+def test_host_span_name_contract(call, finds):
+    documented = {"engine.step", "engine.emit", "site:<label>"}
+    src = f"def f(req, slot, label, n, n_tokens, name):\n    with {call}:\n        pass\n"
+    out = lint.check_host_spans(src, "pkg/serving/engine.py", documented)
+    if finds is None:
+        assert out == []
+    else:
+        assert out and any(finds in v for v in out), out
+
+
+def test_trace_annotation_is_allowed_in_its_one_home():
+    src = "from jax.profiler import TraceAnnotation\n"
+    assert lint.check_host_spans(src, "pkg/utils/tracing.py", set()) == []
+    assert len(lint.check_host_spans(src, "pkg/utils/other.py", set())) == 1
 
 
 def test_cli_exit_status():
