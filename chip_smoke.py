@@ -300,6 +300,53 @@ def _flash_probe(leg: Leg, name: str, b, s, h, d, dtype, *, causal=True,
     return out
 
 
+def _paged_probe(leg: Leg, name: str, dtype, hkv: int, group: int) -> dict:
+    """The paged decode kernel vs the gather + masked softmax it replaces, on
+    one ragged batch over a pool with a NaN trash page.  What only the chip
+    can show: which half of a 32-bit word holds which KV head."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_tensorflow_ibm_mnist_tpu.models.transformer import _attend_cached
+    from distributed_tensorflow_ibm_mnist_tpu.ops.paged_attention import (
+        paged_decode_attention,
+    )
+
+    ps, n_row, d = (8, 20, 128) if leg.dry_run else (64, 64, 128)
+    max_len = ps * n_row
+    lens = [1, ps, ps + 1, max_len // 3, max_len, 2, 9 * ps + 5]
+    pages = [-(-n // ps) for n in lens]
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(0, 0.5, (len(lens), hkv * group, d)), dtype)
+    pool_k = jnp.asarray(rng.normal(0, 0.5, (1 + sum(pages), ps, hkv, d)), dtype)
+    pool_v = jnp.asarray(rng.normal(0, 0.5, (1 + sum(pages), ps, hkv, d)), dtype)
+    bt = np.zeros((len(lens), n_row), np.int32)
+    ids = iter(rng.permutation(np.arange(1, 1 + sum(pages))))
+    for r, n in enumerate(pages):
+        bt[r, :n] = [next(ids) for _ in range(n)]
+    bt, lengths = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+
+    def gather(q, pool_k, pool_v):
+        kc = pool_k[bt].reshape(len(lens), max_len, hkv, d)
+        vc = pool_v[bt].reshape(len(lens), max_len, hkv, d)
+        mask = jnp.arange(max_len)[None, None, :] < lengths[:, None, None]
+        return _attend_cached(q[:, None], kc, vc, None, None, mask, dtype)[:, 0]
+
+    kernel = jax.jit(lambda q, k, v: paged_decode_attention(q, k, v, bt, lengths))
+    want = jax.jit(gather)(q, pool_k, pool_v)
+    poisoned = pool_k.at[0].set(jnp.nan), pool_v.at[0].set(jnp.inf)
+    tol = 5e-3 if dtype == jnp.float32 else 2e-2
+    out = {"name": name, "tol": tol,
+           "mosaic": _mosaic_compiled(kernel, q, *poisoned),
+           "fwd_err": _rel_err(kernel(q, *poisoned), want)}
+    _require(_finite(out["fwd_err"]) and out["fwd_err"] < tol,
+             f"paged probe {name} out of tolerance: {out}")
+    _require(out["mosaic"] or leg.dry_run,
+             f"paged probe {name} was not compiled by Mosaic")
+    return out
+
+
 def _xent_probe(leg: Leg, n: int, c: int) -> dict:
     import jax
     import jax.numpy as jnp
@@ -354,6 +401,11 @@ def leg_kernels(leg: Leg) -> None:
                                bf16, window=short_s // 8))
     probes.append(_flash_probe(leg, f"gqa_S{short_s}_h8_kv2", 1, short_s, 8,
                                64, bf16, heads_kv=2))
+    # the serving window's paged decode attention: the benchmark's GQA
+    # group of 12 on a bf16 pair of KV heads, and the strided f32 split
+    probes.append(_paged_probe(leg, "paged_bf16_kv2_g12", bf16, 2, 12))
+    probes.append(_paged_probe(leg, "paged_bf16_kv8_g2", bf16, 8, 2))
+    probes.append(_paged_probe(leg, "paged_f32_kv2_g4", f32, 2, 4))
     for n, c in XENT_SHAPES:
         if leg.dry_run:
             n, c = min(n, 64), min(c, 512)

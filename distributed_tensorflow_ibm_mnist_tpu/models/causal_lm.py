@@ -67,6 +67,10 @@ class CausalLM(nn.Module):
     #   through a shared page pool + block table (serving/kv_pool.py)
     #   instead of dense (B, max_len) rows; serving engine state, training
     #   and prefill are untouched (see TransformerBlock.page_size)
+    paged_one_device: bool = False  # set by the serving engine on its
+    #   decode clone when the program runs on one device (see
+    #   TransformerBlock.paged_one_device): single-token paged decode may
+    #   then read live pages only through the ops/paged_attention.py kernel
     tie_embeddings: bool = False  # share the token embedding with the
     #   output head (logits = x @ embed^T): V*dim fewer params, the
     #   standard small-LM regularizer.  The Megatron rule's feature-dim
@@ -192,7 +196,8 @@ class CausalLM(nn.Module):
                 moe_top_k=self.moe_top_k, moe_z_weight=self.moe_z_weight,
                 moe_fn=self.moe_fn, rope=rope, sow_kv=self.sow_kv,
                 window=self.window, kv_cache_dtype=self.kv_cache_dtype,
-                page_size=self.page_size, quant=self.quant,
+                page_size=self.page_size,
+                paged_one_device=self.paged_one_device, quant=self.quant,
                 dtype=self.dtype, name=f"block_{i}",
             )(x, train, **extra)
         x = nn.LayerNorm(dtype=self.dtype, name="norm_out")(x)

@@ -240,6 +240,12 @@ class TransformerBlock(nn.Module):
     #   (B, max_len/page_size) block table instead of a dense
     #   (B, max_len, ...) slab; see _paged_decode_attention.  The pool is
     #   engine state (serving/kv_pool.py), never initialized here.
+    paged_one_device: bool = False  # the program that decodes through the
+    #   pool runs on ONE device.  A module cannot see the mesh, and a Mosaic
+    #   kernel inside a jit over several devices is refused, so the serving
+    #   engine states this one fact when it clones its decode model
+    #   (tp == 1 and cp == 1); it is what lets single-token paged decode
+    #   take the ops/paged_attention.py kernel where the shapes allow.
     quant: str = "none"  # "int8": WEIGHT-only quantization — every dense
     #   projection in the block (qkv/q_proj/kv_proj/proj/dense_0/dense_1)
     #   becomes an Int8Dense (models/quant.py): int8 kernel + per-output-
@@ -527,16 +533,34 @@ class TransformerBlock(nn.Module):
         path writes only the current chunk's positions — never a whole row.
 
         Writes scatter each new K/V position to ``(block_table[pos // ps],
-        pos % ps)``; reads gather the row's full virtual span
-        ``pool[block_table]`` back to (B, max_len, H_kv, D) and reuse the
-        dense tail (same mask, same reduction shapes), which is what makes
-        paged greedy decoding token-identical to the dense layout.
-        ``max_len`` must be a page multiple so the virtual span is exactly
-        max_len.  Write positions clamp at max_len - 1 exactly like the
-        dense path's ``dynamic_update_slice`` clamp (decode-ahead overrun
-        rows); unallocated block-table entries point at the reserved trash
-        page 0, whose garbage is never exposed: a row's mask only admits
-        positions below its cursor, all of which lie in allocated pages.
+        pos % ps)``.  Reads take one of two forms, chosen from what this
+        call can observe (never a knob):
+
+        * a single-token step (``s == 1``) of a one-device program
+          (``paged_one_device``) over a pool that stores the compute dtype
+          in a shape the kernel reads (``ops.paged_attention.
+          paged_kernel_eligible``: head dim 128, whole sublane tiles a
+          page) runs the Pallas kernel: each row's block table is walked
+          only as far as ``min(cursor + 1, max_len)``, so a step reads the
+          rows' LIVE pages and nothing else;
+        * everything else — multi-token chunks (suffix extend, chunked
+          prefill, speculative verify), int8 pools with their scales,
+          tp/cp-sharded pools, small head dims — gathers the row's full
+          virtual span ``pool[block_table]`` back to (B, max_len, H_kv, D)
+          and reuses the dense tail (same mask, same reduction shapes),
+          which is what makes paged greedy decoding token-identical to the
+          dense layout there.
+
+        Both score the same support with the same arithmetic (compute-dtype
+        operands, f32 scores and accumulation); the kernel's online softmax
+        rounds in a different order, so the two agree to rounding, not bit
+        for bit.  ``max_len`` must be a page multiple so the virtual span
+        is exactly max_len.  Write positions clamp at max_len - 1 exactly
+        like the dense path's ``dynamic_update_slice`` clamp (decode-ahead
+        overrun rows); unallocated block-table entries point at the
+        reserved trash page 0, whose garbage is never exposed: a row's mask
+        only admits positions below its cursor, all of which lie in
+        allocated pages (the kernel never even fetches past them).
 
         The pool, block table, and cursor are ENGINE state: the init fns
         raise, because pool size is serving configuration
@@ -599,6 +623,19 @@ class TransformerBlock(nn.Module):
         pages_v.value = pages_v.value.at[page, off].set(v_st)
         q_pos = idx[:, None] + jnp.arange(s)  # (B, S), unclamped (dense parity)
         idx_var.value = jnp.minimum(idx + s, max_len)
+
+        if s == 1 and self.paged_one_device:
+            from distributed_tensorflow_ibm_mnist_tpu.ops.paged_attention import (
+                paged_decode_attention, paged_kernel_eligible)
+
+            if paged_kernel_eligible(q.dtype, pages_k.value.dtype, ps, hkv, d):
+                # the current token is already in its page: the row attends
+                # cursor + 1 positions (max_len for an overrun row, whose
+                # write was clamped onto max_len - 1)
+                o = paged_decode_attention(
+                    q[:, 0], pages_k.value, pages_v.value, bt,
+                    jnp.minimum(idx + 1, max_len))
+                return o[:, None]
 
         # gather the virtual row: (n_pages, ps, ...)[bt] -> (B, n_row, ps, ...)
         kc = pages_k.value[bt].reshape(b, max_len, hkv, d)

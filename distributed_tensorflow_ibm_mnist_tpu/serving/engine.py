@@ -194,6 +194,7 @@ from distributed_tensorflow_ibm_mnist_tpu.core.generate import (
 from distributed_tensorflow_ibm_mnist_tpu.models.quant import quantize_params_int8
 from distributed_tensorflow_ibm_mnist_tpu.models.transformer import reset_cache_slots
 from distributed_tensorflow_ibm_mnist_tpu.ops.flash_attention import flash_attention
+from distributed_tensorflow_ibm_mnist_tpu.ops.paged_attention import paged_kernel_eligible
 from distributed_tensorflow_ibm_mnist_tpu.parallel.mesh import shard_map_compat
 from distributed_tensorflow_ibm_mnist_tpu.parallel.ring_attention import (
     make_ring_attention,
@@ -703,7 +704,12 @@ class InferenceEngine:
                     f"kv_pages ({kv_pages}) cannot hold one full-length "
                     f"request: need >= max_len/kv_page_size + 1 "
                     f"({n_row + 1}; page 0 is the reserved trash page)")
-            decode_model = model.clone(page_size=kv_page_size)
+            # the decode clone also learns the one fact about the mesh a
+            # module cannot see: off-mesh (tp == 1 and cp == 1) the window
+            # runs on one device, where single-token paged attention may be
+            # a Mosaic kernel (models/transformer._paged_decode_attention)
+            decode_model = model.clone(page_size=kv_page_size,
+                                       paged_one_device=self._mesh is None)
         else:
             decode_model = model
         self._kv_pages = int(kv_pages)
@@ -903,6 +909,14 @@ class InferenceEngine:
             self.cache = _zeros_like_shapes(_shapes, self._cache_shardings)
             self._pool = KVPagePool(kv_pages, kv_page_size)
             self._page_bytes = pool_page_bytes(self.cache)
+            # whether the decode window's attention is the paged kernel:
+            # the model's own rule (same predicate, same facts), evaluated
+            # once so ServingStats can count the windows that took it
+            _leaf = next(iter(self.cache.values()))["pages_k"]
+            self._paged_kernel = bool(
+                speculative is None and decode_model.paged_one_device
+                and paged_kernel_eligible(decode_model.dtype, _leaf.dtype,
+                                          kv_page_size, *_leaf.shape[2:]))
             self._radix = (
                 RadixCache(kv_page_size)
                 if (radix_cache is None or radix_cache) else None)
@@ -915,6 +929,7 @@ class InferenceEngine:
         else:
             self.cache = _zeros_like_shapes(_shapes, self._cache_shardings)
             self._pool = None
+            self._paged_kernel = False
             self._radix = None
             self._slot_alloc = [None] * slots
             self._deferred_free = []
@@ -2239,7 +2254,8 @@ class InferenceEngine:
                         # / rejected lanes) is the window's waste
                         waste += k - appended
                 self.stats.window(dispatch_s, readback_s,
-                                  steps=decoding_at_dispatch * k, waste=waste)
+                                  steps=decoding_at_dispatch * k, waste=waste,
+                                  paged_kernel=self._paged_kernel)
                 if self._tracer is not None:
                     wid = self._tracer.complete(
                         "window", t_w0, self.clock(), cat="serving", k=k,

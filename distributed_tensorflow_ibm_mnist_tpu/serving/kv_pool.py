@@ -28,6 +28,16 @@ means slab ``p`` in EVERY layer's pool), which is what lets the radix
 prefix cache (serving/radix_cache.py) refcount a whole-model prefix block
 as one integer.
 
+Reads of the pool take two forms (models/transformer.py
+``_paged_decode_attention``): multi-token chunks, int8 pools and tp/cp-
+sharded pools gather a row's whole virtual span ``pool[block_table]``; a
+single-token step of a one-device engine over a compute-dtype pool with
+head dim 128 runs the ops/paged_attention.py kernel, which walks a row's
+block table only as far as its cursor and never dereferences an entry
+past it.  The kernel reads the leaves exactly as laid out above (one
+``(hkv, d)`` tile per token, viewed in place as 32-bit rows), so the layout
+contract is unchanged and there is ONE definition of it, here.
+
 Everything jitted here is donation-friendly: the engine wraps
 ``make_paged_insert``/``paged_reset``/``make_paged_extend`` in ``jax.jit``
 with the cache donated, same as the dense path (the ~23% donation win from

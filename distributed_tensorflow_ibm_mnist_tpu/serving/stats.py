@@ -178,6 +178,9 @@ class ServingStats:
         self._end_t: float | None = None
         # --- decode-ahead window accounting (ISSUE 5) ---
         self._windows = 0
+        self._paged_kernel_windows = 0  # of those, dispatched through the
+        #   paged-attention kernel (ops/paged_attention.py); 0 on an engine
+        #   whose decode fell back to the pool[block_table] gather
         self._dispatch_time = 0.0  # window jit-call time (async dispatch)
         self._readback_time = 0.0  # the blocking (slots, k) host sync
         self._window_steps = 0     # occupied-slot decode steps dispatched
@@ -241,12 +244,15 @@ class ServingStats:
                 self._decode_steps += 1
 
     def window(self, dispatch_s: float, readback_s: float, steps: int,
-               waste: int) -> None:
+               waste: int, paged_kernel: bool = False) -> None:
         """One decode-ahead window: ``steps`` = occupied slots × window
         length dispatched, ``waste`` = the subset discarded on the host
-        (tokens decoded past a row's EOS/budget inside the window)."""
+        (tokens decoded past a row's EOS/budget inside the window);
+        ``paged_kernel`` = its attention read live pages through the
+        paged-attention kernel rather than the full-span gather."""
         with self._lock:
             self._windows += 1
+            self._paged_kernel_windows += bool(paged_kernel)
             self._dispatch_time += dispatch_s
             self._readback_time += readback_s
             self._window_steps += steps
@@ -443,6 +449,7 @@ class ServingStats:
             ),
             "decode_ahead": self.decode_ahead,
             "n_windows": self._windows,
+            "paged_kernel_windows": self._paged_kernel_windows,
             "window_dispatch_s": round(self._dispatch_time, 6),
             "window_readback_s": round(self._readback_time, 6),
             "window_steps": self._window_steps,
@@ -564,6 +571,8 @@ class ServingStats:
                             if self._spec_drafted > 0 else None),
             "n_sampled_requests": self._n_sampled,
             "n_prefill_chunks": self._prefill_chunks,
+            "n_windows": self._windows,
+            "paged_kernel_windows": self._paged_kernel_windows,
             "kv_pages_live": self._kv_pages_live,
             "kv_pages_total": self._kv_pages_total,
             "slo_tracked": self._slo_tracked,
@@ -679,6 +688,8 @@ class ServingStats:
             "slot_occupancy": (round(occ_time / busy_weighted, 4)
                                if busy_weighted > 0 else None),
             "n_windows": n_windows,
+            "paged_kernel_windows": sum(
+                rec._paged_kernel_windows for rec in records),
             "window_dispatch_s": round(
                 sum(rec._dispatch_time for rec in records), 6),
             "window_readback_s": round(
