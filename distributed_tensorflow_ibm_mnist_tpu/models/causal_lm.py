@@ -58,6 +58,9 @@ class CausalLM(nn.Module):
     pos: str = "rope"  # 'rope' (rotary, default: length-extrapolating, no
     #   per-position params) | 'learned' (the (1, S, dim) table — bakes max
     #   length into the checkpoint; kept for ablation) | 'none'
+    rope_theta: float = 10000.0  # rotary base, passed to every block's
+    #   apply_rope (pos="rope")
+    norm_eps: float = 1e-6  # epsilon of every LayerNorm in the stack
     sow_kv: bool = False  # sow per-block K/V on the normal forward (the
     #   flash-prefill capture; core/generate.py clones the model with this)
     kv_cache_dtype: str = "native"  # "int8": quantized decode cache with
@@ -165,10 +168,12 @@ class CausalLM(nn.Module):
                 n_stages=self.pp_stages,
                 per_stage=self.depth // self.pp_stages, mlp_ratio=self.mlp_ratio,
                 attn_fn=attn_fn, pipeline_fn=self.pipeline_fn,
-                block_remat=self.block_remat, rope=rope, dtype=self.dtype,
-                name="pipe_blocks",
+                block_remat=self.block_remat, rope=rope,
+                rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                dtype=self.dtype, name="pipe_blocks",
             )(x, train=train)
-            x = nn.LayerNorm(dtype=self.dtype, name="norm_out")(x)
+            x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                             name="norm_out")(x)
             if self.tie_embeddings:
                 x = embed.attend(x)  # logits = x @ embed^T, weights shared
             else:
@@ -194,13 +199,15 @@ class CausalLM(nn.Module):
                 use_moe=self.moe_every > 0 and (i + 1) % self.moe_every == 0,
                 n_experts=self.n_experts, moe_capacity_factor=self.moe_capacity_factor,
                 moe_top_k=self.moe_top_k, moe_z_weight=self.moe_z_weight,
-                moe_fn=self.moe_fn, rope=rope, sow_kv=self.sow_kv,
+                moe_fn=self.moe_fn, rope=rope, rope_theta=self.rope_theta,
+                norm_eps=self.norm_eps, sow_kv=self.sow_kv,
                 window=self.window, kv_cache_dtype=self.kv_cache_dtype,
                 page_size=self.page_size,
                 paged_one_device=self.paged_one_device, quant=self.quant,
                 dtype=self.dtype, name=f"block_{i}",
             )(x, train, **extra)
-        x = nn.LayerNorm(dtype=self.dtype, name="norm_out")(x)
+        x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                         name="norm_out")(x)
         if self.tie_embeddings:
             # the tied head reads the (full-precision) embedding table —
             # quantizing it would also quantize the token lookup, so a

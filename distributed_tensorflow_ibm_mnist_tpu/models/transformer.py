@@ -246,6 +246,10 @@ class TransformerBlock(nn.Module):
     #   engine states this one fact when it clones its decode model
     #   (tp == 1 and cp == 1); it is what lets single-token paged decode
     #   take the ops/paged_attention.py kernel where the shapes allow.
+    rope_theta: float = 10000.0  # the rotary base (a model field: a
+    #   configuration that publishes another passes it; the default is what
+    #   every program compiled with before the field existed)
+    norm_eps: float = 1e-6  # LayerNorm epsilon (flax's default, likewise)
     quant: str = "none"  # "int8": WEIGHT-only quantization — every dense
     #   projection in the block (qkv/q_proj/kv_proj/proj/dense_0/dense_1)
     #   becomes an Int8Dense (models/quant.py): int8 kernel + per-output-
@@ -274,7 +278,8 @@ class TransformerBlock(nn.Module):
         b, s, _ = x.shape
         head_dim = self.dim // self.heads
 
-        h = nn.LayerNorm(dtype=self.dtype, name="norm_attn")(x)
+        h = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                         name="norm_attn")(x)
         hkv = self.heads_kv or self.heads
         if hkv == self.heads:
             qkv = self._dense(3 * self.dim, "qkv")(h)
@@ -297,7 +302,8 @@ class TransformerBlock(nn.Module):
             o = self._decode_attention(q, k, v, max_len, ragged)
         else:
             if self.rope:
-                q, k = apply_rope(q), apply_rope(k)
+                q = apply_rope(q, self.rope_theta)
+                k = apply_rope(k, self.rope_theta)
             if self.sow_kv:
                 # absolute-position-rotated K/V, exactly what the decode
                 # cache stores — the flash-prefill capture point
@@ -309,7 +315,8 @@ class TransformerBlock(nn.Module):
             o = nn.Dropout(self.dropout, deterministic=not train)(o)
         x = x + o
 
-        h = nn.LayerNorm(dtype=self.dtype, name="norm_mlp")(x)
+        h = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                         name="norm_mlp")(x)
         # MoE blocks decode too (round 4): routing is per-call — the decode
         # step routes its B current tokens with capacity sized for B, the
         # standard MoE serving semantics (equal to full-forward logits
@@ -406,8 +413,8 @@ class TransformerBlock(nn.Module):
 
         if ragged:
             if self.rope:
-                q = apply_rope(q, offset=idx)
-                k = apply_rope(k, offset=idx)
+                q = apply_rope(q, self.rope_theta, offset=idx)
+                k = apply_rope(k, self.rope_theta, offset=idx)
             if s == 1:
                 row_update = jax.vmap(
                     lambda c, u, i: jax.lax.dynamic_update_slice(
@@ -445,8 +452,8 @@ class TransformerBlock(nn.Module):
         else:
             idx0 = idx[0]  # uniform rows: ONE cursor, one slice update
             if self.rope:
-                q = apply_rope(q, offset=idx0)
-                k = apply_rope(k, offset=idx0)
+                q = apply_rope(q, self.rope_theta, offset=idx0)
+                k = apply_rope(k, self.rope_theta, offset=idx0)
             if quant:
                 k_st, k_sc = quantize_kv_int8(k)
                 v_st, v_sc = quantize_kv_int8(v)
@@ -606,8 +613,8 @@ class TransformerBlock(nn.Module):
         bt = bt_var.value  # (B, max_len // ps) page ids into the pool
 
         if self.rope:
-            q = apply_rope(q, offset=idx)
-            k = apply_rope(k, offset=idx)
+            q = apply_rope(q, self.rope_theta, offset=idx)
+            k = apply_rope(k, self.rope_theta, offset=idx)
         # write positions, clamped like the dense path's update-slice clamp
         pos = jnp.minimum(idx[:, None] + jnp.arange(s), max_len - 1)  # (B, S)
         page = jnp.take_along_axis(bt, pos // ps, axis=1)  # (B, S)
@@ -677,6 +684,8 @@ class StackedBlocks(nn.Module):
     block_remat: bool = False  # jax.checkpoint each block inside the stage
     #   scan: the pipeline's backward keeps only block-boundary residuals
     rope: bool = False
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -688,6 +697,7 @@ class StackedBlocks(nn.Module):
             dim=self.dim, heads=self.heads, heads_kv=self.heads_kv,
             mlp_ratio=self.mlp_ratio,
             dropout=0.0, attn_fn=self.attn_fn, attn=self.attn, rope=self.rope,
+            rope_theta=self.rope_theta, norm_eps=self.norm_eps,
             dtype=self.dtype,
         )
         sample = jnp.zeros((1, x.shape[1], self.dim), x.dtype)

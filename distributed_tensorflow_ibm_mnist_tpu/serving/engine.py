@@ -502,6 +502,44 @@ class InferenceEngine:
                     "attn_fn for prefill — a model that already carries a "
                     "custom attn_fn would be silently clobbered; pass the "
                     "base model and let the engine compose the ring")
+        # --- layer kinds, resolved once: a model some of whose layers keep
+        # a fixed-size recurrent state per row instead of per-token K/V
+        # (models/sala.py) gets a state pool beside the page pool, owned by
+        # the same cache tree and the same programs.  What such a model
+        # cannot have yet is refused here, by name, never degraded.
+        self._recurrent = bool(getattr(model, "has_recurrent_state", False))
+        self._read_plan = getattr(model, "decode_read_plan", None)
+        if self._recurrent:
+            if not (kv_page_size and prefill_chunk):
+                raise ValueError(
+                    "a model with recurrent-state layers is served through "
+                    "the paged cache by chunked prefill only: it needs "
+                    "kv_page_size > 0 and prefill_chunk > 0 (a chunk carries "
+                    "the row's state forward; there is no bucketed prefill "
+                    "for it)")
+            if prefill_chunk % kv_page_size:
+                raise ValueError(
+                    f"prefill_chunk ({prefill_chunk}) must be whole pages "
+                    f"({kv_page_size}) for a model with recurrent-state "
+                    "layers: a chunk starts on a page and on a compressed-"
+                    "key stride")
+            if radix_cache:
+                raise ValueError(
+                    "radix prefix sharing is refused for a model with "
+                    "recurrent-state layers: a shared prefix's K/V pages are "
+                    "only half of its cache — the state after that prefix "
+                    "would have to be snapshotted and shared with them, and "
+                    "this engine keeps no state snapshots (docs/SERVING.md); "
+                    "pass radix_cache=False or leave it unset")
+            radix_cache = False
+            if (tp > 1 or cp > 1 or speculative is not None or role != "both"
+                    or prefix_cache_bytes or quant == "int8"):
+                raise ValueError(
+                    "a model with recurrent-state layers runs on one chip, "
+                    "role='both', without speculative decoding, the prefix "
+                    "cache or int8 weights: its paged kernels are not "
+                    "partitioned, a rejected draft cannot rewind a state, a "
+                    "handoff moves pages and not states")
         # persistent XLA compilation cache: warm processes (and respawned
         # replicas) skip recompiling the engine's program family.  Placed
         # from outside — utils/compile_cache.py
@@ -794,6 +832,8 @@ class InferenceEngine:
         top_k_ = int(top_k)
         window_ = self.decode_ahead
 
+        recurrent_ = self._recurrent
+
         def _window_impl(params, cache, tok, active, temps, topps, topks,
                          minps, keys, pos):
             # decode_ahead fused decode+pick steps as ONE dispatch
@@ -804,9 +844,21 @@ class InferenceEngine:
             # sampling mix (greedy included) is this ONE program — the
             # census never moves across distinct (temperature, top_p,
             # top_k, min_p, seed) configs.
+            if recurrent_:
+                # (trace time: a uniform model's program never sees this)
+                # the window is told which rows are decoding: a state layer
+                # must not absorb the garbage step of an idle or prefilling
+                # row (a K/V layer's lands in the trash page or above the
+                # chunk cursor)
+                n_valid = jnp.asarray(active, jnp.int32)
+                cache = {name: {**e, "n_valid": n_valid}
+                         for name, e in cache.items()}
             cache, blk, logps, last, pos = _sample_window_core(
                 decode_model, params, cache, tok, active, temps, topps,
                 topks, minps, keys, pos, window_, max_len, True, pad_id_)
+            if recurrent_:
+                cache = {name: {k: v for k, v in e.items() if k != "n_valid"}
+                         for name, e in cache.items()}
             return _pin(cache), blk, logps, last, pos
 
         self._window = jax.jit(_window_impl, donate_argnums=(1,))
@@ -912,7 +964,8 @@ class InferenceEngine:
             # whether the decode window's attention is the paged kernel:
             # the model's own rule (same predicate, same facts), evaluated
             # once so ServingStats can count the windows that took it
-            _leaf = next(iter(self.cache.values()))["pages_k"]
+            _leaf = next(e["pages_k"] for e in self.cache.values()
+                         if "pages_k" in e)
             self._paged_kernel = bool(
                 speculative is None and decode_model.paged_one_device
                 and paged_kernel_eligible(decode_model.dtype, _leaf.dtype,
@@ -1012,6 +1065,23 @@ class InferenceEngine:
             last_progress_t=self._last_progress_t,
         )
         return v
+
+    def _count_recurrent_window(self) -> None:
+        """The counters of an engine whose model keeps recurrent state and
+        selects blocks, taken where the decode window is dispatched: the
+        state pool's occupancy, and what the window's attention reads —
+        the MODEL's own arithmetic (``decode_read_plan``) on the host's
+        record of each decoding row's length."""
+        self.stats.state_sample(self.occupied, self.slots)
+        ctx = np.array(
+            [r.tokens.size + len(r.generated)
+             for r, p in zip(self._slot_req, self._slot_prefill)
+             if r is not None and p is None], np.int64)
+        if self._read_plan is None or not ctx.size:
+            return
+        # step i of the window writes the token at position ctx - 1 + i
+        self.stats.sparse_step(
+            *self._read_plan(ctx[:, None] + np.arange(self.decode_ahead)))
 
     def _stamp_memory(self) -> None:
         """(Re-)stamp the per-chip memory figures into ``self.stats`` —
@@ -1734,7 +1804,9 @@ class InferenceEngine:
             # pending from the previous tenant must not zero it back
             reset_mask[slot] = False
             t_c1 = self.clock()
-            self.stats.chunk(t_c1 - t_c0)
+            self.stats.chunk(t_c1 - t_c0, start=done)
+            if self._recurrent:
+                self.stats.state_sample(self.occupied, self.slots)
             if self._tracer is not None and req.trace is not None:
                 # per-chunk child span under the request's admit phase
                 self._tracer.complete(
@@ -2122,6 +2194,8 @@ class InferenceEngine:
                                     self._active_dev, temps_dev, topps_dev,
                                     topks_dev, minps_dev, keys_dev, pos_dev)
                     else:
+                        if self._recurrent:
+                            self._count_recurrent_window()
                         with self._compile.site(self._site(f"decode_window[k{k}]")):
                             self.cache, blk_dev, logp_dev, last_dev, pos_out = \
                                 self._window(
@@ -2281,6 +2355,17 @@ class InferenceEngine:
         #    the next admission starts from a clean row
         with host_span("engine.reset"):
             if reset_mask.any():
+                if self._paged_kernel:
+                    # every row nobody holds restarts with them, in the same
+                    # dispatch: an idle row's cursor counts one garbage
+                    # token a window and the paged decode kernel reads as
+                    # many trash-page positions as the cursor says, so a
+                    # window's device time climbed with the windows since a
+                    # slot was last used (0.20 -> 0.48 ms a layer for 62
+                    # idle rows at 4096, v5e)
+                    for slot, req in enumerate(self._slot_req):
+                        if req is None:
+                            reset_mask[slot] = True
                 with self._compile.site(self._site("slot_reset")):
                     self.cache = self._reset(self.cache, self._dev(reset_mask))
             # deferred page frees apply only now, AFTER the reset dispatch is

@@ -19,6 +19,13 @@ Layout contract (mirrors the dense cache per block name):
              ["pages_k_scale"/"pages_v_scale": (n_pages, ps, hkv)],
              "block_table": (B, max_len // ps) int32, "index": (B,)}
 
+A model whose layers keep different kinds of state (models/sala.py) says
+itself what each layer holds (``model.paged_cache_shapes``): a sparse layer
+the paged entry above plus ``kc`` (B, max_len / stride, hkv, d) float32
+compressed keys, a lightning layer ``{"state": (B, H, d, d) float32,
+"index": (B,)}`` and no pages at all.  ``ROW_LEAVES`` names those per-row
+leaves; every function here takes an entry as it finds it.
+
 Page 0 is a reserved TRASH page: every unallocated block-table entry points
 at it, so idle rows' decode writes land in garbage nobody reads (the model's
 causal mask only exposes positions below a live row's cursor, all of which
@@ -67,6 +74,13 @@ import jax.numpy as jnp
 from ..core.generate import _zeros_like_shapes
 
 TRASH_PAGE = 0
+# leaves whose leading axis is the SLOT and whose size does not grow with a
+# row's length — a lightning layer's recurrent ``state``, a sparse layer's
+# compressed keys ``kc`` (models/sala.py).  They live beside the page pool
+# in the same cache tree and are owned by the same programs: a prefill chunk
+# narrows them to its row and writes the row back; a row's first chunk
+# starts them from nothing, so reset has no work to do on them
+ROW_LEAVES = ("state", "kc")
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
@@ -167,6 +181,10 @@ def paged_cache_shapes(model, params, slots: int, max_len: int,
             f"({page_size}) so each slot's virtual span is exactly max_len")
     if n_pages < 2:
         raise ValueError(f"n_pages must be >= 2, got {n_pages}")
+    if hasattr(model, "paged_cache_shapes"):
+        # a model whose layers keep different kinds of state says itself
+        # what each layer holds (it has no dense layout to derive it from)
+        return model.paged_cache_shapes(slots, max_len, page_size, n_pages)
     dense = model.clone(page_size=0) if getattr(model, "page_size", 0) else model
     shapes = jax.eval_shape(
         lambda p: dense.apply(
@@ -265,8 +283,9 @@ def paged_reset(cache, slot_mask):
     out = {}
     for name, entry in cache.items():
         e = dict(entry)
-        e["block_table"] = jnp.where(
-            mask[:, None], TRASH_PAGE, entry["block_table"])
+        if "block_table" in entry:  # a state-only layer has none
+            e["block_table"] = jnp.where(
+                mask[:, None], TRASH_PAGE, entry["block_table"])
         e["index"] = jnp.where(mask, 0, entry["index"])
         out[name] = e
     return out
@@ -350,6 +369,9 @@ def make_paged_extend(model, max_len: int, page_size: int) -> Callable:
             "make_paged_extend needs the paged model clone "
             "(model.page_size > 0) — it decodes through the page pool")
     n_row = max_len // page_size
+    # a model with per-row state is told how many of the chunk's tokens
+    # are real (its state must not absorb the padding)
+    counts_valid = bool(getattr(model, "has_recurrent_state", False))
 
     def extend(params, cache, slot, bt_row, suffix, start, suffix_len):
         # install the row's block table first: the chunk decodes through it
@@ -359,17 +381,24 @@ def make_paged_extend(model, max_len: int, page_size: int) -> Callable:
                 "block_table": jax.lax.dynamic_update_slice(
                     e["block_table"], bt_row[None].astype(jnp.int32),
                     (slot, 0)),
-            }
+            } if "block_table" in e else e
             for name, e in cache.items()
         }
-        # B=1 sub-cache over the FULL pool: only the slot's table row and
-        # cursor narrow to the row; the pool leaves are shared storage
+        # B=1 sub-cache over the FULL pool: only the slot's table row,
+        # cursor and per-row leaves narrow to the row; the pool leaves are
+        # shared storage
         sub = {}
         for name, e in cache.items():
             se = {k: v for k, v in e.items() if k.startswith("pages_")}
-            se["block_table"] = jax.lax.dynamic_slice(
-                e["block_table"], (slot, 0), (1, n_row))
+            if "block_table" in e:
+                se["block_table"] = jax.lax.dynamic_slice(
+                    e["block_table"], (slot, 0), (1, n_row))
+            for k in ROW_LEAVES:
+                if k in e:
+                    se[k] = jax.lax.dynamic_slice_in_dim(e[k], slot, 1, axis=0)
             se["index"] = jnp.zeros((1,), jnp.int32) + start
+            if counts_valid:
+                se["n_valid"] = jnp.zeros((1,), jnp.int32) + suffix_len
             sub[name] = se
         logits, vars_ = model.apply(
             {"params": params, "cache": sub}, suffix.astype(jnp.int32),
@@ -382,6 +411,9 @@ def make_paged_extend(model, max_len: int, page_size: int) -> Callable:
             for key in e:
                 if key.startswith("pages_"):
                     oe[key] = new[name][key]
+                elif key in ROW_LEAVES:
+                    oe[key] = jax.lax.dynamic_update_slice_in_dim(
+                        e[key], new[name][key], slot, axis=0)
             # real cursor, not the padded chunk's clamped one
             oe["index"] = e["index"].at[slot].set(
                 (start + suffix_len).astype(jnp.int32))

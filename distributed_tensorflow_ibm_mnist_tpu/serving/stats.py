@@ -71,6 +71,7 @@ latency — a deadline kill is not a service time).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import hashlib
 import random
@@ -220,8 +221,17 @@ class ServingStats:
         self._prefill_chunks = 0     # extend[b{C}] dispatches
         self._chunk_stall_s = 0.0    # total wall seconds inside chunk
         #   dispatches (the decode-latency budget chunking bounds)
+        self._chunk_starts: dict[int, int] = {}  # chunks by first position
         self._longest_prompt = 0     # max admitted prompt tokens; 0 = no
         #   admission recorded (summary reports None)
+        # --- block-sparse decode and the recurrent-state pool --- all
+        # zero on engines over a uniform K/V model.  Blocks are counted per
+        # (decoding row, sparse layer, KV head) of every decode step
+        self._sparse_blocks_read = 0   # pages the paged kernel was handed
+        self._sparse_blocks_live = 0   # pages those rows held
+        self._dense_len_rows = 0       # row-steps still within dense_len
+        self._state_rows_in_use = 0    # slots holding a request's state
+        self._state_rows_total = 0
         # --- compile accounting (ISSUE 6) --- the engine's own XLA
         # program family: a CompileTracker snapshot DELTA from engine
         # construction to stats emission (utils/tracing.py)
@@ -304,14 +314,34 @@ class ServingStats:
             else:
                 self._radix_misses += 1
 
-    def chunk(self, stall_s: float) -> None:
+    def chunk(self, stall_s: float, start: int | None = None) -> None:
         """One chunked-prefill dispatch (ISSUE 14): ``stall_s`` = wall
         seconds the dispatch occupied the host loop — the bounded
         per-iteration decode-latency cost the chunked_prefill bench leg
-        gates on."""
+        gates on; ``start`` = the chunk's first position in its row, counted
+        per start (a chunk's attention cost grows with it)."""
         with self._lock:
             self._prefill_chunks += 1
             self._chunk_stall_s += float(stall_s)
+            if start is not None:
+                self._chunk_starts[int(start)] = (
+                    self._chunk_starts.get(int(start), 0) + 1)
+
+    def sparse_step(self, blocks_read: int, blocks_live: int,
+                    dense_rows: int) -> None:
+        """One decode step of an engine whose model selects blocks: pages
+        read and pages live, summed over decoding rows, sparse layers and
+        KV heads, and the rows that still read densely."""
+        with self._lock:
+            self._sparse_blocks_read += int(blocks_read)
+            self._sparse_blocks_live += int(blocks_live)
+            self._dense_len_rows += int(dense_rows)
+
+    def state_sample(self, rows_in_use: int, rows_total: int) -> None:
+        """Occupancy of the recurrent-state pool (one row a slot)."""
+        with self._lock:
+            self._state_rows_in_use = int(rows_in_use)
+            self._state_rows_total = int(rows_total)
 
     def prompt_admitted(self, n_tokens: int) -> None:
         """One admission's prompt length (chunked engines call this at
@@ -530,6 +560,12 @@ class ServingStats:
             "longest_prompt_admitted": (
                 self._longest_prompt if self._longest_prompt > 0 else None
             ),
+            "prefill_chunk_starts": dict(sorted(self._chunk_starts.items())),
+            "sparse_blocks_read": self._sparse_blocks_read,
+            "sparse_blocks_live": self._sparse_blocks_live,
+            "dense_len_rows": self._dense_len_rows,
+            "state_rows_in_use": self._state_rows_in_use,
+            "state_rows_total": self._state_rows_total,
             # compile accounting (None until set_compile — an engine that
             # never emitted stats has no delta to report)
             "n_compiled_programs": (
@@ -575,6 +611,8 @@ class ServingStats:
             "paged_kernel_windows": self._paged_kernel_windows,
             "kv_pages_live": self._kv_pages_live,
             "kv_pages_total": self._kv_pages_total,
+            "state_rows_in_use": self._state_rows_in_use,
+            "state_rows_total": self._state_rows_total,
             "slo_tracked": self._slo_tracked,
             "slo_met": self._slo_met,
             "slo_miss": self._slo_miss,
@@ -748,6 +786,13 @@ class ServingStats:
                 if busy_total > 0 and n_chunks > 0 else None),
             "longest_prompt_admitted": (
                 max(longest) if longest else None),
+            "prefill_chunk_starts": dict(sorted(sum(
+                (collections.Counter(rec._chunk_starts) for rec in records),
+                collections.Counter()).items())),
+            **{k: sum(getattr(rec, "_" + k) for rec in records)
+               for k in ("sparse_blocks_read", "sparse_blocks_live",
+                         "dense_len_rows", "state_rows_in_use",
+                         "state_rows_total")},
             "tp": tps.pop() if len(tps) == 1 else None,
             "cp": cps.pop() if len(cps) == 1 else None,
             # common scheme or None when replicas disagree (a mid-rollout

@@ -192,6 +192,42 @@ def test_engine_radix_shared_pages_through_kernel():
     assert s["paged_kernel_windows"] == s["n_windows"] > 0
 
 
+def test_idle_rows_restart_with_every_retirement():
+    """The kernel reads as many (trash-page) positions for an idle row as
+    its cursor says, and an idle row's cursor counts one garbage token a
+    window: so every row nobody holds is zeroed in the reset dispatch a
+    retirement makes anyway.  One long request beside short ones, one
+    after another: the slot that was never used stays within a short
+    request's life of zero, the long row keeps its own cursor, tokens are
+    the dense engine's."""
+    model, params = _model_and_params()
+
+    def run(**kw):
+        eng = InferenceEngine(model, params, slots=3, max_len=32, **kw)
+        long = eng.submit([1, 2, 3], max_new=24)
+        shorts, idle_max = [], 0
+        while eng.has_work:
+            if long.status != "done" and (
+                    not shorts or shorts[-1].status == "done"):
+                shorts.append(eng.submit([4, 5], max_new=3))
+            eng.step()
+            if kw:
+                index = np.asarray(next(iter(eng.cache.values()))["index"])
+                idle_max = max(idle_max, int(index[2]))
+        assert long.status == "done" and all(r.status == "done" for r in shorts)
+        return [tuple(r.generated) for r in [long] + shorts], idle_max, eng
+
+    want, _, _ = run()
+    got, idle_max, eng = run(kv_page_size=8, radix_cache=False)
+    assert got == want
+    s = eng.stats.summary()
+    assert s["paged_kernel_windows"] == s["n_windows"] > 20
+    # slot 2 never held a request: without the restart its cursor stands at
+    # the number of windows run (over 20), with it at the windows since the
+    # last short request retired
+    assert idle_max <= 6
+
+
 @pytest.mark.parametrize("case", ["int8", "tp2", "spec"])
 def test_ineligible_engines_keep_the_gather_path(case):
     """int8 KV, a tp mesh and speculative verify windows read 0 and decode
