@@ -78,7 +78,10 @@ distribution.
 
 ISSUE 13 adds the SAMPLING-aware siblings the serving engine composes:
 :func:`_pick_rows` (argmax / temperature / top-p / top-k — ISSUE 14 —
-selected by per-row *data* planes, never by program shape),
+selected by per-row *data* planes, never by program shape; since
+ISSUE 29 its work follows the planes too, through ``lax.cond`` on
+:func:`pick_work`: no sampled row, no filters; no top-k / top-p row, no
+sort),
 :func:`_sample_window_core`
 (the decode-ahead scan with per-row fold-in PRNG keys and a position
 counter threaded through the carry, emitting per-token logprobs), and
@@ -467,42 +470,38 @@ def make_verify_window(model, max_len: int, draft_len: int,
     return verify
 
 
-def _filter_topp_rows(logits, top_ps):
-    """Per-row nucleus filter with ``top_p`` as DATA — the plane-driven
-    sibling of :func:`_filter_logits`'s static ``top_p`` branch (same keep
-    rule: ranks whose PRECEDING mass is < p survive, so the argmax always
-    does).  ``top_ps`` is (B,) float32; rows with ``top_p <= 0`` or
-    ``>= 1`` pass through unfiltered, so greedy and unfiltered-sampling
-    rows ride the same program as nucleus rows."""
-    neg = jnp.finfo(logits.dtype).min
-    sorted_logits = jnp.sort(logits, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = jnp.concatenate(
-        [jnp.ones_like(cum[:, :1], bool), cum[:, :-1] < top_ps[:, None]],
-        axis=-1)
-    cutoff = jnp.min(
-        jnp.where(keep, sorted_logits, jnp.inf), axis=-1, keepdims=True)
-    filtered = jnp.where(logits < cutoff, neg, logits)
-    nucleus = (top_ps > 0.0) & (top_ps < 1.0)
-    return jnp.where(nucleus[:, None], filtered, logits)
+def _filter_sorted_rows(logits, top_ks, top_ps):
+    """Per-row top-k, then nucleus, with ``top_k`` / ``top_p`` as DATA —
+    the plane-driven sibling of :func:`_filter_logits`'s static branches,
+    same keep rules, from ONE descending sort of ``[B, vocab]``.
 
-
-def _filter_topk_rows(logits, top_ks):
-    """Per-row top-k filter with ``top_k`` as DATA — the plane-driven
-    sibling of :func:`_filter_logits`'s static ``top_k`` branch (same keep
-    rule: the k highest logits survive, ties at the k-th value included).
-    ``top_ks`` is (B,) int32; rows with ``top_k <= 0`` or ``>= vocab``
-    pass through unfiltered, so greedy and unfiltered-sampling rows ride
-    the same program as top-k rows."""
+    Top-k: the k highest logits survive, ties at the k-th value included;
+    ``top_ks`` is (B,) int32, rows with ``top_k <= 0`` or ``>= vocab``
+    pass through.  Nucleus, over what top-k left: ranks whose PRECEDING
+    mass is < p survive, so the argmax always does; ``top_ps`` is (B,)
+    float32, rows with ``top_p <= 0`` or ``>= 1`` pass through.  The
+    nucleus needs the descending sort of the top-k-FILTERED logits, and
+    that is the sort already taken with every entry below the k-th value
+    floored (they are its tail already) — so the result is bit for bit
+    what two filters with a sort each give (tests/test_sampling.py holds
+    it to that reference)."""
     neg = jnp.finfo(logits.dtype).min
     vocab = logits.shape[-1]
     sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
     k = jnp.clip(top_ks, 1, vocab).astype(jnp.int32)
     kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-    filtered = jnp.where(logits < kth, neg, logits)
-    on = (top_ks > 0) & (top_ks < vocab)
-    return jnp.where(on[:, None], filtered, logits)
+    k_on = ((top_ks > 0) & (top_ks < vocab))[:, None]
+    logits = jnp.where(k_on & (logits < kth), neg, logits)
+    sorted_desc = jnp.where(k_on & (sorted_desc < kth), neg, sorted_desc)
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = jnp.concatenate(
+        [jnp.ones_like(cum[:, :1], bool), cum[:, :-1] < top_ps[:, None]],
+        axis=-1)
+    cutoff = jnp.min(
+        jnp.where(keep, sorted_desc, jnp.inf), axis=-1, keepdims=True)
+    p_on = ((top_ps > 0.0) & (top_ps < 1.0))[:, None]
+    return jnp.where(p_on & (logits < cutoff), neg, logits)
 
 
 def _filter_minp_rows(logits, min_ps):
@@ -522,29 +521,60 @@ def _filter_minp_rows(logits, min_ps):
     return jnp.where(on[:, None], filtered, logits)
 
 
-def _tempered_rows(logits, temps, topps, topks, minps):
+def pick_work(active, temps, topps, topks, vocab: int):
+    """What a pick over these rows has to compute, from the planes
+    themselves: ``(samples, sorts)`` — some DECODING row has
+    ``temperature > 0``; some such row has top-k or top-p on.  ``active``
+    masks the rows that decode, because a retired slot keeps its stale
+    planes until the slot is reused.
+
+    Operators and ``.any()`` only, so the SAME expression is the device's
+    ``lax.cond`` predicate (jnp planes, inside the program) and the
+    host's ``sampled_windows`` / ``sorted_windows`` count (the engine's
+    numpy mirrors)."""
+    sampled = active & (temps > 0.0)
+    sort_on = ((topks > 0) & (topks < vocab)) | ((topps > 0.0) & (topps < 1.0))
+    return sampled.any(), (sampled & sort_on).any()
+
+
+def _tempered_rows(logits, temps, topps, topks, minps, active=None):
     """The per-row SAMPLING distribution as filtered logits: temperature
     scaling (before the filters, matching :func:`make_generator`'s static
     order), then the data-driven top-k, nucleus, and min-p filters (top-k
     first, like the static path; min-p last so its confidence-relative
-    cut applies to the already-truncated support).  Rows with
-    ``temps <= 0`` get a well-defined placeholder (divide by 1) — their
-    output is overridden by argmax in :func:`_pick_rows`, the placeholder
-    just keeps the math NaN-free."""
+    cut applies to the already-truncated support).
+
+    The two sorted filters share one sort (:func:`_filter_sorted_rows`),
+    and that sort runs only if some ``active`` row (default: all) with
+    ``temps > 0`` has one of them on (:func:`pick_work`, a ``lax.cond`` on
+    the planes): temperature-only and min-p-only batches pay a softmax
+    and no sort.  A row that samples gets the same bits either way.  Rows
+    with ``temps <= 0`` get a well-defined placeholder (divide by 1) —
+    their output is overridden by argmax in :func:`_pick_rows`, the
+    placeholder just keeps the math NaN-free."""
+    topks = jnp.asarray(topks, jnp.int32)
+    if active is None:
+        active = jnp.ones(temps.shape, bool)
     safe_t = jnp.where(temps > 0.0, temps, 1.0)[:, None]
     scaled = logits / safe_t
-    scaled = _filter_topk_rows(scaled, jnp.asarray(topks, jnp.int32))
-    scaled = _filter_topp_rows(scaled, topps)
+    _, sorts = pick_work(active, temps, topps, topks, logits.shape[-1])
+    scaled = jax.lax.cond(
+        sorts, lambda x: _filter_sorted_rows(x, topks, topps), lambda x: x,
+        scaled)
     return _filter_minp_rows(scaled, jnp.asarray(minps, jnp.float32))
 
 
-def _pick_rows(logits, temps, topps, topks, minps, keys):
+def _pick_rows(logits, temps, topps, topks, minps, keys, active=None):
     """Data-driven per-row pick: (B, V) logits + per-row ``temps`` /
     ``topps`` / ``topks`` / ``minps`` / already-fold-in'd ``keys`` (B, 2)
     uint32 planes -> ``((B,) int32 token, (B,) float32 logprob)``.  Rows
-    with ``temps <= 0`` take argmax (greedy) — selected by ``where`` on
-    the DATA, so every (temperature, top_p, top_k, min_p) mix shares one
-    program.
+    with ``temps <= 0`` take argmax (greedy); every (temperature, top_p,
+    top_k, min_p) mix shares one program, whose WORK follows the planes:
+    the sampled pick (filters + the categorical draw over ``[B, vocab]``)
+    sits under a ``lax.cond`` on :func:`pick_work` — "some ``active`` row
+    (default: all) has ``temps > 0``" — so an all-greedy step computes
+    argmax and the logprob and nothing else.  A greedy row's token does
+    not depend on which branch its neighbours put the step on.
 
     The logprob is always ``log_softmax`` of the RAW logits at the
     emitted token — the model's own distribution, before temperature or
@@ -552,10 +582,17 @@ def _pick_rows(logits, temps, topps, topks, minps, keys):
     sampling configs and greedy requests report calibrated confidences.
     """
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    filtered = _tempered_rows(logits, temps, topps, topks, minps)
-    sampled = jax.vmap(
-        lambda l, k: jax.random.categorical(k, l))(filtered, keys)
-    tok = jnp.where(temps > 0.0, sampled.astype(jnp.int32), greedy)
+    if active is None:
+        active = jnp.ones(temps.shape, bool)
+    samples, _ = pick_work(active, temps, topps, topks, logits.shape[-1])
+
+    def sampled_pick():
+        filtered = _tempered_rows(logits, temps, topps, topks, minps, active)
+        sampled = jax.vmap(
+            lambda l, k: jax.random.categorical(k, l))(filtered, keys)
+        return jnp.where(temps > 0.0, sampled.astype(jnp.int32), greedy)
+
+    tok = jax.lax.cond(samples, sampled_pick, lambda: greedy)
     logp = jnp.take_along_axis(
         jax.nn.log_softmax(logits, axis=-1), tok[:, None], axis=1)[:, 0]
     return tok, logp.astype(jnp.float32)
@@ -594,7 +631,7 @@ def _sample_window_core(model, params, cache, tok, active, temps, topps,
                                           max_len, ragged)
         step_keys = jax.vmap(jax.random.fold_in)(keys, pos)
         nxt, logp = _pick_rows(logits, temps, topps, topks, minps,
-                               step_keys)
+                               step_keys, active)
         nxt = jnp.where(active, nxt, pad)
         logp = jnp.where(active, logp, 0.0)
         return (cache, nxt, pos + step), (nxt, logp)
@@ -664,7 +701,8 @@ def _verify_sample_core(model, params, cache, chunk, draft_lens, active,
     filt = _tempered_rows(flat, jnp.repeat(temps, k),
                           jnp.repeat(topps, k),
                           jnp.repeat(topks, k),
-                          jnp.repeat(minps, k)).reshape(b, k, -1)
+                          jnp.repeat(minps, k),
+                          jnp.repeat(active, k)).reshape(b, k, -1)
     probs = jax.nn.softmax(filt, axis=-1)                        # (B, k, V)
 
     # generated index per position and its key family (flattened B*k)

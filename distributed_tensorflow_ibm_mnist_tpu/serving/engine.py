@@ -52,10 +52,12 @@ the engine keeps per-slot (slots,) temperature/top-p/top-k planes and a
 (slots, 2) base-key plane as runtime DATA into ONE compiled window program
 (core/generate.py ``_sample_window_core``) — greedy and sampled rows ride
 the same program, so the compile census is invariant across sampling
-mixes.  Each generated token's raw-logits logprob comes back with the
-token block (``Request.logprobs``), and a request's stream is a pure
-function of its seed — restarts and failover replays are
-token-identical.
+mixes; inside it the pick branches on the planes (ISSUE 29), so an
+all-greedy window pays for argmax alone and only a window with a top-k or
+top-p row sorts the vocabulary.  Each generated token's raw-logits
+logprob comes back with the token block (``Request.logprobs``), and a
+request's stream is a pure function of its seed — restarts and failover
+replays are token-identical.
 
 Two more host-loop latencies hide behind the window (ISSUE 5):
 
@@ -190,6 +192,7 @@ from distributed_tensorflow_ibm_mnist_tpu.core.generate import (
     _zeros_like_shapes,
     cache_shapes,
     make_prefill,
+    pick_work,
 )
 from distributed_tensorflow_ibm_mnist_tpu.models.quant import quantize_params_int8
 from distributed_tensorflow_ibm_mnist_tpu.models.transformer import reset_cache_slots
@@ -843,7 +846,11 @@ class InferenceEngine:
             # base-key/position ride as per-slot DATA planes, so every
             # sampling mix (greedy included) is this ONE program — the
             # census never moves across distinct (temperature, top_p,
-            # top_k, min_p, seed) configs.
+            # top_k, min_p, seed) configs.  What a step COMPUTES follows
+            # the planes (lax.cond inside the program): no decoding row
+            # samples -> argmax and the logprob only; some do -> the
+            # filters and the draw, with the one [slots, vocab] sort only
+            # if a sampled row has top-k or top-p on.
             if recurrent_:
                 # (trace time: a uniform model's program never sees this)
                 # the window is told which rows are decoding: a state layer
@@ -1009,6 +1016,9 @@ class InferenceEngine:
         self._slot_key = np.tile(self._default_key, (slots, 1))
         # (temps, topps, topks, minps, keys) on device; None = stale
         self._planes_dev = None
+        # (samples, sorts) of the windows dispatched on _active_dev and
+        # _planes_dev as they stand: recomputed with them, never per window
+        self._pick_work = (False, False)
         # device (slots,) int32 count of already-generated tokens per slot
         # — the PRNG position plane.  Plain windows return the advanced
         # plane (carried like _tok_dev); spec windows re-upload fresh each
@@ -2169,20 +2179,27 @@ class InferenceEngine:
                             self._pos_dev = self._dev(np.array(
                                 [0 if r is None else len(r.generated)
                                  for r in self._slot_req], np.int32))
-                    if self._active_dev is None:
+                    if self._active_dev is None or self._planes_dev is None:
                         # PREFILLING slots stay INACTIVE: their pages hold a
                         # partial prompt — garbage decode writes above the
                         # chunk cursor are overwritten by the next chunk
-                        self._active_dev = self._dev(np.array(
+                        active = np.array(
                             [r is not None and p is None
                              for r, p in zip(self._slot_req,
-                                             self._slot_prefill)]))
-                    if self._planes_dev is None:
-                        self._planes_dev = (self._dev(self._slot_temp),
-                                            self._dev(self._slot_topp),
-                                            self._dev(self._slot_topk),
-                                            self._dev(self._slot_minp),
-                                            self._dev(self._slot_key))
+                                             self._slot_prefill)])
+                        self._active_dev = self._dev(active)
+                        if self._planes_dev is None:
+                            self._planes_dev = (self._dev(self._slot_temp),
+                                                self._dev(self._slot_topp),
+                                                self._dev(self._slot_topk),
+                                                self._dev(self._slot_minp),
+                                                self._dev(self._slot_key))
+                        # which branches of the pick these windows take: the
+                        # device's own predicate (core/generate.py pick_work)
+                        # on the mirrors of what was just uploaded
+                        self._pick_work = tuple(map(bool, pick_work(
+                            active, self._slot_temp, self._slot_topp,
+                            self._slot_topk, self.model.num_classes)))
                     (temps_dev, topps_dev, topks_dev, minps_dev,
                      keys_dev) = self._planes_dev
                     t_disp = self.clock()
@@ -2329,7 +2346,9 @@ class InferenceEngine:
                         waste += k - appended
                 self.stats.window(dispatch_s, readback_s,
                                   steps=decoding_at_dispatch * k, waste=waste,
-                                  paged_kernel=self._paged_kernel)
+                                  paged_kernel=self._paged_kernel,
+                                  sampled=self._pick_work[0],
+                                  sorted_=self._pick_work[1])
                 if self._tracer is not None:
                     wid = self._tracer.complete(
                         "window", t_w0, self.clock(), cat="serving", k=k,

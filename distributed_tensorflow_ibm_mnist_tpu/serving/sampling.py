@@ -131,7 +131,9 @@ def base_key(seed: int) -> np.ndarray:
 def first_pick(logits, temps, topps, topks, minps, keys, pos):
     """The shared first-token pick program: fold each row's base key at
     its generated index (0 for a fresh request) and pick with the same
-    data-driven math the decode window uses.  Module-level jit: every
+    data-driven :func:`_pick_rows` the decode window uses (every row
+    counts as decoding, so a greedy landing computes argmax alone and a
+    sampled one sorts only for top-k / top-p).  Module-level jit: every
     engine in the process shares one compilation per shape (top-k and
     min-p ride the ``topks``/``minps`` DATA planes), and prefix-cache
     hit/miss paths are bit-identical by construction.

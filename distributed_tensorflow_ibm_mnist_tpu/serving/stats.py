@@ -182,6 +182,10 @@ class ServingStats:
         self._paged_kernel_windows = 0  # of those, dispatched through the
         #   paged-attention kernel (ops/paged_attention.py); 0 on an engine
         #   whose decode fell back to the pool[block_table] gather
+        self._sampled_windows = 0  # windows in which some decoding row had
+        #   temperature > 0: the pick ran its filters and the draw
+        self._sorted_windows = 0   # of those, some such row had top-k or
+        #   top-p on: the pick sorted [slots, vocab] (core/generate.py)
         self._dispatch_time = 0.0  # window jit-call time (async dispatch)
         self._readback_time = 0.0  # the blocking (slots, k) host sync
         self._window_steps = 0     # occupied-slot decode steps dispatched
@@ -254,15 +258,21 @@ class ServingStats:
                 self._decode_steps += 1
 
     def window(self, dispatch_s: float, readback_s: float, steps: int,
-               waste: int, paged_kernel: bool = False) -> None:
+               waste: int, paged_kernel: bool = False,
+               sampled: bool = False, sorted_: bool = False) -> None:
         """One decode-ahead window: ``steps`` = occupied slots × window
         length dispatched, ``waste`` = the subset discarded on the host
         (tokens decoded past a row's EOS/budget inside the window);
         ``paged_kernel`` = its attention read live pages through the
-        paged-attention kernel rather than the full-span gather."""
+        paged-attention kernel rather than the full-span gather;
+        ``sampled`` / ``sorted_`` = what its token pick had to compute
+        (``core.generate.pick_work`` on the planes it was dispatched
+        with): the sampled branch at all, and the vocabulary sort in it."""
         with self._lock:
             self._windows += 1
             self._paged_kernel_windows += bool(paged_kernel)
+            self._sampled_windows += bool(sampled)
+            self._sorted_windows += bool(sorted_)
             self._dispatch_time += dispatch_s
             self._readback_time += readback_s
             self._window_steps += steps
@@ -480,6 +490,8 @@ class ServingStats:
             "decode_ahead": self.decode_ahead,
             "n_windows": self._windows,
             "paged_kernel_windows": self._paged_kernel_windows,
+            "sampled_windows": self._sampled_windows,
+            "sorted_windows": self._sorted_windows,
             "window_dispatch_s": round(self._dispatch_time, 6),
             "window_readback_s": round(self._readback_time, 6),
             "window_steps": self._window_steps,
@@ -609,6 +621,8 @@ class ServingStats:
             "n_prefill_chunks": self._prefill_chunks,
             "n_windows": self._windows,
             "paged_kernel_windows": self._paged_kernel_windows,
+            "sampled_windows": self._sampled_windows,
+            "sorted_windows": self._sorted_windows,
             "kv_pages_live": self._kv_pages_live,
             "kv_pages_total": self._kv_pages_total,
             "state_rows_in_use": self._state_rows_in_use,
@@ -728,6 +742,9 @@ class ServingStats:
             "n_windows": n_windows,
             "paged_kernel_windows": sum(
                 rec._paged_kernel_windows for rec in records),
+            "sampled_windows": sum(
+                rec._sampled_windows for rec in records),
+            "sorted_windows": sum(rec._sorted_windows for rec in records),
             "window_dispatch_s": round(
                 sum(rec._dispatch_time for rec in records), 6),
             "window_readback_s": round(

@@ -23,6 +23,11 @@ The decisive properties:
   token-identical on a survivor with exactly-once streaming delivery.
 * STATS — sampled-request accounting (counts, mean temperature, NLL
   histogram) flows through ``ServingStats`` and the router rollup.
+* WORK FOLLOWS THE PLANES (ISSUE 29) — the pick sorts the vocabulary once,
+  and only in a step where some decoding row samples with top-k or top-p
+  on; an all-greedy step is argmax and the logprob.  Same bits as the
+  two-sort composition, same one program, and the host counts the
+  windows by the device's own predicate (``pick_work``).
 """
 
 import functools
@@ -33,10 +38,16 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_ibm_mnist_tpu.core.generate import (
+    _filter_minp_rows,
+    _filter_sorted_rows,
+    _pick_rows,
+    _sample_window_core,
     _tempered_rows,
     _verify_sample_core,
+    init_cache,
     make_decode_step,
     make_prefill,
+    pick_work,
 )
 from distributed_tensorflow_ibm_mnist_tpu.models import get_model
 from distributed_tensorflow_ibm_mnist_tpu.serving import (
@@ -46,7 +57,10 @@ from distributed_tensorflow_ibm_mnist_tpu.serving import (
     SamplingParams,
     ServingStats,
 )
-from distributed_tensorflow_ibm_mnist_tpu.serving.sampling import base_key
+from distributed_tensorflow_ibm_mnist_tpu.serving.sampling import (
+    base_key,
+    first_pick,
+)
 from distributed_tensorflow_ibm_mnist_tpu.utils.chaos import (
     FaultInjector,
     FaultPlan,
@@ -152,13 +166,11 @@ def test_filter_topk_rows_per_row_support():
     """The data-plane top-k filter (ISSUE 14): each ROW keeps its own k
     highest logits and floors the rest; k=0 and k>=vocab are per-row
     no-ops (the off states), all in one (B, V) program."""
-    from distributed_tensorflow_ibm_mnist_tpu.core.generate import (
-        _filter_topk_rows,
-    )
     rng = np.random.default_rng(0)
     raw = rng.normal(size=(4, 16)).astype(np.float32)  # no ties w.h.p.
     ks = jnp.asarray([0, 1, 3, 16], jnp.int32)
-    out = np.asarray(_filter_topk_rows(jnp.asarray(raw), ks))
+    out = np.asarray(_filter_sorted_rows(
+        jnp.asarray(raw), ks, jnp.zeros((4,), jnp.float32)))  # top-p off
     neg = np.finfo(np.float32).min
     np.testing.assert_array_equal(out[0], raw[0])      # 0 = filter off
     np.testing.assert_array_equal(out[3], raw[3])      # k >= vocab = off
@@ -207,9 +219,6 @@ def test_filter_minp_rows_per_row_support():
     tokens whose probability is below its own ``min_p * max_prob`` —
     the threshold scales with the row's confidence; min_p=0 is a per-row
     no-op and min_p=1 keeps only the argmax, all in one (B, V) program."""
-    from distributed_tensorflow_ibm_mnist_tpu.core.generate import (
-        _filter_minp_rows,
-    )
     rng = np.random.default_rng(1)
     raw = rng.normal(size=(3, 16)).astype(np.float32)  # no ties w.h.p.
     mps = jnp.asarray([0.0, 0.5, 1.0], jnp.float32)
@@ -424,6 +433,227 @@ def test_verify_rejection_sampling_matches_target_distribution():
 
 
 # ----------------------------------------------------------------------
+# the pick's work follows its planes (ISSUE 29): one sort, under a cond
+
+
+def _two_sort_reference(logits, temps, topps, topks, minps):
+    """What ``_tempered_rows`` computed before ISSUE 29, written out here
+    so that it cannot move with the code: top-k with a sort of its own,
+    the nucleus with a second sort, then min-p."""
+    neg = jnp.finfo(logits.dtype).min
+    vocab = logits.shape[-1]
+    scaled = logits / jnp.where(temps > 0.0, temps, 1.0)[:, None]
+    # top-k
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k = jnp.clip(topks, 1, vocab).astype(jnp.int32)
+    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+    on = (topks > 0) & (topks < vocab)
+    scaled = jnp.where(on[:, None], jnp.where(scaled < kth, neg, scaled),
+                       scaled)
+    # nucleus
+    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]
+    cum = jnp.cumsum(jax.nn.softmax(sorted_logits, axis=-1), axis=-1)
+    keep = jnp.concatenate(
+        [jnp.ones_like(cum[:, :1], bool), cum[:, :-1] < topps[:, None]],
+        axis=-1)
+    cutoff = jnp.min(
+        jnp.where(keep, sorted_logits, jnp.inf), axis=-1, keepdims=True)
+    nucleus = (topps > 0.0) & (topps < 1.0)
+    scaled = jnp.where(nucleus[:, None],
+                       jnp.where(scaled < cutoff, neg, scaled), scaled)
+    # min-p
+    probs = jax.nn.softmax(scaled, axis=-1)
+    cut = minps[:, None] * jnp.max(probs, axis=-1, keepdims=True)
+    return jnp.where((minps > 0.0)[:, None],
+                     jnp.where(probs < cut, neg, scaled), scaled)
+
+
+V = 32
+# name -> rows of (temperature, top_p, top_k, min_p)
+PLANE_MIXES = {
+    "topk_only": [(0.7, 0.0, 5, 0.0), (1.3, 0.0, 2, 0.0), (1.0, 0.0, 0, 0.0)],
+    "topp_only": [(0.7, 0.9, 0, 0.0), (1.3, 0.35, 0, 0.0), (1.0, 0.0, 0, 0.0)],
+    "both": [(0.7, 0.9, 5, 0.0), (0.4, 0.3, 7, 0.2), (1.6, 0.5, 0, 0.0),
+             (1.0, 0.0, 3, 0.1)],
+    "ties_at_kth": [(1.0, 0.0, 3, 0.0), (1.0, 0.8, 3, 0.0),
+                    (0.5, 0.6, 12, 0.0)],
+    "k_ge_vocab": [(0.9, 0.0, V, 0.0), (0.9, 0.0, V + 9, 0.0),
+                   (0.9, 0.7, V, 0.0)],
+    "p_ge_one": [(0.9, 1.0, 0, 0.0), (0.9, 1.0, 4, 0.0)],
+    "k_one": [(1.7, 0.0, 1, 0.0), (1.7, 0.9, 1, 0.0)],
+    "greedy_row_beside": [(0.0, 0.0, 0, 0.0), (0.8, 0.9, 6, 0.0),
+                          (0.0, 0.0, 0, 0.0), (1.2, 0.0, 0, 0.3)],
+}
+
+
+def _planes(rows):
+    t, p, k, m = zip(*rows)
+    return (jnp.asarray(t, jnp.float32), jnp.asarray(p, jnp.float32),
+            jnp.asarray(k, jnp.int32), jnp.asarray(m, jnp.float32))
+
+
+def _logits(n, seed, ties=False):
+    raw = np.random.default_rng(seed).normal(size=(n, V)).astype(np.float32)
+    if ties:
+        # a quarter-step grid: every row has runs of equal logits, so the
+        # k-th value is shared and the nucleus cutoff lands inside a run
+        raw = np.round(raw * 2.0) / 2.0
+    return jnp.asarray(3.0 * raw)
+
+
+@pytest.mark.parametrize("mix", sorted(PLANE_MIXES))
+def test_one_sort_filters_equal_two_sort_reference_bitwise(mix):
+    rows = PLANE_MIXES[mix]
+    temps, topps, topks, minps = _planes(rows)
+    logits = _logits(len(rows), seed=len(mix), ties=(mix == "ties_at_kth"))
+    want = np.asarray(
+        _two_sort_reference(logits, temps, topps, topks, minps))
+    for fn in (_tempered_rows, jax.jit(_tempered_rows)):
+        got = np.asarray(fn(logits, temps, topps, topks, minps))
+        np.testing.assert_array_equal(got, want)
+    # the filter did something wherever it was on (the case is not vacuous)
+    on = [(0 < k < V) or (0.0 < p < 1.0) for _, p, k, _ in rows]
+    cut = (want == np.finfo(np.float32).min).any(axis=-1)
+    assert all(c for c, o in zip(cut, on) if o)
+
+
+@pytest.mark.parametrize("key_seed", [0, 1, (1 << 31) + 7])
+def test_all_greedy_pick_is_argmax_and_raw_logprob(key_seed):
+    """All-greedy planes: the token is argmax and the logprob the raw
+    ``log_softmax`` at it, EXACTLY, whatever keys ride along."""
+    logits = _logits(5, seed=3, ties=True)  # ties: argmax's first-index rule
+    zf, zi = jnp.zeros((5,), jnp.float32), jnp.zeros((5,), jnp.int32)
+    keys = jnp.asarray(np.random.default_rng(key_seed).integers(
+        0, 1 << 32, size=(5, 2), dtype=np.uint32))
+    tok, logp = jax.jit(_pick_rows)(logits, zf, zf, zi, zf, keys)
+    want = np.argmax(np.asarray(logits), axis=-1)
+    np.testing.assert_array_equal(np.asarray(tok), want)
+    raw = np.asarray(jax.nn.log_softmax(logits, axis=-1))
+    np.testing.assert_array_equal(
+        np.asarray(logp), raw[np.arange(5), want])
+    # and the first-token program is the same pick
+    tok1, logp1 = first_pick(logits, zf, zf, zi, zf, keys, zi)
+    np.testing.assert_array_equal(np.asarray(tok1), want)
+    np.testing.assert_array_equal(np.asarray(logp1), np.asarray(logp))
+
+
+def _sorts(jaxpr, in_cond=False):
+    """``(sorts under some cond, sorts under none)`` in a jaxpr, through
+    every nested jaxpr (pjit bodies, scan bodies, cond branches)."""
+    inside = outside = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            inside, outside = inside + in_cond, outside + (not in_cond)
+        under = in_cond or eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            a, b = _sorts(sub, under)
+            inside, outside = inside + a, outside + b
+    return inside, outside
+
+
+def _window_jaxpr():
+    model, params = _model_and_params()
+    b, max_len = 3, 16
+    cache = init_cache(model, params, b, max_len)
+    zf, zi = jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32)
+    return jax.make_jaxpr(functools.partial(
+        _sample_window_core, model, window=2, max_len=max_len, ragged=True,
+        pad_id=0))(params, cache, zi, jnp.ones((b,), bool), zf, zf, zi, zf,
+                   jnp.zeros((b, 2), jnp.uint32), zi)
+
+
+def _pick_jaxpr():
+    zf, zi = jnp.zeros((3,), jnp.float32), jnp.zeros((3,), jnp.int32)
+    return jax.make_jaxpr(_pick_rows)(
+        jnp.zeros((3, V)), zf, zf, zi, zf, jnp.zeros((3, 2), jnp.uint32))
+
+
+@pytest.mark.parametrize("program", [_pick_jaxpr, _window_jaxpr],
+                         ids=["pick_rows", "sample_window"])
+def test_pick_sorts_once_and_only_under_a_cond(program):
+    """The regression ISSUE 29 removed cannot come back unseen: the
+    vocabulary sort is ONE, and no step pays it unconditionally."""
+    assert _sorts(program().jaxpr) == (1, 0)
+
+
+@pytest.mark.parametrize("sp", [
+    SamplingParams(temperature=0.8, top_p=0.9, top_k=6, seed=21),  # sorts
+    SamplingParams(temperature=0.8, min_p=0.1, seed=21),           # does not
+], ids=["sorted_filters", "temperature_minp_only"])
+def test_mixed_batch_rows_do_not_feel_each_other(sp):
+    """A greedy row's token and logprob do not depend on whether its
+    neighbour samples (the step takes another branch of the same program),
+    and the sampled row's stream is what it is alone."""
+    model, params = _model_and_params(seed=10)
+    mix = [None, sp, None, None, None]
+    toks, lps = _serve(model, params, sampling=mix)
+    g_toks, g_lps = _serve(model, params)               # all greedy
+    for i, s in enumerate(mix):
+        if s is None:
+            assert toks[i] == g_toks[i] and lps[i] == g_lps[i], i
+    alone, alone_lp = _serve(model, params, sampling=[sp],
+                             prompts=[PROMPTS[1]])
+    assert toks[1] == alone[0]
+    np.testing.assert_allclose(lps[1], alone_lp[0], atol=1e-5)
+    assert toks[1] != g_toks[1]                          # it did sample
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_pick_work_is_one_expression_on_host_and_device(seed):
+    """The host's ``sampled_windows`` / ``sorted_windows`` count and the
+    program's ``lax.cond`` predicates are ``pick_work`` on the same
+    planes: numpy mirrors and device arrays answer alike."""
+    rng = np.random.default_rng(seed)
+    n = 6
+    active = rng.random(n) < 0.5
+    temps = np.where(rng.random(n) < 0.4, rng.random(n) + 0.1,
+                     0.0).astype(np.float32)
+    topps = rng.choice([0.0, 0.5, 1.0], n).astype(np.float32)
+    topks = rng.choice([0, 3, V, V + 1], n).astype(np.int32)
+    host = pick_work(active, temps, topps, topks, V)
+    dev = jax.jit(pick_work, static_argnums=4)(
+        jnp.asarray(active), jnp.asarray(temps), jnp.asarray(topps),
+        jnp.asarray(topks), V)
+    assert tuple(map(bool, host)) == tuple(map(bool, dev))
+    sampled = active & (temps > 0)
+    on = ((topks > 0) & (topks < V)) | ((topps > 0) & (topps < 1))
+    assert tuple(map(bool, host)) == (sampled.any(), (sampled & on).any())
+
+
+def test_retired_sampled_slot_stops_the_sampled_branch():
+    """The stale plane: a sampled request retires, greedy ones go on in
+    the other slots and its slot stays empty.  Its plane row still says
+    ``temperature > 0``; the active mask is what turns the sampled branch
+    off — on the host's count and in the device's predicate alike."""
+    model, params = _model_and_params(seed=11)
+    eng = _engine(model, params, slots=3)
+    sp = SamplingParams(temperature=0.9, top_p=0.8, seed=4)
+    short = eng.submit(np.asarray(PROMPTS[0], np.int32), max_new=3,
+                       sampling=sp)
+    longs = [eng.submit(np.asarray(p, np.int32), max_new=12)
+             for p in PROMPTS[1:3]]
+    while short.status != "done":
+        eng.step()
+    at_retirement = eng.stats.summary()
+    # every token after the first (first_pick's) came from one window
+    assert at_retirement["sampled_windows"] == len(short.generated) - 1 == 2
+    assert at_retirement["sorted_windows"] == 2
+    eng.step()                        # a window after the retirement
+    assert eng.has_work and all(r.status != "done" for r in longs)
+    assert (eng._slot_temp > 0).any()                  # the plane is stale
+    assert eng._pick_work == (False, False)            # the host's reading
+    temps, topps, topks, _, _ = eng._planes_dev
+    dev = pick_work(eng._active_dev, temps, topps, topks,
+                    KW["num_classes"])                 # the device's
+    assert (bool(dev[0]), bool(dev[1])) == (False, False)
+    assert bool((temps > 0).any())     # `any(temps > 0)` would still sort
+    eng.run()
+    s = eng.stats.summary()
+    assert s["sampled_windows"] == s["sorted_windows"] == 2 < s["n_windows"]
+    eng.close()
+
+
+# ----------------------------------------------------------------------
 # failover: seeded replay is token-identical with exactly-once streaming
 
 
@@ -492,6 +722,12 @@ def test_stats_sampling_fields_and_merge():
     assert s["mean_temperature"] == pytest.approx(0.6, abs=1e-4)
     assert s["logprob_tokens"] == sum(len(r.generated) for r in reqs)
     assert s["nll_p50"] is not None and s["nll_p50"] >= 0
+    # the windows whose pick ran the sampled branch: the sampled request's
+    # 4 tokens after its first; temperature alone asks for no sort
+    assert s["sampled_windows"] == 4 <= s["n_windows"]
+    assert s["sorted_windows"] == 0
+    v = eng.stats.vitals()
+    assert (v["sampled_windows"], v["sorted_windows"]) == (4, 0)
     eng.close()
 
     # empty stats: every sampling figure is null, never NaN, and the
@@ -500,6 +736,15 @@ def test_stats_sampling_fields_and_merge():
     es = empty.summary()
     assert es["n_sampled_requests"] == 0
     assert es["mean_temperature"] is None and es["nll_p50"] is None
+    assert es["sampled_windows"] == es["sorted_windows"] == 0
+    other = ServingStats(slots=1)
+    other.window(0.0, 0.0, steps=1, waste=0, sampled=True, sorted_=True)
+    other.window(0.0, 0.0, steps=1, waste=0, sampled=True)
+    other.window(0.0, 0.0, steps=1, waste=0)
+    rolled = ServingStats.merge([eng.stats, other, empty])
+    assert rolled["n_windows"] == s["n_windows"] + 3
+    assert rolled["sampled_windows"] == 4 + 2
+    assert rolled["sorted_windows"] == 0 + 1
     merged = ServingStats.merge([eng.stats, empty])
     assert merged["n_sampled_requests"] == 1
     assert merged["mean_temperature"] == pytest.approx(0.6, abs=1e-4)
