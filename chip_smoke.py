@@ -202,7 +202,7 @@ def leg_lenet(leg: Leg) -> None:
 
 
 def _lm_config(leg: Leg, **over):
-    """The tracked long-context LM of bench.py (dim 512, S=8192, flash)."""
+    """The tracked long-context LM of rounds 3-5 (dim 512, S=8192, flash)."""
     import jax.numpy as jnp
 
     from distributed_tensorflow_ibm_mnist_tpu.utils.config import RunConfig

@@ -72,7 +72,7 @@ class Trainer:
         if tracer is not None:
             self._compile.bind(tracer)
         # the trainer OWNS the writer only when it built one itself — a
-        # caller-supplied writer (bench harnesses sharing one log) must
+        # caller-supplied writer (harnesses sharing one log) must
         # survive this trainer's close()
         self._owns_writer = writer is None
         self.writer = writer or MetricWriter(path=config.metrics_path, stdout=not config.quiet)
@@ -383,8 +383,8 @@ class Trainer:
         # Compile-census path label: every parallelism knob that changes
         # WHICH programs fit() compiles gets a token, so by-site compile
         # attribution distinguishes e.g. train_epoch[dp4_fsdp] from
-        # train_epoch[dp4] and the census gate
-        # (scripts/bench_train_census.py) can pin per-path budgets.
+        # train_epoch[dp4] and the census (tests/test_train_census.py)
+        # can pin per-path program counts.
         _parts = [f"dp{self.dp}"]
         if config.fsdp:
             _parts.append("fsdp")
@@ -783,7 +783,7 @@ class Trainer:
         executables on which inputs are committed, and ``out_shardings``
         commits every output — so pinning them on an unsharded (uncommitted)
         single-chip state made the next ``fit()`` recompile the whole epoch
-        program (20.7 s inside bench.py's time-to-accuracy on the v5e, PR
+        program (20.7 s inside a time-to-accuracy run on the v5e, PR
         21).  Without a mesh the copy simply follows its input.
         """
         def copy(s):
@@ -1683,8 +1683,8 @@ class Trainer:
         summary["n_compiled_programs"] = cdelta["n_compiled_programs"]
         summary["compile_time_s"] = round(cdelta["compile_time_s"], 3)
         # path-qualified site attribution (train_epoch[...]/eval[...]/
-        # h2d[...]) — the per-path census scripts/bench_train_census.py
-        # budgets against; strict JSON (plain dicts, ints, floats)
+        # h2d[...]) — what tests/test_train_census.py pins per path;
+        # strict JSON (plain dicts, ints, floats)
         summary["compile_by_site"] = cdelta["by_site"]
         tokens = self._tokens_per_sec(images / steady_mean / chips) if steady_mean else None
         if tokens is not None:

@@ -1,8 +1,8 @@
 """Weight-only int8 quantization for the serving decode path (ISSUE 12).
 
 Decode is weight-bandwidth-bound: every step streams the full parameter
-set from HBM and does ~2 FLOPs per byte with it (scripts/bench_decode.py's
-roofline).  Storing the matmul weights as int8 with per-OUTPUT-CHANNEL
+set from HBM and does ~2 FLOPs per byte with it (PERF.md section 5: the
+decode window against its weight-read roofline).  Storing the matmul weights as int8 with per-OUTPUT-CHANNEL
 symmetric f32 scales cuts that dominant stream ~4x vs f32 masters (~2x vs
 the bf16 compute-dtype copy) at a bounded accuracy cost — the same move
 the int8 KV cache (models/transformer.py::quantize_kv_int8) made for the
@@ -168,7 +168,7 @@ class Int8Dense(nn.Module):
 
 def weight_stream_bytes(params) -> int:
     """Total parameter bytes one decode step streams from HBM — the
-    honest bytes-moved figure the bench quant leg reports (int8 kernels
+    honest bytes-moved figure tests/test_quant.py pins at 3.2-4x (int8 kernels
     count 1 byte/element, their f32 scales 4, everything else its own
     itemsize)."""
     return sum(

@@ -67,7 +67,7 @@ _LOG2E = 1.4426950408889634  # exp(x) == exp2(x * log2(e)): the kernels run
 _LN2 = 0.6931471805599453
 
 # Default VMEM tile sizes (q rows x k cols per inner step).  Swept on the
-# v5e at B=4 S=8192 H=8 D=64 causal bf16 (scripts/bench_flash.py): larger
+# v5e at B=4 S=8192 H=8 D=64 causal bf16 (round 5's microbenchmark): larger
 # tiles amortize the scratch read-modify-write of the online-softmax state
 # and per-step DMA setup — fwd+bwd walks 251 ms (128x128) -> 91.6
 # (256x512) -> 62.5 (512x1024), then plateaus (1024x1024: 68.0, 512x2048:
@@ -140,8 +140,8 @@ def _dot(a, b, dims):
 
 
 # Interior-tile mask elision (round 5): when False, every live tile runs
-# the masked body — the pre-round-5 behavior, kept togglable so
-# scripts/bench_flash.py can A/B the split in one session.
+# the masked body — the pre-round-5 behavior, kept togglable so one
+# session on the chip can A/B the split.
 _SPLIT_INTERIOR = True
 
 

@@ -22,8 +22,8 @@ module is that loop, deliberately small and deliberately mechanism-free:
   ``hysteresis_down`` ticks, never below ``min_replicas`` — via :meth:`ServingDaemon.retire_replica`, which
   DRAINS first (the replica finishes its in-flight work undispatchable,
   then the watchdog closes it under the pump lock).  Scale-down drops
-  nothing, ever; that is the router's ``begin_retire`` contract, and the
-  bench gates it.
+  nothing, ever; that is the router's ``begin_retire`` contract, and
+  tests/test_autoscaler.py holds it.
 
 Hysteresis is the whole art here: both verdicts must hold for N
 consecutive ticks, and any tick of contrary evidence resets the streak —
@@ -32,12 +32,11 @@ does not flap the tier.  After every action the OTHER direction's streak
 resets too (an up decision is evidence against down, and vice versa).
 
 The controller runs either embedded (call :meth:`tick` from your own
-loop — the deterministic path tests and the bench drive) or as its own
+loop — the deterministic path the tests drive) or as its own
 daemon thread (:meth:`start` / :meth:`stop`) ticking every
 ``interval_s``.  :meth:`chip_seconds` integrates healthy-engines x
 seconds over the capacity log — the denominator that makes elastic and
-fixed tiers comparable at equal hardware cost (goodput per chip-second,
-the bench's gate currency).
+fixed tiers comparable at equal hardware cost (goodput per chip-second).
 """
 
 from __future__ import annotations

@@ -2634,8 +2634,7 @@ class InferenceEngine:
 
         Returns ``{"programs", "compile_s", "wall_s", "by_site"}`` — the
         compile delta this call caused (0 programs on a warm persistent
-        cache is the success case the bench ``compile_cache`` block
-        measures as cold-vs-prewarmed TTFT).
+        cache is the success case).
         """
         if self._closed:
             raise RuntimeError("engine is closed")
@@ -2652,8 +2651,8 @@ class InferenceEngine:
             # decode replicas own NO prefill program: pages arrive via
             # admit_prefilled (serving/kv_handoff.py), so the family here
             # is first_pick + the decode window + reset + the per-page
-            # handoff writer — and the per-role census (bench_disagg)
-            # pins that no prefill[b*]/extend[b*] site ever appears
+            # handoff writer — and the per-role census
+            # (tests/test_disagg.py) pins that no prefill[b*]/extend[b*] site ever appears
             vocab = getattr(self.model, "num_classes")
             last_logits = self._dev(np.zeros((1, vocab), np.float32))
             with self._compile.site(self._site("handoff_install")):

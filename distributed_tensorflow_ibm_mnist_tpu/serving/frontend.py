@@ -77,7 +77,7 @@ exemplar-bearing OpenMetrics when the scraper sends
 ``Accept: application/openmetrics-text``.
 
 Thread model: the server runs on ONE asyncio event loop (optionally on
-its own thread via :meth:`FrontDoor.start_in_thread` — the test/bench
+its own thread via :meth:`FrontDoor.start_in_thread` — the test
 harness path).  Handler coroutines touch the daemon only through its
 thread-safe surface (``submit``/``cancel``/``conservation``); daemon
 threads touch asyncio only through ``call_soon_threadsafe``.  The
@@ -85,7 +85,7 @@ frontend's own counters are loop-thread-only ints mirrored into the
 registry.
 
 :class:`FrontDoorClient` is the curl-equivalent blocking client
-(stdlib ``http.client``) the example, tests, and bench drive the wire
+(stdlib ``http.client``) the example and the tests drive the wire
 with — including an SSE parser, so parity checks compare the actual
 bytes on the wire against :meth:`ServingDaemon.stream`.
 """
@@ -185,7 +185,7 @@ class FrontDoor:
     """HTTP/SSE network edge over one :class:`~.daemon.ServingDaemon`.
 
     ``port=0`` binds an ephemeral port (read :attr:`port` after start —
-    the test/bench pattern).  ``max_connections`` bounds concurrently
+    the tests' pattern).  ``max_connections`` bounds concurrently
     served connections; past it a connection is answered 503 +
     ``Retry-After`` immediately.  ``registry`` is the MetricsRegistry
     ``/metrics`` exposes — default: the daemon's telemetry registry when
@@ -981,7 +981,7 @@ class _swallow:
 
 
 # ----------------------------------------------------------------------
-# the curl-equivalent client (stdlib http.client) — example/tests/bench
+# the curl-equivalent client (stdlib http.client) — example/tests
 
 
 class FrontDoorClient:
@@ -992,7 +992,7 @@ class FrontDoorClient:
     verdict; :meth:`stream` yields tokens off the SSE wire as they
     arrive and stores the terminal event on :attr:`last_terminal` —
     byte-level parity with :meth:`ServingDaemon.stream` is exactly what
-    the bench gates.
+    tests/test_frontend.py holds.
     """
 
     def __init__(self, host: str, port: int, timeout: float = 60.0):

@@ -68,9 +68,8 @@ merely re-runs a finished request to the same tokens), so outside
   ``os.fsync``-s at most every ``fsync_interval_s`` seconds when dirty
   (group commit: bounded host-crash exposure, and the ~ms fsync never
   rides the serving path);
-* ``"always"`` — flush + fsync every append (a database WAL; the
-  2 %-overhead bench gate runs the default policy,
-  scripts/bench_crash.py measures all three).
+* ``"always"`` — flush + fsync every append (a database WAL;
+  tests/test_journal.py::test_journal_fsync_policies counts all three).
 
 Chaos: the ``journal-write`` site (utils/chaos.py) fires one event per
 append.  ``kind="torn"`` writes a prefix of the encoded line and stops
@@ -130,8 +129,8 @@ class RequestJournal:
 
     Thread-safe: one lock serializes append/rotate/close — the daemon
     appends from its submit callers AND its delivery thread.  ``stats()``
-    is the overhead ledger the bench gate reads (append count/bytes/
-    seconds, fsyncs, rotations).
+    is the overhead ledger (append count/bytes/seconds, fsyncs,
+    rotations).
     """
 
     def __init__(self, directory: str, *,
@@ -175,8 +174,7 @@ class RequestJournal:
         # (microseconds); a background syncer fsyncs every
         # fsync_interval_s WHEN dirty.  The durability contract is the
         # same — at most interval_s of exposure — but the ~1ms fsync
-        # never rides the serving path, which is what keeps the bench's
-        # 2% overhead gate honest.
+        # never rides the serving path.
         self._dirty = False
         self._syncer = None
         if self.fsync_policy == "interval":
@@ -196,7 +194,7 @@ class RequestJournal:
         Wall-clock over the whole call would bill the journal for GIL
         preemptions that land inside the span — scheduler noise an
         order of magnitude above the journal's own work — and the
-        bench's overhead gate would be measuring the scheduler.
+        ledger would be measuring the scheduler.
         """
         t0 = time.thread_time()
         io_s = 0.0

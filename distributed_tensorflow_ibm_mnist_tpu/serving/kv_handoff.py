@@ -41,7 +41,7 @@ length moves as N dispatches of the same two fixed-shape programs
 (``handoff_gather`` on the source, the per-page writer + no-forward
 ``bt_install`` under ``handoff_install`` on the destination) — the
 per-role compile census never moves with traffic, which is what
-``scripts/bench_disagg.py`` pins.
+tests/test_disagg.py::test_per_role_prewarm_census pins.
 """
 
 from __future__ import annotations
@@ -227,8 +227,8 @@ def deliver(engine, packet: "HandoffPacket") -> bool:
         req.pages = total
         # first token: the source's logits row through the SAME shared
         # pick program every landing path uses — bit-identical to the
-        # token a monolithic engine would have picked, which is what the
-        # bench's disagg-vs-monolithic token-parity gate checks
+        # token a monolithic engine would have picked, which is what
+        # tests/test_disagg.py's disagg-vs-monolithic token parity checks
         first, first_logp = engine._first_pick(
             req, engine._dev(packet.last_logits))
         req.generated.append(first)

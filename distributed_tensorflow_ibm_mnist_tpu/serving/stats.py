@@ -111,12 +111,12 @@ def percentiles(xs, qs=(50, 95, 99)) -> dict[str, float]:
 
 def transcript_digest(tokens) -> str:
     """Content address of one token transcript: blake2b over the int32
-    stream.  The token-parity primitive of the crash bench and the
-    recovery tests (serving/journal.py): a client transcript stitched
+    stream.  The token-parity primitive of the recovery tests
+    (tests/test_journal.py): a client transcript stitched
     across a SIGKILL — pre-crash SSE prefix + post-recovery resume —
     must digest identically to the uncrashed reference's, which is a
     stronger statement than equal lengths and cheaper to ship in a
-    one-line bench record than the streams themselves."""
+    one-line record than the streams themselves."""
     return hashlib.blake2b(np.asarray(tokens, np.int32).tobytes(),
                            digest_size=16).hexdigest()
 
@@ -327,8 +327,7 @@ class ServingStats:
     def chunk(self, stall_s: float, start: int | None = None) -> None:
         """One chunked-prefill dispatch (ISSUE 14): ``stall_s`` = wall
         seconds the dispatch occupied the host loop — the bounded
-        per-iteration decode-latency cost the chunked_prefill bench leg
-        gates on; ``start`` = the chunk's first position in its row, counted
+        per-iteration decode-latency cost chunking exists to bound; ``start`` = the chunk's first position in its row, counted
         per start (a chunk's attention cost grows with it)."""
         with self._lock:
             self._prefill_chunks += 1
@@ -560,8 +559,7 @@ class ServingStats:
             ),
             # chunked prefill (ISSUE 14; all-zero/None on whole-prompt
             # engines).  chunk_stall_frac = share of busy time spent
-            # inside chunk dispatches — the interleaving tax the bench
-            # leg bounds.
+            # inside chunk dispatches — the interleaving tax.
             "n_prefill_chunks": self._prefill_chunks,
             "chunk_stall_s": round(self._chunk_stall_s, 6),
             "chunk_stall_frac": (

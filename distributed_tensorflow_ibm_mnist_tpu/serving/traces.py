@@ -1,11 +1,11 @@
 """Recorded arrival traces: workload shapes as DATA, not driver code
 (ISSUE 17).
 
-The open-loop SLO bench (scripts/bench_slo.py) hardcodes its arrival
-process — a homogeneous Poisson generator inlined in the driver.  That
-measures overload, but only ONE shape of it, and the shape is not a
-thing you can save, diff, or replay against two tiers.  This module
-makes the workload a first-class artifact:
+A homogeneous Poisson generator inlined in a driver measures overload,
+but only ONE shape of it, and the shape is not a thing you can save,
+diff, or replay against two tiers.  This module makes the workload a
+first-class artifact (tests/test_autoscaler.py replays it against a live
+tier; the benchmark keeps its own copy, benchmark/traffic.py):
 
 * :class:`TraceEvent` / :class:`ArrivalTrace` — the schema: each event
   is an arrival offset from trace start plus the request's shape
@@ -16,7 +16,7 @@ makes the workload a first-class artifact:
   :meth:`ArrivalTrace.load`), so a shape generated once replays
   byte-identically against any tier configuration.
 * Generators for the canonical shapes: :func:`poisson_trace`
-  (homogeneous — the bench's existing process, now recordable),
+  (homogeneous, now recordable),
   :func:`bursty_trace` (on/off modulated: quiet base load with periodic
   arrival bursts — the autoscaler's reason to exist),
   :func:`diurnal_trace` (sinusoidal rate via Lewis-Shedler thinning —

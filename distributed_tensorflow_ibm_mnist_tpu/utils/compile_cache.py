@@ -9,7 +9,7 @@ the path is part of how a later process finds what an earlier one compiled,
 so a directory that moves never hits.
 
 Everything that wants warm compiles — ``Trainer``, ``InferenceEngine``
-(hence every ``Replica`` factory), ``bench.py``, the scripts,
+(hence every ``Replica`` factory), ``benchmark/run.py``, the scripts,
 ``chip_smoke.py`` — calls :func:`enable_compile_cache`; nothing else writes
 jax's cache-directory option.
 """
@@ -27,8 +27,7 @@ def compile_cache_dir() -> str:
     """The directory the cache uses when it is on.
 
     Imports no jax, so a parent process that must stay off the chip
-    (``bench.py`` before its compile-measurement children, the
-    ``chip_smoke.py`` parent) can inspect the cache's state.
+    (the ``chip_smoke.py`` parent) can inspect the cache's state.
     """
     return os.environ.get(ENV_VAR) or os.path.join(_CHECKOUT, ".cache", "xla")
 

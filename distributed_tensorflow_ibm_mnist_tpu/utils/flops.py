@@ -187,8 +187,7 @@ def ring_hop_bytes(
     grouped ring path never expands GQA before the hop — satellite 1 of
     ISSUE 20), ``2 * B * S_local * H_kv * D * dtype_bytes`` per layer.
     A full prefill performs ``cp - 1`` hops per layer, so total ring
-    traffic per chip is ``(cp - 1) * ring_hop_bytes(...)`` — the figure
-    bench_cp_serving.py reports.  On
+    traffic per chip is ``(cp - 1) * ring_hop_bytes(...)``.  On
     this CPU-emulation box the ppermute is a memcpy; the byte count is
     the honest analytic charge for real-ICI projections."""
     if seq_local < 0 or heads_kv < 1 or head_dim < 1:

@@ -7,8 +7,8 @@ needs N CPU devices.  From the shell that is
 this helper does the same from inside a process (the ``--virtual-devices``
 CLI flag, the multi-device examples, the driver's multichip dry run).
 
-It is never reached from ``Trainer``, ``InferenceEngine``, ``bench.py``'s
-chip phases or ``chip_smoke.py``: a program that wants the chip must not
+It is never reached from ``Trainer``, ``InferenceEngine``, the benchmark
+or ``chip_smoke.py``: a program that wants the chip must not
 quietly leave it for virtual CPUs.  Where a caller does leave an
 initialized accelerator backend, this says so on stderr.
 """

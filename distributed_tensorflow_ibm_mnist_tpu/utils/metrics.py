@@ -22,7 +22,7 @@ def _sanitize(v: Any) -> Any:
     ``Infinity`` tokens, which are NOT JSON and break every strict consumer
     of the log (a diverged loss must not corrupt the metrics file it is
     being recorded in).  Recurses through dicts/lists/tuples so nested
-    blocks (bench.py's comparison sections) get the same guarantee."""
+    blocks (a record's comparison sections) get the same guarantee."""
     if isinstance(v, dict):
         return {k: _sanitize(x) for k, x in v.items()}
     if isinstance(v, (list, tuple)):

@@ -38,8 +38,8 @@ memory with traffic.  Three pieces:
 Wiring follows the nil-guard zero-cost-off contract of ``chaos`` and
 ``Tracer``: every instrumented site is ``if self._telemetry is not None:
 ...``, so a component built without telemetry pays a single attribute
-test (the ``telemetry_overhead`` leg of scripts/bench_serving.py holds
-the wired-on cost under 2% in the primary serving regime).
+test (tests/test_telemetry.py::test_engine_without_telemetry_is_untouched;
+the wired-on cost is not measured on the chip).
 """
 
 from __future__ import annotations
@@ -532,7 +532,8 @@ class Telemetry:
         self.prefix = prefix
         # fsync=True makes every JSONL sample and Prometheus rewrite
         # crash-durable (survives SIGKILL, not just process exit) at the
-        # cost of one fsync per sample — the crash-bench post-mortem mode
+        # cost of one fsync per sample — the post-mortem mode the SIGKILL
+        # test of tests/test_journal.py reads back
         self.fsync = bool(fsync)
         self.registry = (registry if registry is not None
                          else MetricsRegistry(window=window))

@@ -25,7 +25,7 @@ The pieces:
   cache hits don't fire), each attributed to the SITE active at compile
   time (``with tracker.site("prefill[b32]")``), plus a count of how many
   of them the persistent compilation cache served.  This is what makes
-  "number of distinct compiled programs" a tracked bench metric — the
+  "number of distinct compiled programs" a tracked metric — the
   r04→r05 cold-compile regression (ROADMAP item 5) becomes reproducible
   and regression-gated per-PR.
 * :func:`host_span` — the program's spans on the PROFILER's clock: a
@@ -51,7 +51,8 @@ The pieces:
   everything); :func:`merge_traces` / :func:`trace_forest` /
   :meth:`Tracer.trace_events` — multi-process exports joined through
   hex ``span_ctx``/``parent_ctx`` edges into per-trace trees whose
-  connectivity is bench-gateable (scripts/bench_tracing.py).
+  connectivity a test can assert (tests/test_frontend.py,
+  tests/test_disagg.py and tests/test_journal.py do, end to end).
 
 Event schema (what ``export_trace`` writes, documented in
 docs/OBSERVABILITY.md): one JSON object ``{"traceEvents": [...],
@@ -781,7 +782,7 @@ def merge_traces(sources: list, path_or_file: str | IO[str] | None = None,
 
 def trace_forest(doc: dict) -> dict:
     """Group a (possibly merged) trace doc's spans by trace id and test
-    each group's CONNECTIVITY — the bench's trace-completeness gate.
+    each group's CONNECTIVITY — the trace-completeness check.
 
     Edges considered: in-file ``args.parent`` ids, ``args.links``, the
     W3C hex edges (a span whose ``args.parent_ctx`` equals another
@@ -1022,7 +1023,7 @@ class CompileTracker:
     @staticmethod
     def delta(after: dict, before: dict) -> dict:
         """What compiled BETWEEN two snapshots — the per-component figure
-        every consumer (ServingStats, bench blocks) actually reports."""
+        every consumer (ServingStats, the benchmark) actually reports."""
         by_site: dict[str, dict] = {}
         b_sites = before.get("by_site", {})
         for site, v in after.get("by_site", {}).items():

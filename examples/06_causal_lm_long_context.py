@@ -90,7 +90,7 @@ if __name__ == "__main__":
 
     print(f"retrieval LM: vocab {VOCAB}, seq {SEQ}, {DEPTH} blocks, causal flash attention")
     print(f"no-attention models are stuck at the {np.log(VOCAB):.3f} uniform loss floor")
-    # warm the compile outside the timed region (repo convention, bench.py)
+    # warm the compile outside the timed region (repo convention)
     params, opt, loss = step(params, opt, tokens, labels)
     jax.device_get(loss)
     t0 = time.perf_counter()

@@ -25,8 +25,7 @@ The ISSUE 3 acceptance proof, as one JSON record.  Three phases:
 
 Usage:  JAX_PLATFORMS=cpu python scripts/chaos_soak.py
 Emits one line: {"metric": "chaos", ..., "passed": true}.
-bench.py runs this in a subprocess as its `chaos` block
-(DTM_BENCH_SKIP_CHAOS=1 skips).
+tests/test_chaos.py::test_chaos_soak_script_end_to_end (slow) runs it.
 """
 
 from __future__ import annotations

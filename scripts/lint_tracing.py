@@ -12,8 +12,8 @@ all are mechanical enough to enforce with ``ast`` instead of code review:
    None:`` (or the else-branch of an ``is None`` test), or in a function
    that already bailed early through ``if <x>._tracer is None:
    return/raise/continue``.  An unguarded call is a crash on the
-   default ``tracer=None`` configuration — the exact configuration the
-   overhead gate (`scripts/bench_tracing.py`) promises costs nothing.
+   default ``tracer=None`` configuration, which is promised to cost
+   nothing (tests/test_tracing.py::test_tracerless_engine_has_no_tracer_state).
 
 2. **Monotonic-clock contract.**  Serving code must not read
    ``time.time()``: span math runs on the tracer's ``time.monotonic``

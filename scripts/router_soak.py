@@ -47,8 +47,8 @@ second cluster step of wave 1, on replica 1, deterministically.
 
 Usage:  JAX_PLATFORMS=cpu python scripts/router_soak.py
 Emits one line: {"metric": "router", ..., "passed": true}.
-bench.py runs this in a subprocess as its `router` block
-(DTM_BENCH_SKIP_ROUTER=1 skips); a dropped request exits nonzero.
+tests/test_router.py::test_router_soak_script_passes (slow) runs it; a
+dropped request exits nonzero.
 """
 
 from __future__ import annotations

@@ -29,3 +29,34 @@ def eight_devices():
     if len(devices) < 8:
         pytest.skip(f"need 8 virtual devices, have {len(devices)}")
     return devices
+
+
+def _pools_refcount_zero(router) -> bool:
+    for rep in router.replicas:
+        engine = rep.engine
+        pool = getattr(engine, "_pool", None)
+        if not rep.alive or pool is None:
+            continue
+        radix = getattr(engine, "_radix", None)
+        if radix is None:
+            if pool.allocated != 0:
+                return False
+            continue
+        stack = [radix.root]
+        while stack:
+            node = stack.pop()
+            if node.ref != 0:
+                return False
+            stack.extend(node.children.values())
+        if pool.allocated != radix.n_blocks:
+            return False
+    return True
+
+
+@pytest.fixture(scope="session")
+def pools_refcount_zero():
+    """``check(router) -> bool``: every live engine's KV pool is back at
+    refcount zero — any page still allocated is owned by the radix trie
+    with every node at ref 0 (retained zero-ref prefixes are the cache
+    working as designed), nothing a request or a handoff packet holds."""
+    return _pools_refcount_zero

@@ -299,7 +299,7 @@ def test_metric_writer_close_is_idempotent_and_write_after_close_is_clear(tmp_pa
 def test_metric_writer_sanitizes_non_finite_to_null(tmp_path):
     """NaN/Infinity metric values must round-trip as STRICT JSON null, not
     json.dumps's bare NaN/Infinity tokens (invalid JSON) — including inside
-    nested blocks like bench.py's comparison sections."""
+    nested blocks like a record's comparison sections."""
     import json
     import math
 

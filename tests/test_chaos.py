@@ -4,8 +4,7 @@ recovery with backoff/window/restart records, step-granular preemption, and
 per-request failure isolation in the serving engine (ISSUE 3).
 
 The fast tests here are tier-1; the full multi-fault soak
-(scripts/chaos_soak.py, also wired into bench.py) runs under the ``slow``
-marker.
+(scripts/chaos_soak.py) runs under the ``slow`` marker.
 """
 
 import json
@@ -647,7 +646,7 @@ def test_chaos_hooks_are_noops_when_unwired(tmp_path):
 @pytest.mark.slow
 def test_chaos_soak_script_end_to_end():
     """The full multi-fault soak (training + serving + overhead assert),
-    exactly as bench.py runs it."""
+    as a subprocess on the CPU backend."""
     import subprocess
     import sys
 

@@ -184,6 +184,36 @@ def test_per_chip_kv_bytes_drop_by_cp(native):
         assert 0.9 * cp <= ratio <= 1.1 * cp, (cp, ratio)
 
 
+def test_prompt_over_one_chips_kv_budget_serves_to_parity_at_cp2():
+    """The max_len-ceiling story.  One chip's KV budget is set at 60% of
+    the cp=1 engine's footprint: cp=1 does NOT fit it, cp=2 does.  A
+    40-token prompt that needs that footprint is admitted at cp=2,
+    prefills through the ring and decodes to the cp=1 engine's tokens
+    exactly, greedy AND seeded-sampled (on virtual devices the cp=1
+    engine physically fits, which is what makes it the reference)."""
+    from distributed_tensorflow_ibm_mnist_tpu.serving import SamplingParams
+
+    model, params = _model_and_params(num_classes=64, dim=256, depth=4,
+                                      heads=8)
+    prompt = [(i * 7) % 62 + 1 for i in range(40)]
+    sampled = SamplingParams(temperature=0.7, top_k=8, seed=123)
+    got, kv = {}, {}
+    for cp in (1, 2):
+        eng = InferenceEngine(
+            model, params, slots=2, max_len=64, cp=cp, kv_page_size=8,
+            kv_pages=16,
+            scheduler=FIFOScheduler(max_len=64, buckets=(48,), max_queue=8))
+        reqs = [eng.submit(prompt, max_new=8, sampling=sp)
+                for sp in (None, sampled)]
+        eng.run()
+        got[cp] = [list(r.generated) for r in reqs]
+        kv[cp] = eng.kv_bytes_per_chip()
+        eng.close()
+    budget = int(kv[1] * 0.6)
+    assert kv[1] > budget >= kv[2]
+    assert all(len(t) == 8 for t in got[1]) and got[2] == got[1]
+
+
 def test_stats_cp_merges_into_rollup():
     import json
 
@@ -217,6 +247,9 @@ def test_prewarm_under_cp_then_zero_serving_compiles(native):
     # the family is cp-qualified: one program per (site, shape, cp)
     assert any(s.startswith("prefill[") and s.endswith(",cp2]")
                for s in warm["by_site"]), warm["by_site"]
+    # prefill + insert + extend + pick + window + reset + host glue: ~14
+    # cold; the headroom makes a new tiny program a nudge, not a page
+    assert warm["programs"] <= 26, warm["by_site"]
     before = tracker.snapshot()
     reqs = [eng.submit(p, max_new=6) for p in PROMPTS]
     eng.run()
@@ -242,6 +275,32 @@ def test_chaos_event_counts_cp_invariant(native):
                       inj.events("serving-step"))
     assert counts[1] == counts[2] == counts[4], counts
     assert counts[1][0] >= len(PROMPTS) and counts[1][1] > 0
+
+
+def test_kv_handoff_event_counts_cp_invariant(native):
+    """... through a REAL prefill -> decode tier too: the same admit, step
+    and kv-handoff events at cp 1 and 2, the same tokens, all done."""
+    from distributed_tensorflow_ibm_mnist_tpu.serving import Router
+
+    model, params = native
+    roles = ["prefill", "decode"]
+    counts, toks = {}, {}
+    for cp in (1, 2):
+        inj = FaultInjector(FaultPlan(faults=()))
+
+        def make_engine(tid, index):
+            return _engine(model, params, cp=cp, trace_tid=tid,
+                           role=roles[index], chaos=inj)
+
+        with Router(make_engine, 2, roles=roles, chaos=inj) as r:
+            rrs = [r.submit(p, max_new=6) for p in PROMPTS]
+            r.run_until_done(max_steps=500)
+            assert all(rr.status == "done" for rr in rrs)
+            toks[cp] = [list(rr.generated) for rr in rrs]
+        counts[cp] = {site: inj.events(site) for site in
+                      ("serving-admit", "serving-step", "kv-handoff")}
+    assert counts[1] == counts[2] and toks[1] == toks[2], counts
+    assert counts[1]["kv-handoff"] >= len(PROMPTS)
 
 
 # ----------------------------------------------------------------------

@@ -25,11 +25,6 @@ The decisive properties (ISSUE 15):
   hammered from many threads lose no increments and never tear.
 """
 
-import json
-import os
-import pathlib
-import subprocess
-import sys
 import threading
 
 import jax
@@ -93,32 +88,6 @@ def _reference(model, params, prompts=PROMPTS, max_new=6):
     return [list(r.generated) for r in reqs]
 
 
-def _pools_refcount_zero(router):
-    """Every live engine's KV pool back at refcount zero: any page still
-    allocated is owned by the radix cache's trie with every node ref 0
-    (retained zero-ref prefixes are the cache working as designed)."""
-    for rep in router.replicas:
-        if not rep.alive:
-            continue
-        pool = getattr(rep.engine, "_pool", None)
-        if pool is None:
-            continue
-        radix = getattr(rep.engine, "_radix", None)
-        if radix is None:
-            if pool.allocated != 0:
-                return False
-            continue
-        stack = [radix.root]
-        while stack:
-            node = stack.pop()
-            if node.ref != 0:
-                return False
-            stack.extend(node.children.values())
-        if pool.allocated != radix.n_blocks:
-            return False
-    return True
-
-
 def _drain_stream(daemon, dr):
     """Consume dr's event queue after the fact (terminal already set):
     the token order stream() would have yielded live."""
@@ -132,7 +101,8 @@ def _drain_stream(daemon, dr):
 # parity + lifecycle
 
 
-def test_daemon_parity_streams_and_clean_drain(model_and_params):
+def test_daemon_parity_streams_and_clean_drain(model_and_params,
+                                               pools_refcount_zero):
     """Greedy decode through the full thread stack == one fault-free
     engine; callbacks/stream()/tokens agree; drain leaves open_spans == 0
     and the paged KV pools at refcount zero; conservation exact."""
@@ -167,7 +137,7 @@ def test_daemon_parity_streams_and_clean_drain(model_and_params):
         # drained tier: admission refused, nothing left in flight
         with pytest.raises(RuntimeError):
             d.submit([1, 2], 2)
-        assert _pools_refcount_zero(router)
+        assert pools_refcount_zero(router)
     assert tracer.open_spans == 0
     with pytest.raises(RuntimeError):
         d.submit([1, 2], 2)
@@ -558,23 +528,3 @@ def test_telemetry_maybe_sample_once_per_interval():
         assert sum(r is not None for r in results) == 1
     assert tel.samples == 3
     tel.close()
-
-
-# ----------------------------------------------------------------------
-# the SLO bench, quick form
-
-
-@pytest.mark.slow
-def test_bench_slo_quick_gates():
-    repo = pathlib.Path(__file__).resolve().parent.parent
-    env = dict(os.environ, JAX_PLATFORMS="cpu", DTM_BENCH_QUICK="1")
-    out = subprocess.run(
-        [sys.executable, str(repo / "scripts" / "bench_slo.py")],
-        capture_output=True, text=True, timeout=420, env=env)
-    assert out.returncode == 0, (
-        f"bench_slo quick failed rc={out.returncode}; "
-        f"stderr tail: {out.stderr[-800:]!r}")
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "slo_daemon"
-    assert rec["passed"] is True
-    assert all(rec["gates"].values()), rec["gates"]

@@ -1,6 +1,5 @@
 """FLOPs/MFU accounting + the public throughput-measurement API."""
 
-import os
 
 import jax
 import jax.numpy as jnp
@@ -176,7 +175,7 @@ def test_measure_throughput_public_api(monkeypatch):
     assert out["model_tflops_per_sec_per_chip"] > 0
     assert 0 < out["mfu"] < 1
     # "untouched" includes the state's commitment: the fit() that follows
-    # (bench.py's order) must reuse the epoch program, not recompile it
+    # (measure, then train) must reuse the epoch program, not recompile it
     summary = t.fit()
     assert not [s for s in summary["compile_by_site"] if s.startswith("train_epoch")]
 
@@ -242,14 +241,6 @@ def test_fit_summary_reports_mfu(monkeypatch):
     s = t.fit()
     assert s["model_tflops_per_sec_per_chip"] > 0
     assert s["mfu"] is not None
-
-
-def test_bench_uses_no_private_internals():
-    """bench.py must drive the public API only (VERDICT.md round-1 item 9)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "bench.py")) as f:
-        src = f.read()
-    assert "trainer._" not in src and "._run_epoch" not in src and "._eval" not in src
 
 
 def test_cost_analysis_counts_scan_body_once():

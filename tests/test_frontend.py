@@ -77,29 +77,6 @@ def _factory(model, params, **kw):
     return make_engine
 
 
-def _pools_refcount_zero(router):
-    for rep in router.replicas:
-        if not rep.alive:
-            continue
-        pool = getattr(rep.engine, "_pool", None)
-        if pool is None:
-            continue
-        radix = getattr(rep.engine, "_radix", None)
-        if radix is None:
-            if pool.allocated != 0:
-                return False
-            continue
-        stack = [radix.root]
-        while stack:
-            node = stack.pop()
-            if node.ref != 0:
-                return False
-            stack.extend(node.children.values())
-        if pool.allocated != radix.n_blocks:
-            return False
-    return True
-
-
 @pytest.fixture()
 def tier(model_and_params):
     """A 2-replica daemon + front door on an ephemeral port, torn down
@@ -208,7 +185,7 @@ def test_stream_order_matches_delivery(tier):
 # closed, conservation exact)
 
 
-def test_client_disconnect_mid_stream_cancels(tier):
+def test_client_disconnect_mid_stream_cancels(tier, pools_refcount_zero):
     daemon, fd, tracer = tier
     body = json.dumps({"prompt": [5, 6, 7], "max_new": 6, "stream": True,
                        "deadline_s": 60.0}).encode()
@@ -237,7 +214,7 @@ def test_client_disconnect_mid_stream_cancels(tier):
     # slot free, pages free, spans closed — the disconnect leaked nothing
     for rep in daemon.router.replicas:
         assert rep.engine.occupied == 0
-    assert _pools_refcount_zero(daemon.router)
+    assert pools_refcount_zero(daemon.router)
     assert tracer.open_spans == 0
 
 
@@ -343,6 +320,9 @@ def test_429_carries_policy_retry_after(model_and_params):
                 break
             time.sleep(0.02)
         assert daemon.conservation()["conserved"]
+        # one scrape, both worlds: the door's rejects beside the tier's
+        text = cli.metrics()
+        assert "frontdoor_requests" in text and "frontdoor_rejected" in text
     finally:
         fd.stop()
         daemon.drain(timeout=30.0)
@@ -459,31 +439,6 @@ def test_start_in_thread_idempotent_stop_and_rebind_error(tier):
     with pytest.raises(OSError):
         clash.start_in_thread()
     clash.stop()        # no-op: never started
-
-
-# ----------------------------------------------------------------------
-# the front-door bench, quick form
-
-
-@pytest.mark.slow
-def test_bench_frontdoor_quick_gates():
-    import os
-    import pathlib
-    import subprocess
-    import sys
-
-    repo = pathlib.Path(__file__).resolve().parent.parent
-    env = dict(os.environ, JAX_PLATFORMS="cpu", DTM_BENCH_QUICK="1")
-    out = subprocess.run(
-        [sys.executable, str(repo / "scripts" / "bench_frontdoor.py")],
-        capture_output=True, text=True, timeout=420, env=env)
-    assert out.returncode == 0, (
-        f"bench_frontdoor quick failed rc={out.returncode}; "
-        f"stderr tail: {out.stderr[-800:]!r}")
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "frontdoor"
-    assert rec["passed"] is True
-    assert all(rec["gates"].values()), rec["gates"]
 
 
 # ----------------------------------------------------------------------
@@ -831,3 +786,89 @@ def test_shed_request_gets_shed_span_and_tail_keeps(model_and_params):
     # only the shed trace's front-door span survives export
     assert all(tid == shed_tid for tid, f in forest.items()
                if "http_request" in f["names"])
+
+
+# ----------------------------------------------------------------------
+# failover behind connected clients: the wire inherits the tier's
+# guarantee, and each finished stream is one connected span tree
+
+
+def test_pump_kill_behind_connected_sse_clients(model_and_params, tmp_path,
+                                                pools_refcount_zero):
+    """``daemon-pump`` chaos kills one of two pumps while SSE clients are
+    connected: every stream still ends ``done`` with its tokens delivered
+    exactly once, conservation stays exact, ``/healthz`` shows the casualty,
+    and every stream's trace — found by the ``traceparent`` the door
+    echoed — is ONE connected tree from the HTTP accept through admission,
+    prefill and decode, with a span link from a replayed dispatch back to
+    the attempt that died."""
+    from distributed_tensorflow_ibm_mnist_tpu.utils.chaos import (
+        FaultInjector,
+        FaultPlan,
+        FaultSpec,
+    )
+    from distributed_tensorflow_ibm_mnist_tpu.utils.tracing import (
+        trace_forest,
+        validate_trace,
+    )
+
+    model, params = model_and_params
+    inj = FaultInjector(FaultPlan(seed=5, faults=(
+        FaultSpec(site="daemon-pump", kind="raise", at=(0,)),)))
+    tracer = Tracer()
+    router = Router(_factory(model, params, tracer=tracer, chaos=inj), 2,
+                    chaos=inj, tracer=tracer)
+    router.prewarm()
+    daemon = ServingDaemon(router, max_queue=64,
+                           liveness_timeout_s=30.0).start()
+    fd = FrontDoor(daemon).start_in_thread()
+    prompts = [PROMPTS[i % len(PROMPTS)] + [1 + i] for i in range(6)]
+    results = {}
+
+    def client(i, prompt):
+        cli = FrontDoorClient("127.0.0.1", fd.port, timeout=WAIT_S)
+        toks = list(cli.stream(prompt, 4, deadline_s=WAIT_S))
+        results[i] = (toks, cli.last_terminal,
+                      cli.last_headers.get("traceparent"))
+
+    try:
+        threads = [threading.Thread(target=client, args=(i, p))
+                   for i, p in enumerate(prompts)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=WAIT_S)
+        assert daemon.router.failovers >= 1
+        assert daemon.counters["pump_faults"] >= 1
+        # the same prompts, greedy, through the tier that survived
+        refs = [daemon.submit(p, 4) for p in prompts]
+        assert all(dr.wait(timeout=WAIT_S) for dr in refs)
+        for i, dr in enumerate(refs):
+            toks, terminal, _ = results[i]        # no stream was dropped
+            assert terminal["status"] == "done"
+            assert toks == list(dr.tokens)        # ... or replayed twice
+            assert terminal["n_tokens"] == len(toks)
+        health = FrontDoorClient("127.0.0.1", fd.port).healthz()
+        assert health["healthy"] == 1 and sorted(
+            v["state"] for v in health["replicas"].values()) == [
+                "failed", "healthy"]
+        assert daemon.conservation()["conserved"]
+    finally:
+        fd.stop()
+        drained = daemon.drain(timeout=30.0)
+        pools = pools_refcount_zero(daemon.router)
+        daemon.close()
+    assert drained and pools
+    assert tracer.open_spans == 0
+    path = str(tmp_path / "failover.json")
+    tracer.export_trace(path)
+    assert validate_trace(path) == []
+    with open(path) as fh:
+        doc = json.load(fh)
+    forest = trace_forest(doc)
+    for _, _, tp in results.values():
+        g = forest[TraceContext.parse_traceparent(tp).trace_id]
+        assert g["connected"], g
+        assert {"http_request", "daemon_request", "request", "prefill",
+                "decode"} <= set(g["names"]), g["names"]
+    assert any(e.get("args", {}).get("links") for e in doc["traceEvents"])

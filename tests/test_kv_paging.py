@@ -354,31 +354,56 @@ def test_chaos_admit_poison_isolated_on_paged():
 
 
 # ----------------------------------------------------------------------
-# bench harness smoke (slow: subprocess + fresh jax init)
+# the memory model: sessions at a fixed number of KV bytes
 
 
-@pytest.mark.slow
-def test_bench_kv_paging_quick_smoke():
-    """The equal-HBM concurrency bench end to end in CI-smoke sizes: the
-    paged+radix leg must serve >= 2x the dense leg's peak concurrent
-    sessions at ~equal KV bytes with token-identical greedy output — the
-    script itself exits nonzero when either gate fails."""
-    import os
-    import subprocess
-    import sys
+def test_sessions_at_equal_kv_bytes_and_gqa_page_bytes():
+    """A dense engine's concurrency is an allocation: every slot owns
+    ``max_len`` positions, so a KV budget buys ``budget / (max_len x token
+    bytes)`` sessions.  The same bytes cut into pages (4 dense slots x 128
+    positions = 32 pages of 16, + the trash page), behind 16 slots with the
+    radix trie holding a 48-token shared system prompt ONCE, hold at least
+    TWICE the sessions at once, and no token moves.  With ``heads_kv =
+    heads // 4`` a request pins as many pages, each a quarter the bytes."""
+    vocab, heads, max_len, page, dense_slots = 64, 4, 128, 16, 4
+    kv_pages = dense_slots * max_len // page + 1
+    rng = np.random.default_rng(7)
+    shared = rng.integers(1, vocab, size=48).tolist()
+    prompts = [shared + rng.integers(1, vocab, size=8).tolist()
+               for _ in range(12)]
 
-    script = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", "bench_kv_paging.py")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", DTM_BENCH_QUICK="1")
-    out = subprocess.run([sys.executable, script], env=env,
-                         capture_output=True, text=True, timeout=420)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["ok"] is True
-    assert rec["outputs_match"] is True
-    assert rec["concurrency_ratio"] >= 2.0
-    assert 0.9 <= rec["bytes_ratio"] <= 1.1  # the budget really was fixed
-    assert rec["paged"]["radix_hit_tokens"] > 0
+    lm = dict(num_classes=vocab, dim=48, depth=2, heads=heads)
+    mha = _model_and_params(**lm)
+    gqa = _model_and_params(**lm, heads_kv=heads // 4)
+
+    def serve(model_and_params, **kw):
+        eng = InferenceEngine(*model_and_params, max_len=max_len,
+                              buckets=(64, 128), eos_id=None, **kw)
+        kv_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(eng.cache))
+        reqs = [eng.submit(p, max_new=8) for p in prompts]
+        peak = 0
+        while eng.has_work:
+            eng.step()
+            peak = max(peak, sum(r is not None for r in eng._slot_req))
+        assert all(r.status == "done" for r in reqs)
+        summary = eng.stats.summary()
+        eng.close()
+        return _outputs(reqs), peak, kv_bytes, summary
+
+    paged_kw = dict(slots=4 * dense_slots, kv_page_size=page,
+                    kv_pages=kv_pages)
+    dense_out, dense_peak, dense_bytes, _ = serve(mha, slots=dense_slots)
+    paged_out, paged_peak, paged_bytes, paged = serve(mha, **paged_kw)
+    assert paged_out == dense_out
+    assert 0.9 <= paged_bytes / dense_bytes <= 1.1   # the budget was fixed
+    assert dense_peak == dense_slots and paged_peak >= 2 * dense_peak
+    assert paged["radix_hit_tokens"] > 0
+
+    gqa_dense_out, _, _, _ = serve(gqa, slots=dense_slots)
+    gqa_out, _, _, gqa_stats = serve(gqa, **paged_kw)
+    assert gqa_out == gqa_dense_out
+    assert gqa_stats["kv_pages_total"] == paged["kv_pages_total"]
+    assert paged["kv_bytes_peak"] / gqa_stats["kv_bytes_peak"] >= 0.9 * 4
 
 
 def test_close_fails_overcommit_stalled_request_and_frees_pages():

@@ -184,6 +184,46 @@ def test_engine_greedy_agreement_and_bytes(fp):
     assert 3.2 <= fbytes / qbytes <= 4.0, (fbytes, qbytes)
 
 
+ZOO = {"base": {}, "gqa_window": {"heads_kv": 2, "window": 8},
+       "tied": {"tie_embeddings": True}}
+
+
+@pytest.mark.parametrize("name", sorted(ZOO))
+def test_quant_drift_and_layout_invariance_on_fit_zoo_configs(name):
+    """Each LM shape of the zoo, briefly FIT (a fresh model's logits tie
+    near the argmax everywhere; two short epochs sharpen them): int8
+    weights move a plain forward's logits by under 5% of their range, and
+    every serving layout reads the one int8 tree to the same tokens (a
+    windowed model serves dense and plain only)."""
+    from distributed_tensorflow_ibm_mnist_tpu.core.trainer import Trainer
+    from distributed_tensorflow_ibm_mnist_tpu.utils.config import RunConfig
+
+    t = Trainer(RunConfig(
+        name=f"quant_{name}", model="causal_lm",
+        model_kwargs={"dim": 32, "depth": 2, "heads": 4, **ZOO[name]},
+        dataset="retrieval", dataset_kwargs={"vocab": 32, "seq_len": 16},
+        n_train=64, n_test=16, batch_size=16, epochs=2, quiet=True,
+        eval_batch_size=16))
+    try:
+        t.fit()
+        model, params = t.model, t._decode_params()
+    finally:
+        t.close()
+    tokens = jnp.asarray(
+        np.random.default_rng(0).integers(0, 16, size=(2, 16)), jnp.int32)
+    ref = model.apply({"params": params}, tokens)
+    got = model.clone(quant="int8").apply(
+        {"params": quantize_params_int8(params)}, tokens)
+    drift = float(jnp.max(jnp.abs(ref - got)) / jnp.max(jnp.abs(ref)))
+    assert drift < 0.05, drift
+    composed = ({"decode_ahead": 8} if ZOO[name].get("window") else
+                {"kv_page_size": 8, "decode_ahead": 8,
+                 "speculative": "ngram", "draft_len": 3})
+    base = _serve(model, params, quant="int8")
+    assert all(len(toks) == 6 for toks in base)
+    assert _serve(model, params, quant="int8", **composed) == base
+
+
 def eng_params_host(eng):
     return jax.tree.map(np.asarray, jax.device_get(eng.params))
 

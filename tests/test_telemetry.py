@@ -413,6 +413,45 @@ def test_engine_judges_slos_and_feeds_the_sampler(tmp_path):
     eng.close()
 
 
+def test_slo_counters_exact_on_an_overloaded_queue():
+    """4x-slots requests queued at once (the queue is the overload), half
+    with a TTFT SLO below one jit dispatch and half with one nothing can
+    miss, then an unloaded wave that fits the slots.  Arithmetic, not
+    timing: met + miss == tracked on each leg, the tight half misses at
+    first token exactly, the generous half and the unloaded leg meet
+    exactly, goodput is reported, and ``ServingStats.merge`` sums them as
+    the router's rollup does."""
+    from distributed_tensorflow_ibm_mnist_tpu.serving import ServingStats
+
+    model, params = _model_and_params()
+    slots = 2
+    n, n_tight = 4 * slots, 2 * slots
+    eng = InferenceEngine(
+        model, params, slots=slots, max_len=16, decode_ahead=4,
+        scheduler=FIFOScheduler(max_len=16, buckets=(8,), max_queue=n))
+    for i in range(n):
+        eng.submit(PROMPTS[i % len(PROMPTS)], max_new=4, tpot_slo_s=1e4,
+                   ttft_slo_s=(1e-6 if i % 2 == 0 else 1e4))
+    eng.run()
+    over_stats, over = eng.stats, eng.stats.summary()
+    eng.stats = ServingStats(slots, decode_ahead=eng.decode_ahead)
+    for p in PROMPTS[:slots]:
+        eng.submit(p, max_new=4, ttft_slo_s=1e4, tpot_slo_s=1e4)
+    eng.run()
+    un = eng.stats.summary()
+    merged = ServingStats.merge([over_stats, eng.stats])
+    eng.close()
+    assert over["slo_met"] + over["slo_miss"] == over["slo_tracked"] == n
+    assert over["slo_miss"] == over["slo_ttft_miss"] == n_tight
+    assert over["slo_met"] == n - n_tight
+    assert un["slo_met"] == un["slo_tracked"] == slots
+    assert un["slo_miss"] == 0
+    assert over["goodput_rps"] is not None and un["goodput_rps"] is not None
+    assert merged["slo_tracked"] == n + slots
+    assert merged["slo_met"] == over["slo_met"] + un["slo_met"]
+    assert merged["slo_miss"] == over["slo_miss"]
+
+
 def test_engine_without_telemetry_is_untouched():
     """The nil-guard off-path: no telemetry attribute consulted beyond
     `is not None`, identical serving behavior, SLO judgment still runs

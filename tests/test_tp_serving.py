@@ -97,15 +97,15 @@ def refs(native, int8):
 
 
 # ----------------------------------------------------------------------
-# parity: the curated composition slice
+# parity: the composition matrix
 
 
-CASES = [
-    # (tp, kv_dtype, paged, decode_ahead, speculative)
-    (2, "native", False, 1, False),
-    (2, "native", True, 1, False),
-    (2, "int8", False, 8, False),
-    (2, "native", True, 8, True),
+# (tp, kv_dtype, paged, decode_ahead, speculative): the whole composition
+# matrix at tp=2 — {dense, paged} x {native, int8 KV} x decode_ahead {1, 8}
+# x {plain, speculative} — and a slice of it at tp=4
+CASES = [(2, kvd, paged, k, spec)
+         for paged in (False, True) for kvd in ("native", "int8")
+         for k in (1, 8) for spec in (False, True)] + [
     (4, "native", False, 8, False),
     (4, "int8", True, 1, False),
     (4, "native", False, 1, True),

@@ -4,7 +4,8 @@ Pins the W3C ``traceparent`` surface (:class:`TraceContext`), the
 head+tail sampler, span links/annotation, the multi-tracer merge, the
 forest connectivity checker, and the exemplar-bearing OpenMetrics
 exposition — the building blocks the serving tier's end-to-end tracing
-(scripts/bench_tracing.py) is assembled from.
+(tests/test_frontend.py, tests/test_disagg.py, tests/test_journal.py) is
+assembled from.
 """
 
 import io

@@ -466,40 +466,93 @@ def test_trace_report_counter_track_rollup(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# bench harness smoke (slow: subprocess + fresh jax init); the fast legs
-# above cover the library — this pins the harness itself
+# the serving engine's program family, site by site
 
 
-@pytest.mark.slow
-def test_bench_compile_census_quick_smoke():
-    """The compile-census acceptance figure, end to end in a subprocess:
-    n_compiled_programs moves when (and only when) a new bucket appears."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code = (
-        "import sys, os, json; "
-        "sys.path.insert(0, os.path.join(%r, 'scripts')); "
-        "from bench_serving import run_compile_census; "
-        "print(json.dumps(run_compile_census(2)))" % root)
-    env = dict(os.environ, JAX_PLATFORMS="cpu", DTM_BENCH_QUICK="1")
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["repeat_compiles_zero"] is True
-    assert rec["new_bucket_compiles"] is True
-    census = rec["legs"]
-    assert census["bucket16_first"]["n_new_programs"] > 0
-    assert census["bucket32_new"]["n_new_programs"] >= 1
-    # the new bucket's compiles are its prefill program — decode/insert/
-    # reset are bucket-invariant and must all be cache hits
-    assert "prefill[b32]" in census["bucket32_new"]["by_site"]
-    for site in ("decode_window", "slot_insert", "slot_reset"):
-        assert not any(k.startswith(site)
-                       for k in census["bucket32_new"]["by_site"])
-    # ISSUE 7: the census is a regression GATE — every leg pinned to its
-    # budget, and the paged family compiles once, never per request
-    assert rec["census_ok"] is True, rec["over_budget"]
-    assert set(rec["budget"]) == set(census)
-    assert census["paged_cold"]["n_new_programs"] > 0
-    assert any(k.startswith("extend[") for k in census["paged_cold"]["by_site"])
-    assert census["paged_repeat"]["n_new_programs"] == 0
+def test_serving_compile_census_by_site(eight_devices):
+    """``n_compiled_programs`` moves when, and only when, a new member of
+    the program family appears, and every engine's cold set is pinned site
+    by site: one more program under any site is a compile storm at
+    start-up or a flapping jit cache key, even when every token is still
+    right.  The whole sequence runs twice, first at another width and
+    unpinned: that pass compiles the module-level programs (the shared
+    ``first_pick``, eager helpers), whose count would otherwise follow
+    whatever this process ran before."""
+    from distributed_tensorflow_ibm_mnist_tpu.serving import SamplingParams
+
+    tracker = CompileTracker.install()
+
+    def census(dim):
+        model = get_model("causal_lm", num_classes=32, dim=dim, depth=1,
+                          heads=2, dtype=jnp.float32)
+        params = model.init(jax.random.PRNGKey(4),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+        rng = np.random.default_rng(5)
+        legs = {}
+
+        def prompt(n):
+            return rng.integers(1, 31, size=(n,)).astype(np.int32)
+
+        def engine(**kw):
+            return InferenceEngine(
+                model, params, slots=2, max_len=48,
+                scheduler=FIFOScheduler(max_len=48, buckets=(16, 32),
+                                        max_queue=8), **kw)
+
+        def serve(leg, eng, prompts, sampling=None):
+            before = tracker.snapshot()
+            reqs = [eng.submit(p, max_new=8, sampling=sampling)
+                    for p in prompts]
+            eng.run()
+            assert all(r.status == "done" for r in reqs)
+            d = CompileTracker.delta(tracker.snapshot(), before)
+            legs[leg] = {site: rec["n"] for site, rec in d["by_site"].items()
+                         if site != "unattributed"}
+
+        eng = engine()
+        serve("bucket16_first", eng, [prompt(8)])
+        serve("bucket16_repeat", eng, [prompt(10)])
+        serve("bucket32_new", eng, [prompt(24)])
+        serve("bucket32_repeat", eng, [prompt(28)])
+        serve("sample_cold", eng, [prompt(8)], SamplingParams(
+            temperature=0.8, top_p=0.9, seed=11))
+        serve("sample_repeat", eng, [prompt(10)], SamplingParams(
+            temperature=1.1, top_p=0.5, seed=12))
+        eng.close()
+        # a shared-prefix pair, so the radix suffix-extend program compiles
+        eng = engine(kv_page_size=8)
+        shared = prompt(8)
+        pairs = [np.concatenate([shared, prompt(4)]) for _ in range(4)]
+        serve("paged_cold", eng, pairs[:2])
+        serve("paged_repeat", eng, pairs[2:])
+        eng.close()
+        for name, kw in (("spec", {"speculative": "ngram", "draft_len": 3}),
+                         ("quant", {"quant": "int8"}), ("tp", {"tp": 2})):
+            eng = engine(**kw)
+            serve(f"{name}_cold", eng, [prompt(8)])
+            serve(f"{name}_repeat", eng, [prompt(10)])
+            eng.close()
+        return legs
+
+    census(48)
+    dense = {"prefill[b16]": 1, "slot_insert": 1, "decode_window[k1]": 1,
+             "slot_reset": 1}
+    assert census(32) == {
+        "bucket16_first": dense,
+        "bucket16_repeat": {},                   # a repeat compiles NOTHING
+        "bucket32_new": {"prefill[b32]": 1},     # the new bucket's prefill
+        "bucket32_repeat": {},
+        "sample_cold": {},     # sampling planes are data in the one window
+        "sample_repeat": {},   # program: no (temperature, top_p, seed) forks
+        "paged_cold": {**dense, "extend[b16]": 1},
+        "paged_repeat": {},    # paging adds programs once, not per request
+        # the verify window in place of the decode window; the host-side
+        # draft upload (slot_draft) compiles nothing
+        "spec_cold": {"prefill[b16]": 1, "slot_insert": 1, "slot_reset": 1,
+                      "verify_window[k4]": 1},
+        "spec_repeat": {},
+        # int8 weights and a 2-chip tp mesh change what a program holds,
+        # never how many there are
+        "quant_cold": dense, "quant_repeat": {},
+        "tp_cold": dense, "tp_repeat": {},
+    }
