@@ -193,10 +193,12 @@ def make_prefill(model, max_len: int) -> Callable:
 
     Prefill always emits the DENSE row layout, even when the engine decodes
     paged (``page_size > 0``): the prompt runs through the ordinary forward
-    (no cache involved), and the paged engine scatters the dense row into
-    its page pool on insert (serving/kv_pool.py ``make_paged_insert``) —
-    the prefill program is byte-identical between the two cache layouts,
-    so switching layouts never recompiles the prefill family.
+    (no cache involved), and the paged engine copies the pages under the
+    row's cursor into its page pool on insert (serving/kv_pool.py
+    ``make_paged_insert``: the padded row is a whole number of pages, and
+    those above the cursor are never copied) — the prefill program is
+    byte-identical between the two cache layouts, so switching layouts
+    never recompiles the prefill family.
     """
     if getattr(model, "page_size", 0):
         model = model.clone(page_size=0)  # prefill is layout-agnostic

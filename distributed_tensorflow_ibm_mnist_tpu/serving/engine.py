@@ -1465,12 +1465,12 @@ class InferenceEngine:
 
     def _paged_land(self, req: Request, slot: int, prefilled: tuple):
         """Land ``req`` in ``slot`` on the PAGED layout: allocate its page
-        span, install the block table, and either scatter the dense prefill
-        row (full prefill / prefix-cache hit) or run the suffix-extend
-        program over the radix-shared prefix.  Returns ``(first_token,
-        first_logprob, cache_hit)`` or None when the pool cannot cover the
-        request right now (the caller re-parks it — admission stall, not
-        failure)."""
+        span, install the block table, and either write the pages under
+        the dense prefill row's cursor into the pool (full prefill /
+        prefix-cache hit) or run the suffix-extend program over the
+        radix-shared prefix.  Returns ``(first_token, first_logprob,
+        cache_hit)`` or None when the pool cannot cover the request right
+        now (the caller re-parks it — admission stall, not failure)."""
         row_cache, logits, cache_hit = prefilled
         ps = self._page_size
         n_tok = int(req.tokens.size)
@@ -1534,6 +1534,7 @@ class InferenceEngine:
             with self._compile.site(self._site("slot_insert")):
                 self.cache = self._insert(self.cache, row_cache, bt_dev,
                                           jnp.asarray(slot, jnp.int32))
+            self.stats.inserted(pages_needed(n_tok, ps))
             if self.role == "prefill":
                 first, first_logp, land_logits = _HANDOFF, None, logits
             else:

@@ -56,12 +56,12 @@ PAGE axis (``kv_cache_rule`` pins ``P("cp", None, head, None)``), so each
 of the ``cp`` chip rows physically holds ``n_pages / cp`` page slabs —
 1/cp of the live KV bytes — while the block table and ``KVPagePool``
 keep addressing the same GLOBAL page ids.  The (chip, page) split is the
-partitioner's business: inserts scatter to whichever chip row owns the
-target slab, decode's per-row gather assembles the attended span across
-rows, and the host-side allocator, radix refcounts, and trash-page
-protocol are layout-invariant — the same integers mean the same pages at
-any cp.  The only cp-visible constraint lives in the engine: ``n_pages``
-must divide by ``cp`` so the page axis shards evenly.
+partitioner's business: an insert's page write lands on whichever chip
+row owns the target slab, decode's per-row gather assembles the attended
+span across rows, and the host-side allocator, radix refcounts, and
+trash-page protocol are layout-invariant — the same integers mean the
+same pages at any cp.  The only cp-visible constraint lives in the
+engine: ``n_pages`` must divide by ``cp`` so the page axis shards evenly.
 """
 
 from __future__ import annotations
@@ -227,42 +227,57 @@ def pool_page_bytes(cache) -> int:
 
 
 def make_paged_insert(page_size: int, max_len: int) -> Callable:
-    """Build ``insert(cache, row_cache, bt_row, slot) -> cache``: scatter a
+    """Build ``insert(cache, row_cache, bt_row, slot) -> cache``: write a
     dense prefilled B=1 row (make_prefill's layout) into the page pool
     through ``bt_row`` and install the row's block table + cursor at
     ``slot``.  The engine jits this with the cache donated.
 
-    The full (max_len,) row is scattered — including garbage above the
-    cursor — which is safe precisely because a dense-prefilled request owns
-    ALL of its pages privately (pages become shared only by donation to the
-    radix trie AFTER insert, and donated pages are read-only from then on:
-    later tenants of the same prefix never write below their cursor).
+    Only the pages under the row's cursor are written, each as one whole
+    page: ``row["index"]`` is the prompt's real length, so the row has
+    ``ceil(index / page_size)`` live pages, and page ``j`` of the row's
+    ``(max_len / page_size, page_size, ...)`` view (a free reshape)
+    belongs at ``pages[bt_row[j]]`` as one contiguous block.  One loop
+    over the live pages carries every pool leaf and updates it in place:
+    the work follows the prompt, the program is one whatever the bucket.
+    The last live page takes the row's padding above the cursor with it,
+    and the allocation's pages beyond it keep what their last tenant
+    left: no position at or above a cursor is read unmasked, and a decode
+    step writes its position before any step reads it.  Whole-page writes
+    are safe because a dense-prefilled request owns ALL of its pages
+    privately (pages become shared only by donation to the radix trie
+    AFTER insert, and donated pages are read-only from then on: later
+    tenants of the same prefix never write below their cursor).
     """
     n_row = max_len // page_size
-    pos = jnp.arange(max_len)
-    page_idx = pos // page_size
-    off = pos % page_size
 
     def insert(cache, row_cache, bt_row, slot):
-        page = bt_row[page_idx]  # (max_len,) destination pages
+        pools = pool_page_leaves(cache)
+        rows = {
+            name: {key: row_cache[name][key.removeprefix("pages_")][0].reshape(
+                (n_row, page_size) + leaf.shape[2:])
+                for key, leaf in entry.items()}
+            for name, entry in pools.items()}
+        n_tok = next(iter(row_cache.values()))["index"][0]
+
+        def write_page(j, pools):
+            return jax.tree.map(
+                lambda pool, row: jax.lax.dynamic_update_slice_in_dim(
+                    pool,
+                    jax.lax.dynamic_slice_in_dim(row, j, 1).astype(pool.dtype),
+                    bt_row[j], axis=0),
+                pools, rows)
+
+        pools = jax.lax.fori_loop(
+            0, (n_tok + page_size - 1) // page_size, write_page, pools)
         out = {}
         for name, entry in cache.items():
-            row = row_cache[name]
-            e = dict(entry)
-            e["pages_k"] = entry["pages_k"].at[page, off].set(
-                row["k"][0].astype(entry["pages_k"].dtype))
-            e["pages_v"] = entry["pages_v"].at[page, off].set(
-                row["v"][0].astype(entry["pages_v"].dtype))
-            if "pages_k_scale" in entry:
-                e["pages_k_scale"] = entry["pages_k_scale"].at[page, off].set(
-                    row["k_scale"][0].astype(entry["pages_k_scale"].dtype))
-                e["pages_v_scale"] = entry["pages_v_scale"].at[page, off].set(
-                    row["v_scale"][0].astype(entry["pages_v_scale"].dtype))
+            e = {**entry, **pools[name]}
             e["block_table"] = jax.lax.dynamic_update_slice(
                 entry["block_table"], bt_row[None].astype(jnp.int32),
                 (slot, 0))
             e["index"] = jax.lax.dynamic_update_slice(
-                entry["index"], row["index"].astype(entry["index"].dtype),
+                entry["index"],
+                row_cache[name]["index"].astype(entry["index"].dtype),
                 (slot,))
             out[name] = e
         return out
@@ -274,8 +289,9 @@ def paged_reset(cache, slot_mask):
     """Per-slot reset in the paged layout: point the masked slots' block
     tables back at the trash page and zero their cursors.  The POOL is
     untouched — a freed page's stale K/V is dead data (nothing maps to it)
-    until the allocator hands the page to a new tenant, whose insert/extend
-    scatter overwrites every position its mask will ever expose.  The
+    until the allocator hands the page to a new tenant, which writes every
+    position before its mask exposes it: the insert the pages under the
+    prompt, extend and decode each position as the cursor reaches it.  The
     paged sibling of models/transformer.py ``reset_cache_slots``; the
     engine jits it with the cache donated under the same compile site.
     """

@@ -186,6 +186,9 @@ class ServingStats:
         #   temperature > 0: the pick ran its filters and the draw
         self._sorted_windows = 0   # of those, some such row had top-k or
         #   top-p on: the pick sorted [slots, vocab] (core/generate.py)
+        self._insert_rows = 0  # landings through the paged insert program
+        self._insert_pages_written = 0  # pages those wrote: the pages under
+        #   each prompt, of the max_len / page_size of a row's span
         self._dispatch_time = 0.0  # window jit-call time (async dispatch)
         self._readback_time = 0.0  # the blocking (slots, k) host sync
         self._window_steps = 0     # occupied-slot decode steps dispatched
@@ -277,6 +280,14 @@ class ServingStats:
             self._readback_time += readback_s
             self._window_steps += steps
             self._waste_steps += waste
+
+    def inserted(self, pages: int) -> None:
+        """One landing through the paged insert program (kv_pool
+        ``make_paged_insert``), which wrote ``pages`` whole pages: the
+        pages under the prompt's cursor."""
+        with self._lock:
+            self._insert_rows += 1
+            self._insert_pages_written += int(pages)
 
     def prefix(self, hit: bool) -> None:
         """One prefix-cache lookup (hit = prefill skipped entirely)."""
@@ -491,6 +502,8 @@ class ServingStats:
             "paged_kernel_windows": self._paged_kernel_windows,
             "sampled_windows": self._sampled_windows,
             "sorted_windows": self._sorted_windows,
+            "insert_rows": self._insert_rows,
+            "insert_pages_written": self._insert_pages_written,
             "window_dispatch_s": round(self._dispatch_time, 6),
             "window_readback_s": round(self._readback_time, 6),
             "window_steps": self._window_steps,
@@ -743,6 +756,9 @@ class ServingStats:
             "sampled_windows": sum(
                 rec._sampled_windows for rec in records),
             "sorted_windows": sum(rec._sorted_windows for rec in records),
+            "insert_rows": sum(rec._insert_rows for rec in records),
+            "insert_pages_written": sum(
+                rec._insert_pages_written for rec in records),
             "window_dispatch_s": round(
                 sum(rec._dispatch_time for rec in records), 6),
             "window_readback_s": round(

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_tensorflow_ibm_mnist_tpu.core.generate import make_prefill
 from distributed_tensorflow_ibm_mnist_tpu.models import get_model
 from distributed_tensorflow_ibm_mnist_tpu.serving import (
     FIFOScheduler,
@@ -33,7 +34,12 @@ from distributed_tensorflow_ibm_mnist_tpu.serving import (
     KVPagePool,
     PrefixCache,
     RadixCache,
+    ServingStats,
+    init_paged_cache,
     pages_needed,
+)
+from distributed_tensorflow_ibm_mnist_tpu.serving.kv_pool import (
+    make_paged_insert,
 )
 from distributed_tensorflow_ibm_mnist_tpu.utils.chaos import (
     FaultInjector,
@@ -207,6 +213,114 @@ def test_int8_scales_reset_on_slot_reuse():
         reused = InferenceEngine(model, params, slots=2, max_len=32, **kw)
         got = _outputs(_run(reused))
         assert got == want, f"slot-reuse divergence under {kw or 'dense'}"
+
+
+# ----------------------------------------------------------------------
+# landing: the insert writes the pages under the prompt, and only those
+
+_KV = {"bf16": {}, "int8": {"kv_cache_dtype": "int8"}}
+
+
+@pytest.mark.parametrize("n_tok", [1, 7, 8, 9, 32])
+@pytest.mark.parametrize("kv", list(_KV))
+def test_insert_writes_the_pages_under_the_cursor(kv, n_tok):
+    """``make_paged_insert`` on a pool full of a last tenant's bytes: every
+    position below the cursor, read back through the block table, is the
+    prefilled row's bit for bit (payload and int8 scales); no page but the
+    ``ceil(n_tok / page_size)`` under the cursor changes (the pages the
+    row owns for its answer included); the slot's block table and cursor
+    are the row's, the other slots' untouched."""
+    ps, max_len, n_pages, slots, slot, bucket = 8, 64, 24, 3, 1, 32
+    model, params = _model_and_params(dtype=jnp.bfloat16, **_KV[kv])
+    rng = np.random.default_rng(n_tok)
+    before = jax.tree.map(
+        lambda x: jnp.asarray(rng.integers(1, 100, x.shape), x.dtype),
+        init_paged_cache(model, params, slots, max_len, ps, n_pages))
+    prompt = np.zeros((1, bucket), np.int32)
+    prompt[0, :n_tok] = rng.integers(1, KW["num_classes"], n_tok)
+    row, _ = make_prefill(model, max_len)(
+        params, jnp.asarray(prompt), jnp.asarray([n_tok]))
+    owned = rng.permutation(np.arange(1, n_pages))[
+        : pages_needed(n_tok + 20, ps)]  # the prompt and a 20-token answer
+    bt_row = np.zeros((max_len // ps,), np.int32)  # rest = TRASH
+    bt_row[: owned.size] = owned
+    after = jax.jit(make_paged_insert(ps, max_len))(
+        before, row, jnp.asarray(bt_row), jnp.asarray(slot, jnp.int32))
+
+    live = bt_row[: pages_needed(n_tok, ps)]
+    others = np.setdiff1d(np.arange(1, n_pages), live)
+    names = {"pages_k": "k", "pages_v": "v"}
+    if kv == "int8":
+        names.update(pages_k_scale="k_scale", pages_v_scale="v_scale")
+    for name, entry in after.items():
+        assert set(names) == {k for k in entry if k.startswith("pages_")}
+        for key, row_key in names.items():
+            got, want = np.asarray(entry[key]), np.asarray(row[name][row_key])
+            assert got.dtype == want.dtype
+            span = got[bt_row].reshape((max_len,) + got.shape[2:])
+            np.testing.assert_array_equal(span[:n_tok], want[0, :n_tok])
+            np.testing.assert_array_equal(
+                got[others], np.asarray(before[name][key])[others])
+        bt, idx = np.asarray(entry["block_table"]), np.asarray(entry["index"])
+        np.testing.assert_array_equal(bt[slot], bt_row)
+        assert idx[slot] == n_tok
+        rest = np.arange(slots) != slot
+        np.testing.assert_array_equal(
+            bt[rest], np.asarray(before[name]["block_table"])[rest])
+        np.testing.assert_array_equal(
+            idx[rest], np.asarray(before[name]["index"])[rest])
+
+
+@pytest.mark.parametrize("kv", list(_KV))
+def test_shorter_tenant_of_a_freed_page_matches_fresh_engine(kv):
+    """A pool of exactly one long request's pages: the short request that
+    follows it can only be given pages the long one filled, and its insert
+    writes one of them.  The long tenant's bytes above the short cursor
+    never show: greedy tokens equal a fresh engine's, and the counters say
+    how many pages each landing wrote."""
+    model, params = _model_and_params(**_KV[kv])
+    long_p, short_p = list(range(1, 15)) + [3, 1, 4, 1, 5, 9], [2, 7, 1]
+    kw = dict(slots=1, max_len=32, kv_page_size=8, radix_cache=False)
+    reused = InferenceEngine(model, params, kv_pages=5, **kw)
+    assert reused._pool.capacity == pages_needed(len(long_p) + 10, 8)
+    got = _outputs(_run(reused, [long_p, short_p]))
+    fresh = InferenceEngine(model, params, **kw)
+    assert got[1] == _outputs(_run(fresh, [short_p]))[0]
+    assert got[1][0] == "done" and len(got[1][1]) == 10
+    s = reused.stats.summary()
+    assert (s["insert_rows"], s["insert_pages_written"]) == (2, 3 + 1)
+
+
+def test_insert_counters_in_summary_and_merge():
+    """``insert_rows`` / ``insert_pages_written``: one landing through the
+    insert program and the pages under its prompt, exact through
+    ``merge``; a radix hit lands through extend and counts nothing; the
+    dense engine and an empty record read 0."""
+    model, params = _model_and_params()
+    eng = InferenceEngine(model, params, slots=3, max_len=32, kv_page_size=8,
+                          radix_cache=False)
+    _run(eng)
+    s = eng.stats.summary()
+    assert s["insert_rows"] == len(PROMPTS)
+    assert s["insert_pages_written"] == sum(
+        pages_needed(len(p), 8) for p in PROMPTS)
+    shared = list(range(1, 17))
+    radix = InferenceEngine(model, params, slots=1, max_len=32,
+                            kv_page_size=8, radix_cache=True)
+    _run(radix, [shared + [3], shared + [5, 6]], max_new=4)
+    r = radix.stats.summary()
+    assert r["radix_hits"] == 1
+    assert (r["insert_rows"], r["insert_pages_written"]) == (1, 3)
+    dense = InferenceEngine(model, params, slots=2, max_len=32)
+    _run(dense, PROMPTS[:2])
+    empty = ServingStats(slots=1)
+    for rec in (dense.stats, empty):
+        z = rec.summary()
+        assert z["insert_rows"] == z["insert_pages_written"] == 0
+    merged = ServingStats.merge([eng.stats, radix.stats, empty])
+    assert merged["insert_rows"] == s["insert_rows"] + 1
+    assert merged["insert_pages_written"] == s["insert_pages_written"] + 3
+    json.dumps(merged, allow_nan=False)
 
 
 # ----------------------------------------------------------------------
