@@ -61,40 +61,55 @@ _SUBLANES = 8
 
 
 def paged_kernel_eligible(compute_dtype, pool_dtype, page_size: int,
-                          hkv: int, d: int) -> bool:
+                          hkv: int, d: int, dv: int | None = None) -> bool:
     """Whether :func:`paged_decode_attention` can read a pool of this shape:
     the pool stores the compute dtype (an int8 pool with scales beside it
-    does not), the head dim is the lane width and a page a whole number of
-    sublane tiles (the benchmark's 128 and 64), and 16-bit pools pair their
-    KV heads into 32-bit words.  A token's heads must fill 1, 2 or 4
-    32-bit rows: XLA pads any other count to the next tile, and the page is
-    then no longer the dense matrix of rows the kernel takes it for.
-    Static — shapes and dtypes only."""
+    does not), a K row (``d``) and a V row (``dv``, ``d`` when not named)
+    are each a whole number of lanes and a page a whole number of sublane
+    tiles (the benchmark's 128 / 128 and 256 / 128, pages of 64), and 16-bit
+    pools pair their KV heads into 32-bit words.  A token's heads must fill
+    1, 2 or 4 32-bit rows: XLA pads any other count to the next tile, and
+    the page is then no longer the dense matrix of rows the kernel takes it
+    for.  Static — shapes and dtypes only."""
     pool_dtype = jnp.dtype(pool_dtype)
     if pool_dtype != jnp.dtype(compute_dtype):
         return False
     if pool_dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return False
     pack = 4 // pool_dtype.itemsize
-    return (d == _LANES and page_size % _SUBLANES == 0
+    dv = d if dv is None else dv
+    return (d > 0 and dv > 0 and d % _LANES == 0 and dv % _LANES == 0
+            and page_size % _SUBLANES == 0
             and hkv % pack == 0 and hkv // pack in (1, 2, 4))
 
 
 def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
-            slot_ref, *, n_rows, n_row, ps, hkv, pack, d, scale, cdtype):
+            slot_ref, *, n_rows, n_row, ps, hkv, pack, dk, dv, scale, cdtype):
     b = pl.program_id(0)
     wave = _WAVE_PAGES
     rpt = hkv // pack  # 32-bit rows per token (pack = KV heads per word)
     rows = ps * rpt
     if pack == 2:
-        k_src = k_hbm.bitcast(jnp.uint32).reshape(k_hbm.shape[0], rows, d)
-        v_src = v_hbm.bitcast(jnp.uint32).reshape(v_hbm.shape[0], rows, d)
+        k_src = k_hbm.bitcast(jnp.uint32).reshape(k_hbm.shape[0], rows, dk)
+        v_src = v_hbm.bitcast(jnp.uint32).reshape(v_hbm.shape[0], rows, dv)
     else:
-        k_src = k_hbm.reshape(k_hbm.shape[0], rows, d)
-        v_src = v_hbm.reshape(v_hbm.shape[0], rows, d)
+        k_src = k_hbm.reshape(k_hbm.shape[0], rows, dk)
+        v_src = v_hbm.reshape(v_hbm.shape[0], rows, dv)
 
     def n_pages_of(row):
         return (len_ref[row] + ps - 1) // ps
+
+    def page_copies(src, buf, pid, slot, j, d, sem, act):
+        # a page wider than the lanes lands as one (rows, 128) block per
+        # 128 lanes (the buffer's extra axis): Mosaic's strided loads, which
+        # split the heads below, take 128-lane rows only
+        if d == _LANES:
+            act(pltpu.make_async_copy(src.at[pid], buf.at[slot, j], sem))
+        else:
+            for c in range(d // _LANES):
+                act(pltpu.make_async_copy(
+                    src.at[pid, :, pl.ds(c * _LANES, _LANES)],
+                    buf.at[slot, j, c], sem))
 
     def wave_copies(row, w, slot, act):
         # the wave's pages that exist: one K and one V DMA each
@@ -103,10 +118,8 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             @pl.when(w * wave + j < npg)
             def _():
                 pid = bt_ref[row * n_row + w * wave + j]
-                act(pltpu.make_async_copy(
-                    k_src.at[pid], kbuf.at[slot, j], sem.at[0, slot]))
-                act(pltpu.make_async_copy(
-                    v_src.at[pid], vbuf.at[slot, j], sem.at[1, slot]))
+                page_copies(k_src, kbuf, pid, slot, j, dk, sem.at[0, slot], act)
+                page_copies(v_src, vbuf, pid, slot, j, dv, sem.at[1, slot], act)
 
     @pl.when(b == 0)
     def _():
@@ -122,15 +135,18 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     n_waves = (npg + wave - 1) // wave
     tw = wave * ps  # positions per wave
 
-    def heads_of(buf, slot):
+    def heads_of(buf, slot, d):
         """The wave's (tw, d) operand per KV head, from 32-bit rows."""
         out = []
         for r in range(rpt):
-            if rpt == 1:
-                x = buf[slot]
+            if d != _LANES:
+                x = jnp.concatenate([
+                    buf[slot, :, pl.ds(c, 1), pl.ds(r, ps, stride=rpt), :]
+                    .reshape(tw, _LANES) for c in range(d // _LANES)], axis=-1)
+            elif rpt == 1:
+                x = buf[slot].reshape(tw, d)
             else:
-                x = buf[slot, :, pl.ds(r, ps, stride=rpt), :]
-            x = x.reshape(tw, d)
+                x = buf[slot, :, pl.ds(r, ps, stride=rpt), :].reshape(tw, d)
             if pack == 1:
                 out.append(x)
             else:
@@ -153,7 +169,7 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         wave_copies(b, w, slot, lambda c: c.wait())
         pos = w * tw + lax.broadcasted_iota(jnp.int32, (1, tw), 1)
         valid = pos < length
-        ks, vs = heads_of(kbuf, slot), heads_of(vbuf, slot)
+        ks, vs = heads_of(kbuf, slot, dk), heads_of(vbuf, slot, dv)
         out = []
         for h in range(hkv):
             m, l, acc = carry[h]
@@ -176,42 +192,53 @@ def _kernel(len_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     init = tuple(
         (jnp.full((g, 1), -jnp.inf, jnp.float32),
          jnp.zeros((g, 1), jnp.float32),
-         jnp.zeros((g, d), jnp.float32)) for _ in range(hkv))
+         jnp.zeros((g, dv), jnp.float32)) for _ in range(hkv))
     fin = lax.fori_loop(0, n_waves, wave_body, init)
     for h in range(hkv):
         _, l, acc = fin[h]
         o_ref[h] = (acc / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _paged_decode_attention(q, pages_k, pages_v, block_table, lengths,
-                            interpret):
-    b, h, d = q.shape
+                            scale, interpret):
+    b, h, dk = q.shape
     n_pages, ps, hkv, _ = pages_k.shape
+    dv = pages_v.shape[-1]
     n_row = block_table.shape[1]
     g = h // hkv
     # query rows of a group padded to a whole sublane tile of the compute
     # dtype (12 -> 16): the pad rows score garbage nobody reads
     tile = _SUBLANES * 4 // jnp.dtype(q.dtype).itemsize
     gp = -(-g // tile) * tile
-    qg = jnp.pad(q.reshape(b, hkv, g, d),
+    qg = jnp.pad(q.reshape(b, hkv, g, dk),
                  ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     pack = 4 // pages_k.dtype.itemsize  # KV heads per 32-bit word
-    buf = pltpu.VMEM((2, _WAVE_PAGES, ps * hkv // pack, d),
-                     jnp.uint32 if pack == 2 else pages_k.dtype)
-    q_spec = pl.BlockSpec((None, hkv, gp, d), lambda i, *_: (i, 0, 0, 0))
+
+    def buf(d):
+        rows = ps * hkv // pack
+        return pltpu.VMEM(
+            (2, _WAVE_PAGES, rows, d) if d == _LANES else
+            (2, _WAVE_PAGES, d // _LANES, rows, _LANES),
+            jnp.uint32 if pack == 2 else pages_k.dtype)
+
+    def spec(d):
+        return pl.BlockSpec((None, hkv, gp, d), lambda i, *_: (i, 0, 0, 0))
+
     out = pl.pallas_call(
         functools.partial(
-            _kernel, n_rows=b, n_row=n_row, ps=ps, hkv=hkv, pack=pack, d=d,
-            scale=d ** -0.5, cdtype=q.dtype),
+            _kernel, n_rows=b, n_row=n_row, ps=ps, hkv=hkv, pack=pack,
+            dk=dk, dv=dv, scale=dk ** -0.5 if scale is None else scale,
+            cdtype=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+            in_specs=[spec(dk), pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=q_spec,
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+            out_specs=spec(dv),
+            scratch_shapes=[buf(dk), buf(dv),
+                            pltpu.SemaphoreType.DMA((2, 2)),
                             pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, dv), q.dtype),
         name="paged_decode_attention",
         **({"interpret": True} if interpret else {
             "interpret": False,
@@ -220,23 +247,26 @@ def _paged_decode_attention(q, pages_k, pages_v, block_table, lengths,
                 dimension_semantics=("arbitrary",))}),
     )(jnp.clip(lengths, 1, n_row * ps).astype(jnp.int32),
       block_table.reshape(-1).astype(jnp.int32), qg, pages_k, pages_v)
-    return out[:, :, :g].reshape(b, h, d)
+    return out[:, :, :g].reshape(b, h, dv)
 
 
 def paged_decode_attention(q, pages_k, pages_v, block_table, lengths,
+                           scale: float | None = None,
                            interpret: bool | None = None):
     """One decode step of attention over a paged KV pool.
 
-    ``q`` (B, H, D) in the compute dtype; ``pages_k``/``pages_v``
-    (n_pages, page_size, H_kv, D) pools of the same dtype, the current
-    token's K/V already written; ``block_table`` (B, max_len // page_size)
-    page ids; ``lengths`` (B,) positions each row attends, clamped to
-    ``[1, max_len]``.  Returns (B, H, D).  Shapes must satisfy
-    :func:`paged_kernel_eligible`.
+    ``q`` (B, H, Dk) in the compute dtype; ``pages_k`` (n_pages, page_size,
+    H_kv, Dk) and ``pages_v`` (n_pages, page_size, H_kv, Dv) pools of the
+    same dtype, the current token's K/V already written; ``block_table``
+    (B, max_len // page_size) page ids; ``lengths`` (B,) positions each row
+    attends, clamped to ``[1, max_len]``.  Returns (B, H, Dv).  Scores are
+    scaled by ``scale`` (``Dk ** -0.5`` when not named: a model that stores
+    its keys zero-padded to whole lanes names the scale of the width it
+    computes with).  Shapes must satisfy :func:`paged_kernel_eligible`.
 
     The body is one ``jax.jit``-ed function: thirty layers calling it with
     identical avals trace and lower the kernel once.
     """
     return _paged_decode_attention(
-        q, pages_k, pages_v, block_table, lengths,
+        q, pages_k, pages_v, block_table, lengths, scale=scale,
         interpret=resolve_interpret(interpret))

@@ -19,6 +19,20 @@ capacity with :func:`expert_capacity` to bound drops.
 Gradient path: the gate probability multiplies the combined output, so the
 router trains through the same loss (plus the standard load-balancing
 auxiliary loss, returned separately).
+
+The serving side (:func:`sigmoid_topk_route`, :func:`dropless_held_ffn`) is
+the other contract: ONE chip's share of a wide expert-parallel deployment.
+The layer is told which experts it HOLDS (``first`` and the banks' leading
+axis), routes every token over ALL experts, keeps the (token, choice) pairs
+whose expert it holds, groups them by expert and runs one grouped matrix
+product per projection over the experts held (a Pallas kernel, JAX's own
+``megablox.gmm``: measured against ``jax.lax.ragged_dot`` on a v5e it took
+1.20 against 1.74 ms a layer at 96 decoding rows and 3.79 against 4.71 ms
+at a 2048-token chunk, PERF.md §6 PR 32, so it is the one kept).  It
+returns that PARTIAL sum: what the absent experts would add is another
+chip's, and nothing here stands in for those chips or for their exchange.  No capacity, no drop: a
+pair is computed whatever the imbalance.  The capacity-dropping
+:class:`MoEBlock` above stays the trainer's.
 """
 
 from __future__ import annotations
@@ -30,8 +44,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _megablox_gmm
+
+from distributed_tensorflow_ibm_mnist_tpu.ops.interpret import resolve_interpret
 from distributed_tensorflow_ibm_mnist_tpu.parallel import collectives as cl
 from distributed_tensorflow_ibm_mnist_tpu.parallel.mesh import shard_map_compat
+
+_GMM_TILES = (128, 1024, 1024)  # rows x contraction x columns of one step of
+#   the grouped product: the best of (128, 1024, 1024), (256, 2048, 512) and
+#   (512, 1024, 1024) at 16 banks of 4096 x 2048 on a v5e
 
 
 def expert_capacity(n_tokens: int, n_experts: int, factor: float = 1.25) -> int:
@@ -101,6 +122,68 @@ def _route(x, w_router, n_experts: int, capacity: int, top_k: int = 1):
         "z": jnp.mean(jax.nn.logsumexp(logits32, axis=-1) ** 2),
     }
     return dispatch, combine, (frac_tokens, frac_probs), stats
+
+
+def sigmoid_topk_route(u, w_router, bias, top_k: int):
+    """Sigmoid-scored top-k routing with a selection-only correction bias
+    (``noaux_tc`` with one group): ``s = sigmoid(u W_r)`` in float32, the
+    ``top_k`` experts with the largest ``s + bias``, weights ``s_e`` over
+    the sum of the chosen ``s`` — the bias moves the CHOICE and never the
+    weights.  ``u`` (T, D), ``w_router`` (D, E), ``bias`` (E,).  Returns
+    ``(ids (T, top_k) int32, weights (T, top_k) float32)``."""
+    s = jax.nn.sigmoid(jnp.dot(
+        u.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    return ids.astype(jnp.int32), w / w.sum(-1, keepdims=True)
+
+
+def dropless_held_ffn(u, ids, weights, w_gate, w_up, w_down, first: int,
+                      valid=None):
+    """The held experts' part of a gated (SwiGLU) top-k expert layer.
+
+    ``u`` (T, D) tokens; ``ids`` / ``weights`` (T, k) each token's chosen
+    experts (ids over ALL experts) and their weights; ``w_gate`` / ``w_up``
+    (H, D, F) and ``w_down`` (H, F, D) the banks of the ``H`` experts held
+    here, global ids ``first .. first + H - 1``; ``valid`` (T,) bool marks
+    the real tokens (padding and idle rows route nowhere).
+
+    The T * k pairs are sorted so that the held ones come first, grouped by
+    expert; the grouped product multiplies each group by its expert's matrix
+    and visits no tile of rows past the last group (its grid is as long as
+    the groups are), so the work follows the pairs held and not T * k.
+    Returns ``(y (T, D), load (H,) int32)``:
+    ``y = sum over the held pairs of w * W_down_e(silu(W_gate_e u) * W_up_e
+    u)`` and the pairs each held expert computed.  Dropless: no capacity
+    bounds a group."""
+    t, k = ids.shape
+    n_held = w_gate.shape[0]
+    local = ids - first
+    held = (local >= 0) & (local < n_held)
+    if valid is not None:
+        held = held & valid[:, None]
+    key = jnp.where(held, local, n_held).reshape(-1)
+    order = jnp.argsort(key, stable=True)  # held pairs first, by expert
+    load = jnp.zeros((n_held,), jnp.int32).at[key].add(1, mode="drop")
+    rows = u[order // k]
+    pad = -rows.shape[0] % _GMM_TILES[0]  # whole tiles of rows
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    interpret = resolve_interpret(None)
+
+    def grouped(x, bank):  # (rows, K) x (H, K, N) by groups of ``load`` rows
+        tiles = tuple(map(min, _GMM_TILES, (x.shape[0],) + bank.shape[1:]))
+        return _megablox_gmm(x, bank, load, preferred_element_type=u.dtype,
+                             tiling=tiles, interpret=interpret)
+
+    h = nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    y = grouped(h, w_down)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    y = y[back].reshape(t, k, -1).astype(jnp.float32)
+    # rows past the last group were never written: select, do not multiply
+    y = jnp.where(held[..., None], y * weights[..., None], 0.0).sum(1)
+    return y.astype(u.dtype), load
 
 
 def _expert_ffn(params, x):

@@ -466,11 +466,14 @@ class InferenceEngine:
                     f"max_len ({max_len}) must be a multiple of kv_page_size "
                     f"({kv_page_size}) so every slot's virtual span is "
                     "exactly max_len (the paged==dense parity contract)")
-            if getattr(model, "window", 0):
+            if (getattr(model, "window", 0)
+                    and not getattr(model, "has_window_rings", False)):
                 raise ValueError(
                     "the paged cache does not compose with sliding-window "
                     "attention (model.window > 0) — the windowed decode "
-                    "gathers a contiguous dense span")
+                    "gathers a contiguous dense span (a model that keeps "
+                    "its window layers in rings beside the pool says so: "
+                    "has_window_rings)")
         if tp < 1:
             raise ValueError(f"tp must be >= 1, got {tp}")
         if tp > 1:
@@ -511,11 +514,18 @@ class InferenceEngine:
         # the same cache tree and the same programs.  What such a model
         # cannot have yet is refused here, by name, never degraded.
         self._recurrent = bool(getattr(model, "has_recurrent_state", False))
-        self._read_plan = getattr(model, "decode_read_plan", None)
+        # which per-row leaves those are: a window layer's rings, of which
+        # such a model's global layers alone own pages (models/mimo.py);
+        # and whether some layers are a share of an expert layer, whose
+        # load the device counts in a leaf the host reads back
+        self._rings = bool(getattr(model, "has_window_rings", False))
+        self._experts = bool(getattr(model, "has_expert_layers", False))
         if self._recurrent:
+            kind = ("window-ring and expert layers" if self._rings
+                    else "recurrent-state layers")
             if not (kv_page_size and prefill_chunk):
                 raise ValueError(
-                    "a model with recurrent-state layers is served through "
+                    f"a model with {kind} is served through "
                     "the paged cache by chunked prefill only: it needs "
                     "kv_page_size > 0 and prefill_chunk > 0 (a chunk carries "
                     "the row's state forward; there is no bucketed prefill "
@@ -523,13 +533,13 @@ class InferenceEngine:
             if prefill_chunk % kv_page_size:
                 raise ValueError(
                     f"prefill_chunk ({prefill_chunk}) must be whole pages "
-                    f"({kv_page_size}) for a model with recurrent-state "
-                    "layers: a chunk starts on a page and on a compressed-"
+                    f"({kv_page_size}) for a model with {kind}"
+                    ": a chunk starts on a page and on a compressed-"
                     "key stride")
             if radix_cache:
                 raise ValueError(
                     "radix prefix sharing is refused for a model with "
-                    "recurrent-state layers: a shared prefix's K/V pages are "
+                    f"{kind}: a shared prefix's K/V pages are "
                     "only half of its cache — the state after that prefix "
                     "would have to be snapshotted and shared with them, and "
                     "this engine keeps no state snapshots (docs/SERVING.md); "
@@ -538,11 +548,14 @@ class InferenceEngine:
             if (tp > 1 or cp > 1 or speculative is not None or role != "both"
                     or prefix_cache_bytes or quant == "int8"):
                 raise ValueError(
-                    "a model with recurrent-state layers runs on one chip, "
+                    f"a model with {kind} runs on one chip, "
                     "role='both', without speculative decoding, the prefix "
                     "cache or int8 weights: its paged kernels are not "
                     "partitioned, a rejected draft cannot rewind a state, a "
-                    "handoff moves pages and not states")
+                    "handoff moves pages and not states"
+                    + (", the expert banks have no int8 form and their "
+                       "exchange across chips is not built" if self._experts
+                       else ""))
         # persistent XLA compilation cache: warm processes (and respawned
         # replicas) skip recompiling the engine's program family.  Placed
         # from outside — utils/compile_cache.py
@@ -754,6 +767,9 @@ class InferenceEngine:
         else:
             decode_model = model
         self._kv_pages = int(kv_pages)
+        # what a decode step reads, by the model's own arithmetic: asked of
+        # the clone, which knows the page size
+        self._read_plan = getattr(decode_model, "decode_read_plan", None)
 
         # every jitted program that RETURNS a cache pins the KV layout at
         # its output (identity at tp=1): GSPMD propagation from the
@@ -1076,22 +1092,49 @@ class InferenceEngine:
         )
         return v
 
+    def _sample_row_leaves(self) -> None:
+        """Occupancy of the per-row leaves beside the page pool (one row a
+        slot): the recurrent-state pool's, or the window rings'."""
+        sample = (self.stats.ring_sample if self._rings
+                  else self.stats.state_sample)
+        sample(self.occupied, self.slots)
+
     def _count_recurrent_window(self) -> None:
-        """The counters of an engine whose model keeps recurrent state and
-        selects blocks, taken where the decode window is dispatched: the
-        state pool's occupancy, and what the window's attention reads —
-        the MODEL's own arithmetic (``decode_read_plan``) on the host's
-        record of each decoding row's length."""
-        self.stats.state_sample(self.occupied, self.slots)
+        """The counters of an engine whose model keeps per-row leaves
+        beside its pages, taken where the decode window is dispatched: the
+        rows' occupancy, the pairs the window routes over expert layers,
+        and what the window's attention reads — the MODEL's own arithmetic
+        (``decode_read_plan``) on the host's record of each decoding row's
+        length."""
+        self._sample_row_leaves()
         ctx = np.array(
             [r.tokens.size + len(r.generated)
              for r, p in zip(self._slot_req, self._slot_prefill)
              if r is not None and p is None], np.int64)
+        if self._experts:
+            self.stats.expert_tokens(
+                self.model.expert_pairs(ctx.size * self.decode_ahead))
         if self._read_plan is None or not ctx.size:
             return
         # step i of the window writes the token at position ctx - 1 + i
-        self.stats.sparse_step(
-            *self._read_plan(ctx[:, None] + np.arange(self.decode_ahead)))
+        plan = self._read_plan(ctx[:, None] + np.arange(self.decode_ahead))
+        if self._rings:
+            self.stats.global_step(plan)
+        else:
+            self.stats.sparse_step(*plan)
+
+    def sync_expert_load(self) -> None:
+        """Read the device's expert-load counters into ``self.stats`` — one
+        small transfer, for whoever wants the counters current (the emit
+        points; a benchmark at its window's edges).  Never in the step
+        loop: a step adds to the leaf on the device and moves nothing."""
+        if not self._experts:
+            return
+        leaves = [e["expert_load"] for e in self.cache.values()
+                  if "expert_load" in e]
+        if any(leaf.is_deleted() for leaf in leaves):
+            return  # a faulted dispatch took the donated cache with it
+        self.stats.expert_load(jax.device_get(leaves))
 
     def _stamp_memory(self) -> None:
         """(Re-)stamp the per-chip memory figures into ``self.stats`` —
@@ -1817,7 +1860,10 @@ class InferenceEngine:
             t_c1 = self.clock()
             self.stats.chunk(t_c1 - t_c0, start=done)
             if self._recurrent:
-                self.stats.state_sample(self.occupied, self.slots)
+                self._sample_row_leaves()
+            if self._experts:
+                self.stats.expert_tokens(
+                    self.model.expert_pairs(int(suffix.size)))
             if self._tracer is not None and req.trace is not None:
                 # per-chunk child span under the request's admit phase
                 self._tracer.complete(
@@ -2460,6 +2506,7 @@ class InferenceEngine:
             self.stats.set_compile(CompileTracker.delta(
                 self._compile.snapshot(), self._compile0))
             self._stamp_memory()
+            self.sync_expert_load()
             if self.writer is not None:
                 self.stats.emit(self.writer)
         return self.completed
@@ -2549,6 +2596,7 @@ class InferenceEngine:
         self.stats.set_compile(CompileTracker.delta(
             self._compile.snapshot(), self._compile0))
         self._stamp_memory()
+        self.sync_expert_load()
         if self.writer is not None:
             self.stats.emit(self.writer)
         self._closed = True
@@ -2684,6 +2732,8 @@ class InferenceEngine:
                     jnp.zeros((1, c), jnp.int32),
                     jnp.asarray(0, jnp.int32),
                     jnp.asarray(1, jnp.int32))
+            if self._experts:  # the warm chunk's one real token was routed
+                self.stats.expert_tokens(self.model.expert_pairs(1))
         else:
             last_logits = None
             for b in self.buckets:

@@ -23,8 +23,15 @@ A model whose layers keep different kinds of state (models/sala.py) says
 itself what each layer holds (``model.paged_cache_shapes``): a sparse layer
 the paged entry above plus ``kc`` (B, max_len / stride, hkv, d) float32
 compressed keys, a lightning layer ``{"state": (B, H, d, d) float32,
-"index": (B,)}`` and no pages at all.  ``ROW_LEAVES`` names those per-row
-leaves; every function here takes an entry as it finds it.
+"index": (B,)}`` and no pages at all.  A sliding-window layer
+(models/mimo.py) likewise owns NO page: the last ``window`` positions of a
+row are all it ever reads, so it keeps them in a ring a row, ``ring_k`` /
+``ring_v`` (B, window, hkv, d), position p at slot p mod window — nothing is
+freed "as the window slides" because nothing was allocated; pages are
+allocated for the global layers alone, whose ``pages_k`` and ``pages_v``
+may differ in width.  ``ROW_LEAVES`` names those per-row leaves,
+``SHARED_LEAVES`` the small whole leaves a chunk updates as it does the
+pool; every function here takes an entry as it finds it.
 
 Page 0 is a reserved TRASH page: every unallocated block-table entry points
 at it, so idle rows' decode writes land in garbage nobody reads (the model's
@@ -79,8 +86,13 @@ TRASH_PAGE = 0
 # compressed keys ``kc`` (models/sala.py).  They live beside the page pool
 # in the same cache tree and are owned by the same programs: a prefill chunk
 # narrows them to its row and writes the row back; a row's first chunk
-# starts them from nothing, so reset has no work to do on them
-ROW_LEAVES = ("state", "kc")
+# starts them from nothing, so reset has no work to do on them.  A window
+# layer's ring of its last ``window`` keys and values (models/mimo.py) is
+# the same kind of leaf
+ROW_LEAVES = ("state", "kc", "ring_k", "ring_v")
+# leaves that belong to no row and no page: a chunk takes them whole and
+# hands them back whole, like the pool (an expert layer's load counter)
+SHARED_LEAVES = ("expert_load",)
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
@@ -405,7 +417,8 @@ def make_paged_extend(model, max_len: int, page_size: int) -> Callable:
         # shared storage
         sub = {}
         for name, e in cache.items():
-            se = {k: v for k, v in e.items() if k.startswith("pages_")}
+            se = {k: v for k, v in e.items()
+                  if k.startswith("pages_") or k in SHARED_LEAVES}
             if "block_table" in e:
                 se["block_table"] = jax.lax.dynamic_slice(
                     e["block_table"], (slot, 0), (1, n_row))
@@ -425,7 +438,7 @@ def make_paged_extend(model, max_len: int, page_size: int) -> Callable:
         for name, e in cache.items():
             oe = dict(e)
             for key in e:
-                if key.startswith("pages_"):
+                if key.startswith("pages_") or key in SHARED_LEAVES:
                     oe[key] = new[name][key]
                 elif key in ROW_LEAVES:
                     oe[key] = jax.lax.dynamic_update_slice_in_dim(

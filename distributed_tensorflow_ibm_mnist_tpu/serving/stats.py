@@ -239,6 +239,18 @@ class ServingStats:
         self._dense_len_rows = 0       # row-steps still within dense_len
         self._state_rows_in_use = 0    # slots holding a request's state
         self._state_rows_total = 0
+        # --- window rings and held experts --- all zero on engines whose
+        # model has neither (models/mimo.py has both)
+        self._ring_rows_in_use = 0     # slots holding a request's rings
+        self._ring_rows_total = 0
+        self._global_pages_read = 0    # pages the global layers' decode
+        #   steps read: per (decoding row, global layer) of every step
+        self._expert_assignments = 0   # (token, choice) pairs routed, over
+        #   decoding and chunk tokens and expert layers, held here or not
+        self._expert_load: list[list[int]] = []  # per expert layer and held
+        #   expert, the pairs computed: the DEVICE's own count, as last read
+        self._expert_hits: list[list[int]] = []  # and the calls (decode
+        #   steps, chunks) that gave the expert any pair
         # --- compile accounting (ISSUE 6) --- the engine's own XLA
         # program family: a CompileTracker snapshot DELTA from engine
         # construction to stats emission (utils/tracing.py)
@@ -362,6 +374,32 @@ class ServingStats:
         with self._lock:
             self._state_rows_in_use = int(rows_in_use)
             self._state_rows_total = int(rows_total)
+
+    def ring_sample(self, rows_in_use: int, rows_total: int) -> None:
+        """Occupancy of the window layers' rings (one row a slot)."""
+        with self._lock:
+            self._ring_rows_in_use = int(rows_in_use)
+            self._ring_rows_total = int(rows_total)
+
+    def global_step(self, pages_read: int) -> None:
+        """One decode window of an engine whose model mixes window and
+        global layers: the pages its global layers read."""
+        with self._lock:
+            self._global_pages_read += int(pages_read)
+
+    def expert_tokens(self, pairs: int) -> None:
+        """(token, choice) pairs one dispatch routes over the expert
+        layers: every one of them, whichever chip holds its expert."""
+        with self._lock:
+            self._expert_assignments += int(pairs)
+
+    def expert_load(self, load) -> None:
+        """The device's cumulative counts, per expert layer a (2, held)
+        array as the engine last read it: the pairs each held expert
+        computed, and the calls that gave it any."""
+        with self._lock:
+            self._expert_load = [[int(n) for n in layer[0]] for layer in load]
+            self._expert_hits = [[int(n) for n in layer[1]] for layer in load]
 
     def prompt_admitted(self, n_tokens: int) -> None:
         """One admission's prompt length (chunked engines call this at
@@ -589,6 +627,13 @@ class ServingStats:
             "dense_len_rows": self._dense_len_rows,
             "state_rows_in_use": self._state_rows_in_use,
             "state_rows_total": self._state_rows_total,
+            "ring_rows_in_use": self._ring_rows_in_use,
+            "ring_rows_total": self._ring_rows_total,
+            "global_pages_read": self._global_pages_read,
+            "expert_assignments": self._expert_assignments,
+            "expert_assignments_held": sum(map(sum, self._expert_load)),
+            "expert_load": [list(layer) for layer in self._expert_load],
+            "expert_hits": [list(layer) for layer in self._expert_hits],
             # compile accounting (None until set_compile — an engine that
             # never emitted stats has no delta to report)
             "n_compiled_programs": (
@@ -823,7 +868,16 @@ class ServingStats:
             **{k: sum(getattr(rec, "_" + k) for rec in records)
                for k in ("sparse_blocks_read", "sparse_blocks_live",
                          "dense_len_rows", "state_rows_in_use",
-                         "state_rows_total")},
+                         "state_rows_total", "ring_rows_in_use",
+                         "ring_rows_total", "global_pages_read",
+                         "expert_assignments")},
+            "expert_assignments_held": sum(
+                sum(map(sum, rec._expert_load)) for rec in records),
+            # replicas that hold the same experts add up expert by expert
+            **{k: [[sum(ns) for ns in zip(*layers)] for layers in zip(
+                *(getattr(rec, "_" + k) for rec in records
+                  if getattr(rec, "_" + k)))]
+               for k in ("expert_load", "expert_hits")},
             "tp": tps.pop() if len(tps) == 1 else None,
             "cp": cps.pop() if len(cps) == 1 else None,
             # common scheme or None when replicas disagree (a mid-rollout

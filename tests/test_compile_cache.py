@@ -92,12 +92,19 @@ def test_one_site_sets_the_cache_dir_and_no_temp_cache_paths_remain():
     """A grep test (like tests/test_lint_tracing.py): exactly one
     ``config.update`` of jax's cache-directory option in the repo —
     utils/compile_cache.py, under the variable-unset guard — and no
-    ``mkdtemp`` compile-cache path under scripts/ or examples/."""
+    ``mkdtemp`` compile-cache path under scripts/ or examples/.  The
+    directories ``.gitignore`` names are not the repo: a chip comparison
+    leaves a copy of the parent tree under one of them (``.scratch/``,
+    ``.cache/``)."""
     option = "jax_compilation_" + "cache_dir"  # split: this file is grepped too
+    ignored = {line.strip().rstrip("/") for line in
+               (REPO / ".gitignore").read_text().splitlines()
+               if line.strip().endswith("/")}
+    assert {".cache", ".scratch", "chiprun_out"} <= ignored
     sites = []
     for path in REPO.rglob("*.py"):
         rel = path.relative_to(REPO)
-        if rel.parts[0] in (".cache", "chiprun_out"):
+        if ignored & set(rel.parts[:-1]):
             continue
         text = path.read_text()
         if re.search(r"""config\.update\(\s*["']""" + option, text):
