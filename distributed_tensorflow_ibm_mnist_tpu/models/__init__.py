@@ -17,6 +17,16 @@ from distributed_tensorflow_ibm_mnist_tpu.models.mlp import MLP
 from distributed_tensorflow_ibm_mnist_tpu.models.resnet import ResNet, ResNet20, ResNet50
 from distributed_tensorflow_ibm_mnist_tpu.models.transformer import VisionTransformer
 
+
+def _nemotron_h(**kwargs):
+    """The Nemotron-H serving model (models/nemotron_h.py), imported on
+    first use: its kernels and the grouped expert product stay out of every
+    other model's import."""
+    from distributed_tensorflow_ibm_mnist_tpu.models.nemotron_h import NemotronHLM
+
+    return NemotronHLM(**kwargs)
+
+
 _REGISTRY = {
     "mlp": MLP,
     "lenet5": LeNet5,
@@ -24,6 +34,7 @@ _REGISTRY = {
     "resnet50": ResNet50,
     "vit": VisionTransformer,
     "causal_lm": CausalLM,
+    "nemotron_h": _nemotron_h,
 }
 
 

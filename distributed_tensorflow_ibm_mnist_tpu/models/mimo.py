@@ -69,24 +69,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_tensorflow_ibm_mnist_tpu.models.paged_layer import (
+    Widths,
+    attend,
+    lane_pad,
+    paged_chunk,
+    paged_step,
+)
 from distributed_tensorflow_ibm_mnist_tpu.models.sala import RMSNorm, _external
 from distributed_tensorflow_ibm_mnist_tpu.models.transformer import apply_rope
-from distributed_tensorflow_ibm_mnist_tpu.ops.paged_attention import (
-    paged_decode_attention,
-    paged_kernel_eligible,
-)
 from distributed_tensorflow_ibm_mnist_tpu.parallel.expert_parallel import (
     dropless_held_ffn,
     sigmoid_topk_route,
 )
-
-_LANES = 128
-_MASK = -1e30
-_KEY_BLOCK = 512  # keys a step of a chunk's global attention scores at once
-
-
-def _lane_pad(d: int) -> int:
-    return -(-d // _LANES) * _LANES
 
 
 class MimoBlock(nn.Module):
@@ -183,9 +178,10 @@ class MimoBlock(nn.Module):
             if self.windowed:
                 step = self._ring_step if s == 1 else self._ring_chunk
                 o = step(q, k, v, sink, idx, n_valid)
+            elif s == 1:
+                o = paged_step(self, q, k, v, idx, n_valid, max_len, self._widths())
             else:
-                step = self._paged_step if s == 1 else self._paged_chunk
-                o = step(q, k, v, idx, n_valid, max_len)
+                o = paged_chunk(self, q, k, v, idx, max_len, self._widths())
         else:
             q, k = self._rope(q, 0), self._rope(k, 0)
             o = self._plain(q, k, v, sink)
@@ -197,27 +193,9 @@ class MimoBlock(nn.Module):
         return jnp.concatenate(
             [apply_rope(x[..., :r], self.rope_theta, offset=offset), x[..., r:]], -1)
 
-    def _attend(self, q, k, v, allow, sink):
-        """softmax(q.k / sqrt(head_dim) [+ the sink's column]) v under
-        ``allow``.  q (..., Q, H, Dk), k (..., N, Hkv, Dk), v (..., N, Hkv,
-        Dv), allow broadcastable to (..., Q, N) -> (..., Q, H, Dv)."""
-        hkv = k.shape[-2]
-        g = q.shape[-2] // hkv
-        qg = q.reshape(q.shape[:-2] + (hkv, g, q.shape[-1]))
-        sc = jnp.einsum("...qkgd,...nkd->...kgqn", qg, k,
-                        preferred_element_type=jnp.float32) * self.head_dim ** -0.5
-        sc = jnp.where(allow[..., None, None, :, :], sc, _MASK)
-        m = sc.max(-1, keepdims=True)
-        if sink is not None:
-            sk = sink.astype(jnp.float32).reshape(hkv, g, 1, 1)
-            m = jnp.maximum(m, sk)
-        p = jnp.exp(sc - m)
-        den = p.sum(-1, keepdims=True)
-        if sink is not None:
-            den = den + jnp.exp(sk - m)
-        o = jnp.einsum("...kgqn,...nkd->...qkgd", (p / den).astype(v.dtype), v,
-                       preferred_element_type=jnp.float32)
-        return o.reshape(o.shape[:-3] + (hkv * g, v.shape[-1]))
+    def _widths(self) -> Widths:
+        return Widths(self.heads, self.heads_kv, self.head_dim,
+                      self.v_head_dim, self.page_size, self.dtype)
 
     def _plain(self, q, k, v, sink):
         s = q.shape[1]
@@ -225,7 +203,7 @@ class MimoBlock(nn.Module):
         allow = i[None, :] <= i[:, None]
         if self.windowed:
             allow = allow & (i[None, :] > i[:, None] - self.window)
-        return self._attend(q, k, v, allow[None], sink)
+        return attend(q, k, v, allow[None], self.head_dim, sink)
 
     # ---- window layers: a ring of the last ``window`` positions per row
     def _rings(self):
@@ -245,8 +223,8 @@ class MimoBlock(nn.Module):
         j = jnp.arange(w)
         # slot j holds the newest position <= t that is congruent to j
         held = idx[:, None] - (idx[:, None] - j[None, :]) % w
-        return self._attend(q, ring_k.value, ring_v.value,
-                            (held >= 0)[:, None, :], sink)
+        return attend(q, ring_k.value, ring_v.value, (held >= 0)[:, None, :],
+                      self.head_dim, sink)
 
     def _ring_chunk(self, q, k, v, sink, idx, n_valid):
         """ONE row's prefill chunk, a whole number of windows that starts on
@@ -277,8 +255,8 @@ class MimoBlock(nn.Module):
         allow = ((kpos[:, None, :] <= qpos[:, :, None])
                  & (kpos[:, None, :] > qpos[:, :, None] - w)
                  & (kpos[:, None, :] >= 0))
-        o = self._attend(q[0].reshape((tiles, w) + q.shape[2:]),
-                         pairs(k_ext), pairs(v_ext), allow, sink)
+        o = attend(q[0].reshape((tiles, w) + q.shape[2:]), pairs(k_ext),
+                   pairs(v_ext), allow, self.head_dim, sink)
         # the ring after the chunk: slot j takes the newest REAL position
         # congruent to j (from this chunk or, in a short last chunk, the
         # ring as it was)
@@ -288,94 +266,6 @@ class MimoBlock(nn.Module):
         ring_k.value = k_ext[at][None]
         ring_v.value = v_ext[at][None]
         return o.reshape((1, c) + o.shape[2:])
-
-    # ---- global layers: the page pool
-    def _pool(self, max_len):
-        ps = self.page_size
-        if max_len % ps:
-            raise ValueError(
-                f"max_len ({max_len}) must be a multiple of page_size ({ps})")
-        pages_k = self.variable("cache", "pages_k", _external("pages_k"))
-        pages_v = self.variable("cache", "pages_v", _external("pages_v"))
-        if not paged_kernel_eligible(self.dtype, pages_k.value.dtype, ps,
-                                     self.heads_kv, pages_k.value.shape[-1],
-                                     self.v_head_dim):
-            raise ValueError(
-                "global-layer decode reads the pool through the paged kernel "
-                "only (ops.paged_attention.paged_kernel_eligible): pool "
-                f"{pages_k.value.dtype} for compute {self.dtype}, page {ps}, "
-                f"{self.heads_kv} KV heads of {pages_k.value.shape[-1]} / "
-                f"{self.v_head_dim}")
-        bt = self.variable("cache", "block_table", _external("block_table")).value
-        return pages_k, pages_v, bt
-
-    def _padded(self, x):
-        pad = _lane_pad(self.head_dim) - self.head_dim
-        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
-
-    def _paged_step(self, q, k, v, idx, n_valid, max_len):
-        """One token a row, all rows: write it to its page (a row that is not
-        decoding writes to the trash page), read the row's live pages."""
-        pages_k, pages_v, bt = self._pool(max_len)
-        ps = self.page_size
-        t = jnp.minimum(idx, max_len - 1)
-        page = jnp.where(n_valid > 0,
-                         jnp.take_along_axis(bt, (t // ps)[:, None], 1)[:, 0], 0)
-        pages_k.value = pages_k.value.at[page, t % ps].set(self._padded(k[:, 0]))
-        pages_v.value = pages_v.value.at[page, t % ps].set(v[:, 0])
-        o = paged_decode_attention(
-            self._padded(q[:, 0]), pages_k.value, pages_v.value, bt, t + 1,
-            scale=self.head_dim ** -0.5)
-        return o[:, None]
-
-    def _paged_chunk(self, q, k, v, idx, n_valid, max_len):
-        """ONE row's prefill chunk at its cursor: write its pages, then score
-        the row's keys ``_KEY_BLOCK`` at a time up to the chunk's end (the
-        row's cursor, never ``max_len``) under an online softmax."""
-        del n_valid
-        pages_k, pages_v, bt = self._pool(max_len)
-        ps = self.page_size
-        b, c = q.shape[:2]
-        kb = min(_KEY_BLOCK, c)
-        if b != 1 or c % kb or kb % ps:
-            raise ValueError(
-                f"a prefill chunk is one row of whole key blocks ({kb}) of "
-                f"whole pages ({ps}), got {b} rows of {c} tokens")
-        start = idx[0]
-        pos = jnp.minimum(start + jnp.arange(c), max_len - 1)
-        page = bt[0, pos // ps]
-        pages_k.value = pages_k.value.at[page, pos % ps].set(self._padded(k[0]))
-        pages_v.value = pages_v.value.at[page, pos % ps].set(v[0])
-        hkv, dv = self.heads_kv, self.v_head_dim
-        g = self.heads // hkv
-        qg = self._padded(q[0]).reshape(c, hkv, g, -1)
-        qpos = start + jnp.arange(c)
-        per = kb // ps
-
-        def block(j, carry):
-            m, l, acc = carry
-            ids = jax.lax.dynamic_slice_in_dim(bt[0], j * per, per)
-            kj = pages_k.value[ids].reshape(kb, hkv, -1)
-            vj = pages_v.value[ids].reshape(kb, hkv, dv)
-            sc = jnp.einsum("qkgd,nkd->kgqn", qg, kj,
-                            preferred_element_type=jnp.float32) * self.head_dim ** -0.5
-            kpos = j * kb + jnp.arange(kb)
-            sc = jnp.where(kpos[None, :] <= qpos[:, None], sc, _MASK)
-            m_new = jnp.maximum(m, sc.max(-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(sc - m_new)
-            l = alpha * l + p.sum(-1, keepdims=True)
-            acc = alpha * acc + jnp.einsum(
-                "kgqn,nkd->kgqd", p.astype(vj.dtype), vj,
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        init = (jnp.full((hkv, g, c, 1), -jnp.inf, jnp.float32),
-                jnp.zeros((hkv, g, c, 1), jnp.float32),
-                jnp.zeros((hkv, g, c, dv), jnp.float32))
-        n_blocks = jnp.minimum(start + c, max_len) // kb
-        _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
-        return (acc / l).transpose(2, 0, 1, 3).reshape(1, c, self.heads, dv)
 
 
 class MimoLM(nn.Module):
@@ -489,7 +379,7 @@ class MimoLM(nn.Module):
                 e["ring_v"] = struct((slots, self.window, hkv, self.v_head_dim), self.dtype)
             else:
                 e["pages_k"] = struct((n_pages, page_size, self.heads_kv,
-                                       _lane_pad(self.head_dim)), self.dtype)
+                                       lane_pad(self.head_dim)), self.dtype)
                 e["pages_v"] = struct((n_pages, page_size, self.heads_kv,
                                        self.v_head_dim), self.dtype)
                 e["block_table"] = struct((slots, max_len // page_size), jnp.int32)

@@ -141,7 +141,8 @@ def sigmoid_topk_route(u, w_router, bias, top_k: int):
 
 def dropless_held_ffn(u, ids, weights, w_gate, w_up, w_down, first: int,
                       valid=None):
-    """The held experts' part of a gated (SwiGLU) top-k expert layer.
+    """The held experts' part of a top-k expert layer, gated (SwiGLU) or,
+    with ``w_gate`` None, ungated squared-ReLU.
 
     ``u`` (T, D) tokens; ``ids`` / ``weights`` (T, k) each token's chosen
     experts (ids over ALL experts) and their weights; ``w_gate`` / ``w_up``
@@ -155,10 +156,10 @@ def dropless_held_ffn(u, ids, weights, w_gate, w_up, w_down, first: int,
     the groups are), so the work follows the pairs held and not T * k.
     Returns ``(y (T, D), load (H,) int32)``:
     ``y = sum over the held pairs of w * W_down_e(silu(W_gate_e u) * W_up_e
-    u)`` and the pairs each held expert computed.  Dropless: no capacity
-    bounds a group."""
+    u)`` (ungated: ``w * W_down_e relu(W_up_e u)^2``) and the pairs each held
+    expert computed.  Dropless: no capacity bounds a group."""
     t, k = ids.shape
-    n_held = w_gate.shape[0]
+    n_held = w_up.shape[0]
     local = ids - first
     held = (local >= 0) & (local < n_held)
     if valid is not None:
@@ -177,7 +178,10 @@ def dropless_held_ffn(u, ids, weights, w_gate, w_up, w_down, first: int,
         return _megablox_gmm(x, bank, load, preferred_element_type=u.dtype,
                              tiling=tiles, interpret=interpret)
 
-    h = nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    if w_gate is None:
+        h = jnp.square(jax.nn.relu(grouped(rows, w_up)))
+    else:
+        h = nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
     y = grouped(h, w_down)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
     y = y[back].reshape(t, k, -1).astype(jnp.float32)

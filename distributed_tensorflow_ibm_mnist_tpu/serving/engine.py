@@ -520,6 +520,9 @@ class InferenceEngine:
         # load the device counts in a leaf the host reads back
         self._rings = bool(getattr(model, "has_window_rings", False))
         self._experts = bool(getattr(model, "has_expert_layers", False))
+        # and how many of its layers run a state-space scan (models/
+        # nemotron_h.py), for the counters of the scan and the update
+        self._ssm_layers = int(getattr(model, "ssm_layers", 0))
         if self._recurrent:
             kind = ("window-ring and expert layers" if self._rings
                     else "recurrent-state layers")
@@ -1103,7 +1106,8 @@ class InferenceEngine:
         """The counters of an engine whose model keeps per-row leaves
         beside its pages, taken where the decode window is dispatched: the
         rows' occupancy, the pairs the window routes over expert layers,
-        and what the window's attention reads — the MODEL's own arithmetic
+        the row-steps its state-space layers update, and what the window's
+        attention reads — the MODEL's own arithmetic
         (``decode_read_plan``) on the host's record of each decoding row's
         length."""
         self._sample_row_leaves()
@@ -1114,6 +1118,8 @@ class InferenceEngine:
         if self._experts:
             self.stats.expert_tokens(
                 self.model.expert_pairs(ctx.size * self.decode_ahead))
+        if self._ssm_layers:
+            self.stats.ssm_step(ctx.size * self.decode_ahead * self._ssm_layers)
         if self._read_plan is None or not ctx.size:
             return
         # step i of the window writes the token at position ctx - 1 + i
@@ -1864,6 +1870,8 @@ class InferenceEngine:
             if self._experts:
                 self.stats.expert_tokens(
                     self.model.expert_pairs(int(suffix.size)))
+            if self._ssm_layers:
+                self.stats.ssm_scan(int(suffix.size) * self._ssm_layers)
             if self._tracer is not None and req.trace is not None:
                 # per-chunk child span under the request's admit phase
                 self._tracer.complete(
@@ -2734,6 +2742,8 @@ class InferenceEngine:
                     jnp.asarray(1, jnp.int32))
             if self._experts:  # the warm chunk's one real token was routed
                 self.stats.expert_tokens(self.model.expert_pairs(1))
+            if self._ssm_layers:  # and scanned
+                self.stats.ssm_scan(self._ssm_layers)
         else:
             last_logits = None
             for b in self.buckets:

@@ -87,12 +87,14 @@ TRASH_PAGE = 0
 # in the same cache tree and are owned by the same programs: a prefill chunk
 # narrows them to its row and writes the row back; a row's first chunk
 # starts them from nothing, so reset has no work to do on them.  A window
-# layer's ring of its last ``window`` keys and values (models/mimo.py) is
-# the same kind of leaf
-ROW_LEAVES = ("state", "kc", "ring_k", "ring_v")
+# layer's ring of its last ``window`` keys and values (models/mimo.py), a
+# Mamba layer's state and its convolution's last inputs
+# (models/nemotron_h.py) are the same kind of leaf
+ROW_LEAVES = ("state", "kc", "ring_k", "ring_v", "ssm_state", "conv_state")
 # leaves that belong to no row and no page: a chunk takes them whole and
-# hands them back whole, like the pool (an expert layer's load counter)
-SHARED_LEAVES = ("expert_load",)
+# hands them back whole, like the pool (an expert layer's load counter, and
+# the ids the last chunk's tokens chose where models/nemotron_h.py keeps them)
+SHARED_LEAVES = ("expert_load", "chunk_chosen")
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:

@@ -251,6 +251,12 @@ class ServingStats:
         #   expert, the pairs computed: the DEVICE's own count, as last read
         self._expert_hits: list[list[int]] = []  # and the calls (decode
         #   steps, chunks) that gave the expert any pair
+        # --- state-space layers --- zero on engines whose model has none
+        # (models/nemotron_h.py has them)
+        self._ssm_scan_tokens = 0      # real chunk tokens x Mamba layers
+        #   through the chunk-scan kernel
+        self._ssm_step_rows = 0        # decoding row-steps x Mamba layers
+        #   through the one-token update kernel
         # --- compile accounting (ISSUE 6) --- the engine's own XLA
         # program family: a CompileTracker snapshot DELTA from engine
         # construction to stats emission (utils/tracing.py)
@@ -392,6 +398,18 @@ class ServingStats:
         layers: every one of them, whichever chip holds its expert."""
         with self._lock:
             self._expert_assignments += int(pairs)
+
+    def ssm_scan(self, tokens: int) -> None:
+        """Real tokens one prefill chunk runs through the Mamba layers'
+        scan, summed over those layers."""
+        with self._lock:
+            self._ssm_scan_tokens += int(tokens)
+
+    def ssm_step(self, row_steps: int) -> None:
+        """Decoding row-steps one decode window runs through the Mamba
+        layers' update, summed over those layers."""
+        with self._lock:
+            self._ssm_step_rows += int(row_steps)
 
     def expert_load(self, load) -> None:
         """The device's cumulative counts, per expert layer a (2, held)
@@ -634,6 +652,8 @@ class ServingStats:
             "expert_assignments_held": sum(map(sum, self._expert_load)),
             "expert_load": [list(layer) for layer in self._expert_load],
             "expert_hits": [list(layer) for layer in self._expert_hits],
+            "ssm_scan_tokens": self._ssm_scan_tokens,
+            "ssm_step_rows": self._ssm_step_rows,
             # compile accounting (None until set_compile — an engine that
             # never emitted stats has no delta to report)
             "n_compiled_programs": (
@@ -870,7 +890,8 @@ class ServingStats:
                          "dense_len_rows", "state_rows_in_use",
                          "state_rows_total", "ring_rows_in_use",
                          "ring_rows_total", "global_pages_read",
-                         "expert_assignments")},
+                         "expert_assignments", "ssm_scan_tokens",
+                         "ssm_step_rows")},
             "expert_assignments_held": sum(
                 sum(map(sum, rec._expert_load)) for rec in records),
             # replicas that hold the same experts add up expert by expert

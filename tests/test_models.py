@@ -28,7 +28,8 @@ def test_forward_shapes(name, kwargs, in_shape):
 
 
 def test_registry():
-    assert set(available_models()) == {"mlp", "lenet5", "resnet20", "resnet50", "vit", "causal_lm"}
+    assert set(available_models()) == {"mlp", "lenet5", "resnet20", "resnet50", "vit",
+                                       "causal_lm", "nemotron_h"}
     with pytest.raises(ValueError):
         get_model("nope")
 
